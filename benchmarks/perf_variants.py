@@ -17,7 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import repro.launch.dryrun as DR
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.models.registry import get_config
 
 
@@ -117,7 +117,6 @@ def mixtral_variants():
 
 
 def main():
-    import jax
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", required=True, choices=list(CELLS))
     ap.add_argument("--multi-pod", action="store_true")
@@ -133,7 +132,7 @@ def main():
         mesh_v = mesh
         if "mesh" in kw:   # serving-topology variant (e.g. B3)
             shp = kw.pop("mesh")
-            mesh_v = jax.make_mesh(shp, ("data", "model"))
+            mesh_v = auto_mesh(shp, ("data", "model"))
         try:
             row = lower_variant(arch, shape, mesh_v, label=label, **kw)
         except Exception as e:

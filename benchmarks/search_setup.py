@@ -851,6 +851,7 @@ FLEET_SCALING_SCRIPT = textwrap.dedent("""
     probe = assert_population_epoch_dispatch_count(
         arms["fleet_4dev"], arms["fleet_4dev"].epoch_cursor, E)
     print(json.dumps({"eps": best, "devices": len(jax.devices()),
+                      "platform": jax.devices()[0].platform,
                       "pop_epoch": probe["pop_epoch"]}))
 """)
 
@@ -868,7 +869,19 @@ def fleet_scaling_rows(verbose: bool = True) -> list:
     genuinely parallel devices. The rows pin the sharded dispatch path
     (the probe asserts the 1-execution bound) and its eps/s against
     regression; the >=2x multiple lives on real multi-device backends.
+
+    The child forces the CPU backend, so on a TPU host its "devices"
+    would be host threads: this refuses to run there (the fleet on real
+    chips runs through ``chip_smoke.py --chips 4``), and every row names
+    the platform it ran on.
     """
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "fleet_scaling_rows times forced CPU host devices in a child "
+            "process; on a TPU host they would be reported as devices. "
+            "Run the fleet on the chips with `python chip_smoke.py "
+            "--chips 4` instead.")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + root
@@ -885,7 +898,7 @@ def fleet_scaling_rows(verbose: bool = True) -> list:
     for name, devices in (("fleet_1dev", 1), ("fleet_4dev", 4)):
         row = {"table": "fleet_scaling", "engine": name, "members": 4,
                "batch_size": 4, "updates_per_episode": 2,
-               "devices": devices,
+               "devices": devices, "platform": out["platform"],
                "eps_per_s": round(out["eps"][name], 2)}
         if name == "fleet_4dev":
             row["dispatches_per_epoch"] = out["pop_epoch"]

@@ -22,6 +22,7 @@ from repro.core.ddpg import DDPGConfig
 from repro.core.latency import LatencyContext
 from repro.core.reward import RewardConfig
 from repro.core.search import CompressionSearch, SearchConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run_target(name, ctx, episodes=30):
@@ -46,6 +47,7 @@ def run_target(name, ctx, episodes=30):
 
 
 def main():
+    enable_compile_cache()
     edge = LatencyContext(tokens=1, seq_ctx=512, mode="decode", batch=1)
     pod = LatencyContext(tokens=128, seq_ctx=32_768, mode="decode",
                          batch=128, chips=16, tp=16)

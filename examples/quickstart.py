@@ -18,9 +18,11 @@ from repro.core.compress import CompressibleLM
 from repro.core.ddpg import DDPGConfig
 from repro.core.reward import RewardConfig
 from repro.core.search import CompressionSearch, SearchConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg, params, val, clean_acc = get_lm_testbed()
     print(f"testbed LM: {cfg.num_layers}L d={cfg.d_model} "
           f"clean accuracy {clean_acc:.3f}")

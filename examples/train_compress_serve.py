@@ -30,6 +30,7 @@ from repro.core.latency import LatencyContext
 from repro.core.reward import RewardConfig
 from repro.core.search import CompressionSearch, SearchConfig
 from repro.data.pipeline import DataConfig, ShardedTokenDataset, bigram_lm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import decode_loop, sustained_throughput
 from repro.optim.optimizer import OptimizerConfig, adamw_init
 from repro.train.train_step import make_train_step
@@ -43,6 +44,7 @@ def main():
                     help="search episodes (default: 30, or 6 in smoke)")
     ap.add_argument("--target", type=float, default=0.5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # --steps 2 is the CI smoke: every stage shrinks with the budget
     smoke = args.steps <= 10

@@ -503,34 +503,38 @@ class _BatchedAccuracyMixin:
     def accuracy_batch(self, batch: dict, stacked_cspec) -> jnp.ndarray:
         """(K,) accuracies for K stacked cspecs — one vmap-of-jit call
         instead of K sequential jit dispatches."""
-        return self._acc_batch_fn(batch)(stacked_cspec)
+        return self._acc_batch_fn(batch)(self.params, stacked_cspec)
 
     def _acc_batch_fn(self, batch: dict):
         cached = getattr(self, "_acc_batch_cache", None)
-        if cached is not None and cached[0] is batch \
-                and cached[2] is self.params:
+        if cached is not None and cached[0] is batch:
             return cached[1]
-        fn = jax.jit(jax.vmap(lambda cs: self.accuracy(batch, cs)))
-        self._acc_batch_cache = (batch, fn, self.params)
+        fn = jax.jit(jax.vmap(lambda p, cs: self.accuracy(batch, cs, p),
+                              in_axes=(None, 0)))
+        self._acc_batch_cache = (batch, fn)
         return fn
 
     def accuracy_policy_fn(self, batch: dict):
-        """The pure traced-cspec validator: (K, L) int32 keep/w_bits/
-        a_bits arrays -> (K,) accuracies, un-jitted so callers can
-        inline it into a larger traced program (the epoch-fused engine
-        chains it inside its ``lax.scan`` body).
+        """The pure traced-cspec validator: ``(params, keep, w_bits,
+        a_bits)`` with (K, L) int32 policy arrays -> (K,) accuracies,
+        un-jitted so callers can inline it into a larger traced program
+        (the epoch-fused engine chains it inside its ``lax.scan`` body).
 
-        ``accuracy_policy_batch`` jits exactly this function; both share
-        one cache keyed on batch AND params identity — swapping in new
-        weights (e.g. after a QAT retrain) must re-trace, since the
-        traced builder bakes params and prune scores in as constants.
+        The weights are an argument, never a closed-over constant: a
+        jitted program that captured them would embed them in its HLO
+        (gigabytes at real model widths). ``accuracy_policy_batch`` jits
+        exactly this function; both share one cache keyed on batch AND
+        params identity, because the traced builder bakes the ℓ1 prune
+        scores of the current weights in as constants — swapping in new
+        weights (e.g. after a QAT retrain) must re-trace.
         """
         cached = getattr(self, "_acc_pb_cache", None)
         if cached is None or cached[0] is not batch \
                 or cached[3] is not self.params:
             build = self.cspec_builder()
             fn = jax.vmap(
-                lambda k, w, a: self.accuracy(batch, build(k, w, a)))
+                lambda p, k, w, a: self.accuracy(batch, build(k, w, a), p),
+                in_axes=(None, 0, 0, 0))
             self._acc_pb_cache = (batch, fn, jax.jit(fn), self.params)
             cached = self._acc_pb_cache
         return cached[1]
@@ -544,7 +548,8 @@ class _BatchedAccuracyMixin:
         no per-policy host-side cspec construction at all.
         """
         self.accuracy_policy_fn(batch)        # (re)fill the shared cache
-        return self._acc_pb_cache[2](jnp.asarray(pbatch.keep, jnp.int32),
+        return self._acc_pb_cache[2](self.params,
+                                     jnp.asarray(pbatch.keep, jnp.int32),
                                      jnp.asarray(pbatch.w_bits, jnp.int32),
                                      jnp.asarray(pbatch.a_bits, jnp.int32))
 
@@ -564,16 +569,19 @@ class CompressibleLM(_BatchedAccuracyMixin):
     def _make_cspec_builder(self):
         return make_lm_cspec_builder(self.cfg, self.params, self.specs)
 
-    def logits(self, batch: dict, cspec=None):
-        return M.forward(self.cfg, self.params, tokens=batch["tokens"],
+    def logits(self, batch: dict, cspec=None, params=None):
+        """``params`` overrides ``self.params``: jitted callers pass the
+        weights in rather than capture them as constants."""
+        params = self.params if params is None else params
+        return M.forward(self.cfg, params, tokens=batch["tokens"],
                          cspec=cspec)
 
-    def log_probs(self, batch: dict, cspec=None):
-        return jax.nn.log_softmax(self.logits(batch, cspec), -1)
+    def log_probs(self, batch: dict, cspec=None, params=None):
+        return jax.nn.log_softmax(self.logits(batch, cspec, params), -1)
 
-    def accuracy(self, batch: dict, cspec=None) -> jnp.ndarray:
+    def accuracy(self, batch: dict, cspec=None, params=None) -> jnp.ndarray:
         """Next-token top-1 accuracy."""
-        lg = self.logits(batch, cspec)[:, :-1]
+        lg = self.logits(batch, cspec, params)[:, :-1]
         tgt = batch["tokens"][:, 1:]
         return jnp.mean((jnp.argmax(lg, -1) == tgt).astype(jnp.float32))
 
@@ -621,14 +629,15 @@ class CompressibleResNet(_BatchedAccuracyMixin):
                         i += 1
         raise IndexError(idx)
 
-    def logits(self, batch: dict, cspec=None):
-        return R.forward(self.cfg, self.params, batch["images"], cspec)
+    def logits(self, batch: dict, cspec=None, params=None):
+        params = self.params if params is None else params
+        return R.forward(self.cfg, params, batch["images"], cspec)
 
-    def log_probs(self, batch: dict, cspec=None):
-        return jax.nn.log_softmax(self.logits(batch, cspec), -1)
+    def log_probs(self, batch: dict, cspec=None, params=None):
+        return jax.nn.log_softmax(self.logits(batch, cspec, params), -1)
 
-    def accuracy(self, batch: dict, cspec=None) -> jnp.ndarray:
-        lg = self.logits(batch, cspec)
+    def accuracy(self, batch: dict, cspec=None, params=None) -> jnp.ndarray:
+        lg = self.logits(batch, cspec, params)
         return jnp.mean((jnp.argmax(lg, -1) == batch["labels"])
                         .astype(jnp.float32))
 

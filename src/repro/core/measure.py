@@ -254,8 +254,19 @@ class CalibrationTable:
 
     @classmethod
     def load(cls, path: str) -> "CalibrationTable":
+        """Read a table; refuse one fitted on another backend, whose
+        factors describe a different machine (a table with no recorded
+        backend is taken as it is)."""
         with open(path) as f:
-            return cls.from_dict(json.load(f))
+            table = cls.from_dict(json.load(f))
+        fitted, running = table.meta.get("backend"), jax.default_backend()
+        if fitted is not None and fitted != running:
+            raise ValueError(
+                f"calibration table {path!r} was fitted on backend "
+                f"{fitted!r}, but JAX is running on {running!r}; refit it "
+                f"here with `python -m benchmarks.calibrate_oracle` or "
+                f"use oracle_mode='analytic'")
+        return table
 
 
 def load_calibration(path: Optional[str] = None) -> CalibrationTable:

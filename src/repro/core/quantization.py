@@ -112,7 +112,10 @@ def fake_quant(x: jnp.ndarray, bits, axis=None) -> jnp.ndarray:
         xq = dequantize(q, s, z)
         xq = jnp.where(jnp.asarray(bits) >= 32, xf, xq)
     # Straight-through estimator: forward quantized values, identity grad.
-    out = xf + jax.lax.stop_gradient(xq - xf)
+    # The pass-through selects ``xf`` itself: ``xf + (xf - xf)`` flushes
+    # denormals to zero, so 32 bits would not be the identity.
+    out = jnp.where(jnp.asarray(bits) >= 32, xf,
+                    xf + jax.lax.stop_gradient(xq - xf))
     return out.astype(orig_dtype)
 
 

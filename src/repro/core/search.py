@@ -112,12 +112,14 @@ from typing import List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.checkpoint.checkpointing import (AsyncCheckpointer, restore_latest,
                                             save_async)
 from repro.distributed.fault_tolerance import (FaultToleranceConfig,
                                                StepMonitor)
-from repro.distributed.sharding import pad_members, population_shardings
+from repro.distributed.sharding import (pad_members, population_shardings,
+                                       replicated)
 
 from repro.core.constraints import legal_tables
 from repro.core.ddpg import (_SCAN_UNROLL as _UPDATE_SCAN_UNROLL,
@@ -246,12 +248,13 @@ class CompressionSearch:
         # calibration batch — population members included)
         self.sens = sens if sens is not None else run_sensitivity(
             cmodel, calib_batch if calib_batch is not None else val_batch)
-        self._jit_acc = jax.jit(lambda cs: cmodel.accuracy(val_batch, cs))
+        self._jit_acc = jax.jit(
+            lambda p, cs: cmodel.accuracy(val_batch, cs, p))
         self.ref_policy = Policy.reference(self.specs)
         self.ref_lat = policy_latency(self.specs, self.ref_policy, hw, ctx,
                                       search_cfg.window, calib=self.calib)
         self.ref_acc = float(self._jit_acc(
-            cmodel.build_cspec(self.ref_policy)))
+            cmodel.params, cmodel.build_cspec(self.ref_policy)))
         self.steps = [i for i, s in enumerate(self.specs)
                       if _actionable(s, search_cfg.methods)]
         self._pending_updates = 0
@@ -300,7 +303,7 @@ class CompressionSearch:
         policy = partial
 
         cspec = self.cmodel.build_cspec(policy)
-        acc = float(self._jit_acc(cspec))
+        acc = float(self._jit_acc(self.cmodel.params, cspec))
         lat = policy_latency(self.specs, policy, self.hw, self.ctx,
                              cfg.window, calib=self.calib)
         reward = compute_reward(cfg.reward, acc, lat.total_s,
@@ -637,17 +640,19 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
     Everything member-specific is an argument, so a population can
     ``jit(vmap)`` one epoch function across stacked members.
 
-    Returns ``epoch(st, ring, rkey, keep0, wb0, ab0, sigmas, warmup,
-    hwp, shares, ref_total, cols, ref_total_s) -> (st, ring, rkey,
-    best, ys)`` with ``ys = (accs, lats, rewards, keep, wb, ab)``
+    Returns ``epoch(params, st, ring, rkey, keep0, wb0, ab0, sigmas,
+    warmup, hwp, shares, ref_total, cols, ref_total_s) -> (st, ring,
+    rkey, best, ys)``. ``params`` are the model weights ``acc_fn``
+    validates with (an argument, so no program embeds them);
+    ``ys = (accs, lats, rewards, keep, wb, ab)`` is
     stacked (E, ...) — the device-side metrics read back in one
     transfer — and ``best = (reward, episode offset, (keep, wb, ab))``
     the in-carry argmax over the epoch's E*K episodes.
     """
     segments = _schedule_segments(schedule)
 
-    def epoch(st, ring, rkey, keep0, wb0, ab0, sigmas, warmup, hwp,
-              shares, ref_total, cols, ref_total_s):
+    def epoch(params, st, ring, rkey, keep0, wb0, ab0, sigmas, warmup,
+              hwp, shares, ref_total, cols, ref_total_s):
         # trace-time sample-key schedule (zero runtime dispatches):
         # consume st.key exactly as E per-batch update_chunk calls would
         key = st.key
@@ -675,7 +680,7 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
                 # the normalizer advances at the batch boundary, exactly
                 # as the host engines' observe_states does
                 st = observe_states_pure(st, states.reshape(T * K, -1))
-                accs = acc_fn(keep.astype(jnp.int32),
+                accs = acc_fn(params, keep.astype(jnp.int32),
                               wb.astype(jnp.int32), ab.astype(jnp.int32))
                 rewards = compute_reward_batch(reward_cfg, accs, lats,
                                                ref_total_s)
@@ -912,7 +917,7 @@ class FusedCompressionSearch(BatchedCompressionSearch):
             (self.batch_size, schedule, id(params)),
             lambda h: h[0] is params,
             lambda: (params, jax.jit(self._make_epoch_fn(schedule),
-                                     donate_argnums=(0, 1))))
+                                     donate_argnums=(1, 2))))
         return hit[1]
 
     def run_epoch(self, first_episode: int,
@@ -925,7 +930,8 @@ class FusedCompressionSearch(BatchedCompressionSearch):
         self._flush_updates()          # epoch budgets are computed fresh
         schedule = self._update_schedule(first_episode, n_batches)
         fn = self._epoch_fn_for(schedule)
-        out = fn(*self._epoch_args(first_episode, n_batches))
+        out = fn(self.cmodel.params,
+                 *self._epoch_args(first_episode, n_batches))
         self.dispatch_log.append("epoch")
         return self._finish_epoch(first_episode, n_batches, out)
 
@@ -1052,6 +1058,17 @@ class PopulationSearch:
         makes every shared dispatch run one member per device."""
         return tree_stack(trees)
 
+    def _params_for_dispatch(self, params):
+        """The shared model weights as a shared dispatch takes them
+        (unbatched); ``FleetSearch`` replicates them over its mesh."""
+        return params
+
+    def _member_map(self, fn, in_axes=0):
+        """``fn`` mapped over the stacked member axis of its arguments
+        (``in_axes`` None marks an argument all members share).
+        ``FleetSearch`` runs the map per device under ``shard_map``."""
+        return jax.vmap(fn, in_axes=in_axes)
+
     def _rollouts_fusable(self) -> bool:
         """One vmapped rollout needs one traced step function: same spec
         list (identity — the oracle/legal/static tables bake into the
@@ -1080,7 +1097,7 @@ class PopulationSearch:
         stacked = self._stack_for_dispatch(args)
         if self._pop_rollout is None:
             self._pop_rollout = jax.jit(
-                jax.vmap(self.members[0]._rollout_fn))
+                self._member_map(self.members[0]._rollout_fn))
         outs = self._pop_rollout(*stacked)
         for m in self.members:     # ONE shared dispatch, logged on each
             m.dispatch_log.append("rollout")
@@ -1123,16 +1140,19 @@ class PopulationSearch:
         schedule = next(iter(scheds))
         m0 = self.members[0]
         params = m0.cmodel.params
+        args = [m._epoch_args(first_episode, n_batches)
+                for m in self.members]
         hit = fifo_cached(
             self._pop_epoch_cache, _EPOCH_CACHE_MAX,
             (m0.batch_size, schedule, id(params)),
             lambda h: h[0] is params,
             lambda: (params,
-                     jax.jit(jax.vmap(m0._make_epoch_fn(schedule)),
-                             donate_argnums=(0, 1))))
-        args = [m._epoch_args(first_episode, n_batches)
-                for m in self.members]
-        outs = hit[1](*self._stack_for_dispatch(args))
+                     jax.jit(self._member_map(
+                         m0._make_epoch_fn(schedule),
+                         in_axes=(None,) + (0,) * len(args[0])),
+                             donate_argnums=(1, 2))))
+        outs = hit[1](self._params_for_dispatch(params),
+                      *self._stack_for_dispatch(args))
         res = []
         for i, m in enumerate(self.members):
             m.dispatch_log.append("epoch")   # ONE shared dispatch
@@ -1230,11 +1250,14 @@ class FleetSearch(PopulationSearch):
     lives on one device, so P members time-slice it. ``FleetSearch``
     commits every stacked dispatch operand to a device mesh with
     ``NamedSharding(mesh, P("data"))`` along the member axis
-    (``_stack_for_dispatch``): the SAME program then executes one member
-    per device (members beyond the ``data`` extent round-robin; the stack
-    is padded up to a multiple of it by repeating the last member, whose
-    extra outputs are discarded). Per-member math never mixes member
-    rows, so the partitioned program contains no collectives.
+    (``_stack_for_dispatch``) and maps the epoch and rollout over it under
+    ``shard_map`` (``_member_map``; the weights are replicated): the SAME
+    program then executes one member per device (members beyond the
+    ``data`` extent round-robin; the stack is padded up to a multiple of
+    it by repeating the last member, whose extra outputs are discarded).
+    Per-member math never mixes member rows, so the program contains no
+    collectives — and the Pallas kernels inside it, which the compiler
+    cannot partition on its own, run per device as they are.
 
     Preemption safety: every ``ckpt_every`` completed epochs the stacked
     carry — ``AgentState``, ``DeviceReplay`` ring, rollout PRNG key per
@@ -1282,6 +1305,7 @@ class FleetSearch(PopulationSearch):
             if ckpt_dir else None
         self.epoch_cursor = 0      # episodes completed (per member)
         self.epochs_run = 0        # epoch dispatches completed
+        self._replicated = None    # (params, params replicated on mesh)
 
     # ------------------------------------------------------ mesh placement
     def _stack_for_dispatch(self, trees):
@@ -1291,6 +1315,34 @@ class FleetSearch(PopulationSearch):
                                          self.mesh.shape["data"]))
         return jax.device_put(stacked,
                               population_shardings(stacked, self.mesh))
+
+    def _member_map(self, fn, in_axes=0):
+        """Each device maps ``fn`` over its own slice of the members
+        under ``shard_map``: the Pallas kernels in the epoch and the
+        rollout cannot be partitioned automatically, and no member's
+        math reads another's, so the program needs no collectives."""
+        mapped = jax.vmap(fn, in_axes=in_axes)
+        if self.mesh is None:
+            return mapped
+        # check_vma off: carries the epoch builds from constants would
+        # need marking as per-device, and there is no collective to check
+        return jax.shard_map(
+            mapped, mesh=self.mesh, out_specs=PartitionSpec("data"),
+            in_specs=jax.tree.map(
+                lambda a: PartitionSpec() if a is None
+                else PartitionSpec("data"), in_axes,
+                is_leaf=lambda a: a is None),
+            check_vma=False)
+
+    def _params_for_dispatch(self, params):
+        """Replicate the weights over the mesh once per params object, so
+        an epoch dispatch does not copy them to every device again."""
+        if self.mesh is None:
+            return params
+        if self._replicated is None or self._replicated[0] is not params:
+            self._replicated = (params, jax.device_put(
+                params, replicated(self.mesh)))
+        return self._replicated[1]
 
     # ------------------------------------------------------- checkpointing
     def _fleet_carry(self) -> dict:

@@ -202,11 +202,12 @@ def _fused_kl_fn(cmodel, batch):
     swapping in new weights must re-trace, since the traced builder
     bakes params and prune scores in as constants).
 
-    Signature: ``(ref_k, ref_w, ref_a, keep, wb, ab) -> (P,) KLs`` with
-    the probe arrays pre-chunked to (n_chunks, C, L). The reference
-    log-probs are computed inside the same trace; the probe loop is a
-    ``lax.scan`` over chunks of C vmapped probes, so peak live memory is
-    C probe log-prob blocks, never P.
+    Signature: ``(params, ref_k, ref_w, ref_a, keep, wb, ab) -> (P,)
+    KLs`` with the probe arrays pre-chunked to (n_chunks, C, L); the
+    weights are an argument so the program does not embed them. The
+    reference log-probs are computed inside the same trace; the probe
+    loop is a ``lax.scan`` over chunks of C vmapped probes, so peak live
+    memory is C probe log-prob blocks, never P.
     """
     cached = getattr(cmodel, "_sens_kl_cache", None)
     if cached is not None and cached[0] is batch \
@@ -214,17 +215,17 @@ def _fused_kl_fn(cmodel, batch):
         return cached[2]
     build = cmodel.cspec_builder()
 
-    def one_kl(logp_o, k, w, a):
-        return kl_divergence(cmodel.log_probs(batch, build(k, w, a)),
-                             logp_o)
+    def fused(params, ref_k, ref_w, ref_a, keep, wb, ab):
+        def logp(k, w, a):
+            return cmodel.log_probs(batch, build(k, w, a), params)
 
-    def fused(ref_k, ref_w, ref_a, keep, wb, ab):
-        logp_o = cmodel.log_probs(batch, build(ref_k, ref_w, ref_a))
+        logp_o = logp(ref_k, ref_w, ref_a)
 
         def chunk(_, xs):
             k, w, a = xs
             return None, jax.vmap(
-                lambda kk, ww, aa: one_kl(logp_o, kk, ww, aa))(k, w, a)
+                lambda kk, ww, aa: kl_divergence(logp(kk, ww, aa),
+                                                 logp_o))(k, w, a)
 
         _, kls = jax.lax.scan(chunk, None, (keep, wb, ab))
         return kls.reshape(-1)
@@ -273,7 +274,7 @@ def _plan_kls(cmodel, batch, plan: ProbePlan, chunk: int) -> np.ndarray:
 
     fn = _fused_kl_fn(cmodel, batch)
     ref = tuple(jnp.asarray(r, jnp.int32) for r in plan.ref)
-    kls = _fused_dispatch(fn, *ref,
+    kls = _fused_dispatch(fn, cmodel.params, *ref,
                           prep(uniq[:, :L], plan.ref[0]),
                           prep(uniq[:, L:2 * L], plan.ref[1]),
                           prep(uniq[:, 2 * L:], plan.ref[2]))
@@ -348,7 +349,7 @@ def _seq_logprobs_fn(cmodel, batch):
     if cached is not None and cached[0] is batch \
             and cached[1] is cmodel.params:
         return cached[2]
-    fn = jax.jit(lambda cs: cmodel.log_probs(batch, cs))
+    fn = jax.jit(lambda p, cs: cmodel.log_probs(batch, cs, p))
     cmodel._sens_seq_cache = (batch, cmodel.params, fn)
     return fn
 
@@ -356,13 +357,13 @@ def _seq_logprobs_fn(cmodel, batch):
 def _plan_kls_sequential(cmodel, batch, plan: ProbePlan) -> np.ndarray:
     specs = cmodel.specs
     jit_lp = _seq_logprobs_fn(cmodel, batch)
-    logp_o = _seq_eval(jit_lp,
-                       cmodel.build_cspec(Policy.reference(specs)))
+    lp = lambda cs: jit_lp(cmodel.params, cs)
+    logp_o = _seq_eval(lp, cmodel.build_cspec(Policy.reference(specs)))
     pols = policies_from_batch(specs, PolicyBatch(
         keep=plan.keep, w_bits=plan.w_bits, a_bits=plan.a_bits))
     out = np.empty(len(pols), np.float64)
     for p, pol in enumerate(pols):
-        logp_c = _seq_eval(jit_lp, cmodel.build_cspec(pol))
+        logp_c = _seq_eval(lp, cmodel.build_cspec(pol))
         out[p] = float(kl_divergence(logp_c, logp_o))
     return out
 

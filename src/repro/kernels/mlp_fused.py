@@ -27,6 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _mlp3_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
@@ -82,27 +83,26 @@ def mlp3(x, w1, b1, w2, b2, w3, b3, *, sigmoid: bool = False,
     )(x, w1, b1, w2, b2, w3, b3)
 
 
-def _polyak_kernel(t_ref, p_ref, tau_ref, o_ref):
-    tau = tau_ref[0]
-    o_ref[...] = (1.0 - tau) * t_ref[...] + tau * p_ref[...]
+def _polyak_kernel(tau_ref, t_ref, p_ref, o_ref):
+    tau = tau_ref[0, 0]
+    o_ref[...] = ((1.0 - tau) * t_ref[...]
+                  + tau * p_ref[...]).astype(o_ref.dtype)
 
 
-def polyak_flat(target, online, tau, *, br: int = 256,
-                interpret: bool = True):
+def polyak_flat(target, online, tau, *, br: int, interpret: bool = True):
     """``(1 - tau) * target + tau * online`` over [R, 128] flat views —
-    the whole network's soft-target update as one kernel pass."""
+    the whole network's soft-target update as one kernel pass. ``br``
+    (a multiple of 8, or R itself) must divide R; ``tau`` is an f32
+    scalar in SMEM."""
     R, C = target.shape
-    br = min(br, R)
-    while R % br != 0:
-        br -= 1
-    tau_arr = jnp.reshape(jnp.asarray(tau, target.dtype), (1,))
+    tau_arr = jnp.reshape(jnp.asarray(tau, jnp.float32), (1, 1))
     return pl.pallas_call(
         _polyak_kernel,
         grid=(R // br,),
-        in_specs=[pl.BlockSpec((br, C), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec((br, C), lambda i: (i, 0)),
-                  pl.BlockSpec((1,), lambda i: (0,))],
+                  pl.BlockSpec((br, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), target.dtype),
         interpret=interpret,
-    )(target, online, tau_arr)
+    )(tau_arr, target, online)

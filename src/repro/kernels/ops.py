@@ -21,6 +21,10 @@ from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
 
 
+_LANE = 128      # f32 lane multiple (last axis)
+_SUBLANE = 8     # f32 sublane multiple (second-to-last axis)
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -60,13 +64,42 @@ def quantized_matmul(x: jnp.ndarray, w: jnp.ndarray, w_bits: int = 8,
     return y[:M, :N]
 
 
-@functools.partial(jax.jit, static_argnames=())
+_FQ_MAX_BR = 512    # fake-quant row block: <= 1 MiB of f32 at bc = 512
+_FQ_MIN_BR = 64
+
+
+def _fq_row_block(R: int) -> int:
+    """Row block for ``fake_quant_2d``: all of R when it fits one block,
+    else the largest multiple of 8 in [_FQ_MIN_BR, _FQ_MAX_BR] dividing
+    R, else _FQ_MAX_BR (the wrapper then pads R)."""
+    if R <= _FQ_MAX_BR:
+        return R
+    for br in range(_FQ_MAX_BR, _FQ_MIN_BR - 1, -_SUBLANE):
+        if R % br == 0:
+            return br
+    return _FQ_MAX_BR
+
+
+@jax.jit
 def fused_fake_quant(x: jnp.ndarray, bits) -> jnp.ndarray:
-    """Per-channel (last axis) fake quant of an arbitrary-rank tensor."""
+    """Per-channel (last axis) fake quant of an arbitrary-rank tensor.
+
+    Channels are zero-padded to a lane multiple and rows to a row-block
+    multiple by repeating the last row; both are exact, since a channel's
+    range reduces over its own rows only and a repeated row changes no
+    min or max. The padding is sliced off the result."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    out = _fq.fake_quant_2d(x2, bits, interpret=not _on_tpu())
-    return out.reshape(shape)
+    R, C = x2.shape
+    br = _fq_row_block(R)
+    xp = _pad_to(x2, _LANE, 1)
+    if R % br:
+        xp = jnp.pad(xp, ((0, (-R) % br), (0, 0)), mode="edge")
+    Cp = xp.shape[1]
+    bc = next(b for b in (512, 384, 256, _LANE) if Cp % b == 0)
+    out = _fq.fake_quant_2d(xp, bits, br=br, bc=bc,
+                            interpret=not _on_tpu())
+    return out[:R, :C].reshape(shape)
 
 
 # --------------------------------------------------------------------------
@@ -76,10 +109,6 @@ def fused_fake_quant(x: jnp.ndarray, bits) -> jnp.ndarray:
 # no differentiation rule), so ``jax.grad`` through ``actor_forward`` /
 # ``critic_forward`` works unchanged when the kernel path is routed.
 # --------------------------------------------------------------------------
-
-_LANE = 128      # f32 lane multiple (last axis)
-_SUBLANE = 8     # f32 sublane multiple (second-to-last axis)
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _mlp3_ste(sigmoid: bool, x, w1, b1, w2, b2, w3, b3):
@@ -140,6 +169,9 @@ def fused_mlp3(params, x, final: str = "linear") -> jnp.ndarray:
                      l2["w"], l2["b"], l3["w"], l3["b"])
 
 
+_POLYAK_BR = 256    # polyak row block (rows of 128 lanes)
+
+
 def fused_polyak(target, online, tau):
     """Soft-target update ``(1 - tau) * target + tau * online`` for an
     arbitrary pytree: both trees are flattened into ONE [R, 128] buffer,
@@ -151,12 +183,15 @@ def fused_polyak(target, online, tau):
     flat_t = jnp.concatenate([l.reshape(-1) for l in t_leaves])
     flat_p = jnp.concatenate([l.reshape(-1) for l in p_leaves])
     n = flat_t.shape[0]
-    pad = (-n) % _LANE
+    # rows of 128 lanes, padded to a whole number of row blocks
+    rows = -(-n // _LANE)
+    br = _POLYAK_BR if rows > _POLYAK_BR else -(-rows // _SUBLANE) * _SUBLANE
+    pad = (-(-rows // br) * br) * _LANE - n
     if pad:
         flat_t = jnp.pad(flat_t, (0, pad))
         flat_p = jnp.pad(flat_p, (0, pad))
     out = _mlp.polyak_flat(flat_t.reshape(-1, _LANE),
-                           flat_p.reshape(-1, _LANE), tau,
+                           flat_p.reshape(-1, _LANE), tau, br=br,
                            interpret=not _on_tpu()).reshape(-1)[:n]
     offs, news = 0, []
     for leaf, size in zip(t_leaves, sizes):

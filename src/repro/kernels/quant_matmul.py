@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 DEFAULT_BM = 256
 DEFAULT_BK = 256
@@ -154,7 +153,7 @@ def quant_matmul(xq, wq, sx, zx, sw, zw, *, packed: bool = False,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((bm,), jnp.int32),
                         pltpu.VMEM((bn,), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, wq, sx, zx, sw, zw)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, state_ref, *, bs: int):
     t = pl.program_id(2)
@@ -63,7 +61,7 @@ def rglru_scan(a, b, h0=None, *, bs: int = 128, bc: int = 1024,
         out_specs=pl.BlockSpec((1, bs, bc), lambda bi, ci, ti: (bi, ti, ci)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), a.dtype),
         scratch_shapes=[pltpu.VMEM((bc,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

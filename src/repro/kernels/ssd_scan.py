@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, o_ref, fin_ref, state_ref, *,
                 chunk: int):
@@ -92,7 +90,7 @@ def ssd_scan(xh, dA, Bm, Cm, *, chunk: int = 256, interpret: bool = True):
         out_shape=[jax.ShapeDtypeStruct((B, S, H, P), xh.dtype),
                    jax.ShapeDtypeStruct((B, H, P, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dA, Bm, Cm)

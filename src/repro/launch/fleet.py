@@ -38,6 +38,7 @@ from repro.core.reward import RewardConfig
 from repro.core.search import (FleetSearch, FusedCompressionSearch,
                                SearchConfig)
 from repro.distributed.fault_tolerance import elastic_data_axis
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_dev_mesh
 
 
@@ -139,6 +140,7 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                     help="print a JSON result blob on the last line")
     ap.add_argument("--verbose", action="store_true")
     a = ap.parse_args(argv)
+    enable_compile_cache()
 
     fleet = tiny_fleet(members=a.members, data=a.data, methods=a.methods,
                        batch_size=a.batch_size,

@@ -14,12 +14,21 @@ Topology (TPU v5e): 16×16 chips per pod (256), ICI within a pod; the
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding rules here place
+    arrays with ``with_sharding_constraint`` / ``NamedSharding``, which
+    ``Explicit`` axes (the default of ``jax.make_mesh`` in current JAX)
+    reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_dev_mesh(data: int = 1, model: int = 1):
@@ -39,4 +48,4 @@ def make_dev_mesh(data: int = 1, model: int = 1):
             f"{need} (must be set before jax initializes), or shrink the "
             "mesh — e.g. distributed.fault_tolerance.elastic_data_axis "
             "picks the largest data axis the surviving devices support.")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

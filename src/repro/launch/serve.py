@@ -13,6 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models.registry import get_config
 from repro.train.train_step import make_serve_step
@@ -79,6 +80,7 @@ def main():
                     help="also measure steady-state tok/s over N "
                          "back-to-back batched requests")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.is_encoder:
@@ -99,8 +101,10 @@ def main():
     tokens, dt = decode_loop(cfg, params, args.batch, args.steps,
                              args.max_len, cspec)
     tps = args.batch * args.steps / dt
+    dev = jax.devices()[0]
     print(f"[serve] {args.arch}: {args.steps} steps x batch {args.batch} "
-          f"in {dt:.2f}s -> {tps:.1f} tok/s (CPU)")
+          f"in {dt:.2f}s -> {tps:.1f} tok/s "
+          f"({dev.platform} {dev.device_kind})")
     print("[serve] sample:", tokens[0, :16].tolist())
 
     if args.sustained > 0:

@@ -16,6 +16,7 @@ import jax
 
 from repro.data.pipeline import DataConfig, Prefetcher, ShardedTokenDataset
 from repro.distributed.fault_tolerance import StepTimeout
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_config
 from repro.optim.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -38,6 +39,7 @@ def main():
                     help="cosine|wsd|constant (default: per-arch)")
     ap.add_argument("--max-retries", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     # per-arch schedule default: MiniCPM trains with WSD (arXiv:2404.06395)

@@ -16,11 +16,8 @@ The contract proved here (mirrors PR 1's rollout-parity suite):
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -47,8 +47,9 @@ SUBPROC = textwrap.dedent("""
     import repro.launch.dryrun as DR
     import repro.models.registry as REG
     import repro.configs.base as CB
+    from repro.launch.mesh import make_dev_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_dev_mesh(data=2, model=4)
     orig = REG.get_config
     DR.get_config = lambda a, smoke=False: orig(a, smoke=True).replace(
         scan_layers=orig(a).scan_layers)

@@ -22,11 +22,8 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.ddpg import DDPGConfig
 from repro.core.latency import HardwareTarget, LatencyContext, V5E
@@ -42,8 +39,8 @@ _testbed_cache = {}
 
 def _testbed():
     """Module-cached twin of the ``tiny_lm`` fixture for the
-    ``@given`` property tests (the _propcheck shim fills strategy
-    parameters positionally and cannot mix with pytest fixtures)."""
+    ``@given`` property tests (hypothesis cannot mix strategy
+    parameters with function-scoped pytest fixtures)."""
     if "lm" not in _testbed_cache:
         from repro.configs.base import ArchConfig
         from repro.core.compress import CompressibleLM

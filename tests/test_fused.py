@@ -18,11 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import ArchConfig
 from repro.core import latency as latency_mod

@@ -340,3 +340,51 @@ def test_fused_polyak_nested_tree():
     ref_out = jax.tree.map(lambda t, p: 0.5 * t + 0.5 * p, target, online)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref_out)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
+
+
+def _assert_fq_close(got, want, x, bits):
+    """Equal to 1e-5 except where ``floor`` sits on a level boundary:
+    compiled apart (kernel vs. fused XLA, with or without an FMA), the
+    two may land one quantization level apart there. Allow that on at
+    most 1e-5 of the elements, and never more than one level."""
+    got, want, x = (np.asarray(a, np.float64) for a in (got, want, x))
+    diff = np.abs(got - want)
+    if bits >= 32:
+        assert diff.max() == 0.0
+        return
+    step = (x.max(0) - x.min(0)) / (2.0 ** bits - 1)
+    assert np.all(diff <= step * 1.001 + 1e-5), diff.max()
+    assert np.mean(diff > 1e-5) <= 1e-5, np.sum(diff > 1e-5)
+
+
+# Qwen2-0.5B widths (d_model 896, d_ff 4864), an activation slab taller
+# than one row block, and a row count with no aligned divisor (padded)
+@pytest.mark.parametrize("shape", [(896, 896), (896, 4864), (4864, 896),
+                                   (8192, 896), (1100, 200)])
+@pytest.mark.parametrize("bits", [4, 8])
+def test_fake_quant_kernel_padded_widths(shape, bits):
+    x = jax.random.normal(jax.random.PRNGKey(bits), shape)
+    _assert_fq_close(ops.fused_fake_quant(x, bits),
+                     ref.fake_quant_ref(x, bits), x, bits)
+
+
+def test_fake_quant_kernel_vmapped_bits():
+    """The search vmaps the bit width over K policies of one weight."""
+    x = jax.random.normal(jax.random.PRNGKey(5), (896, 4864))
+    bits = jnp.array([2, 4, 8, 32], jnp.int32)
+    got = jax.vmap(lambda b: ops.fused_fake_quant(x, b))(bits)
+    for i, b in enumerate([2, 4, 8, 32]):
+        _assert_fq_close(got[i], ref.fake_quant_ref(x, b), x, b)
+
+
+@pytest.mark.parametrize("dims", [(16, 400, 300, 1),     # actor: 996 rows
+                                  (17, 400, 300, 1)])    # critic: 999 rows
+def test_fused_polyak_paper_sizes(dims):
+    from repro.core.ddpg import _mlp_init
+    target = _mlp_init(jax.random.PRNGKey(12), dims)
+    online = _mlp_init(jax.random.PRNGKey(13), dims)
+    out = ops.fused_polyak(target, online, 0.01)
+    ref_out = jax.tree.map(lambda t, p: 0.99 * t + 0.01 * p, target, online)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref_out)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)

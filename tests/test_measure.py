@@ -13,8 +13,9 @@ from repro.core.latency import (CONTAINERS, LatencyContext, V5E,
                                 policy_latency, policy_latency_batch)
 from repro.core.measure import (CalibrationTable, MeasureConfig,
                                 deploy_policy_params, fit_calibration,
-                                fit_extra_factor, measure_policy,
-                                policy_bits_by_name, uniform_policy)
+                                fit_extra_factor, load_calibration,
+                                measure_policy, policy_bits_by_name,
+                                uniform_policy)
 from repro.core.policy import Policy
 from repro.core.spec import LayerCMP
 from repro.models import model as M
@@ -63,6 +64,23 @@ def test_table_roundtrip(tmp_path, cm):
     assert back.extra_factor() == pytest.approx(1.4)
     assert back.overhead_factor() == pytest.approx(1.4)
     assert back.meta["note"] == "test"
+
+
+def test_table_load_refuses_other_backend(tmp_path, monkeypatch):
+    """A table fitted on one backend is refused on another; the committed
+    CPU table stays valid on the CPU."""
+    assert load_calibration().meta["backend"] == jax.default_backend()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="fitted on backend 'cpu'"):
+        load_calibration()
+    t = CalibrationTable(ratios={"mlp_up": {"int8": 2.0}},
+                         meta={"backend": "tpu"})
+    p = str(tmp_path / "tpu.json")
+    t.save(p)
+    assert CalibrationTable.load(p).factor("mlp_up", "int8") == 2.0
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    with pytest.raises(ValueError, match="running on 'cpu'"):
+        CalibrationTable.load(p)
 
 
 def test_table_defaults_and_unit_factors(cm):

@@ -2,11 +2,8 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import constraints
 from repro.core.policy import (T_INT8, T_MIX, Policy, d_inverse, map_actions,

@@ -3,11 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.pruning import (head_scores, keep_mask, keep_mask_dynamic,
                                 l1_scores, slice_indices)

@@ -4,12 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    from hypothesis.extra import numpy as hnp
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, settings, st, hnp
+from hypothesis import given, settings
+from hypothesis import strategies as st
+from hypothesis.extra import numpy as hnp
 
 from repro.core.quantization import fake_quant, fake_quant_weight, quantize
 

@@ -2,11 +2,8 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given
-    from hypothesis import strategies as st
-except ImportError:                      # seeded-random fallback shim
-    from _propcheck import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core.reward import (RewardConfig, absolute_reward,
                                compute_reward, compute_reward_batch,
