@@ -1,0 +1,10 @@
+"""Device milliseconds per traced epoch under the ``update`` scope of
+the search's epoch program: the DDPG update scan. Each op is charged to
+the innermost stage scope in its name stack by the self-time rule of
+``Trace.op_seconds`` (``chipbench/stages.py``)."""
+from chipbench import stages
+
+
+def read(ctx):
+    ms = stages.epoch_stages_ms(ctx)
+    return None if ms is None else ms["update"]

@@ -102,21 +102,24 @@ def fake_quant(x: jnp.ndarray, bits, axis=None) -> jnp.ndarray:
     if axis is None:
         axis = tuple(range(x.ndim - 1))
     orig_dtype = x.dtype
-    xf = x.astype(jnp.float32)
-    if _kernel_route(x, axis):
-        # one-pass fused minmax/quant/dequant (bits >= 32 selects
-        # pass-through inside the kernel)
-        xq = _fused_fake_quant_ste(xf, jnp.asarray(bits, jnp.int32))
-    else:
-        q, s, z = quantize(xf, jnp.clip(jnp.asarray(bits), 1, 31), axis)
-        xq = dequantize(q, s, z)
-        xq = jnp.where(jnp.asarray(bits) >= 32, xf, xq)
-    # Straight-through estimator: forward quantized values, identity grad.
-    # The pass-through selects ``xf`` itself: ``xf + (xf - xf)`` flushes
-    # denormals to zero, so 32 bits would not be the identity.
-    out = jnp.where(jnp.asarray(bits) >= 32, xf,
-                    xf + jax.lax.stop_gradient(xq - xf))
-    return out.astype(orig_dtype)
+    # the scope tags these ops in a profiler trace (metadata only)
+    with jax.named_scope("fake_quant"):
+        xf = x.astype(jnp.float32)
+        if _kernel_route(x, axis):
+            # one-pass fused minmax/quant/dequant (bits >= 32 selects
+            # pass-through inside the kernel)
+            xq = _fused_fake_quant_ste(xf, jnp.asarray(bits, jnp.int32))
+        else:
+            q, s, z = quantize(xf, jnp.clip(jnp.asarray(bits), 1, 31),
+                               axis)
+            xq = dequantize(q, s, z)
+            xq = jnp.where(jnp.asarray(bits) >= 32, xf, xq)
+        # Straight-through estimator: forward quantized values, identity
+        # grad. The pass-through selects ``xf`` itself: ``xf + (xf - xf)``
+        # flushes denormals to zero, so 32 bits would not be the identity.
+        out = jnp.where(jnp.asarray(bits) >= 32, xf,
+                        xf + jax.lax.stop_gradient(xq - xf))
+        return out.astype(orig_dtype)
 
 
 def fake_quant_weight(w: jnp.ndarray, bits) -> jnp.ndarray:

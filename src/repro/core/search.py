@@ -138,6 +138,7 @@ from repro.core.replay import (DeviceReplay, device_replay_push,
 from repro.core.reward import RewardConfig, compute_reward, \
     compute_reward_batch
 from repro.core.sensitivity import SensitivityResult, run_sensitivity
+from repro.core import spans
 from repro.core.spec import effective_bits
 from repro.core.state import (StateTables, build_state, build_state_batch,
                               fused_state_block, state_dim)
@@ -674,33 +675,41 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
                 (e, sig, warm), skeys = x[:3], (x[3] if n > 0 else None)
                 rk, bk = jax.random.split(rk)
                 keys = jax.random.split(bk, T)
-                keep, wb, ab, states, actions, lats = rollout_fn(
-                    st, keep0, wb0, ab0, sig, warm, hwp, shares,
-                    ref_total, cols, keys)
-                # the normalizer advances at the batch boundary, exactly
-                # as the host engines' observe_states does
-                st = observe_states_pure(st, states.reshape(T * K, -1))
-                accs = acc_fn(params, keep.astype(jnp.int32),
-                              wb.astype(jnp.int32), ab.astype(jnp.int32))
-                rewards = compute_reward_batch(reward_cfg, accs, lats,
-                                               ref_total_s)
+                # the named scopes tag each stage's device ops in the
+                # profiler trace (metadata only; the ops are the same)
+                with jax.named_scope("rollout"):
+                    keep, wb, ab, states, actions, lats = rollout_fn(
+                        st, keep0, wb0, ab0, sig, warm, hwp, shares,
+                        ref_total, cols, keys)
+                    # the normalizer advances at the batch boundary,
+                    # exactly as the host engines' observe_states does
+                    st = observe_states_pure(st, states.reshape(T * K, -1))
+                with jax.named_scope("validation"):
+                    accs = acc_fn(params, keep.astype(jnp.int32),
+                                  wb.astype(jnp.int32),
+                                  ab.astype(jnp.int32))
+                with jax.named_scope("reward"):
+                    rewards = compute_reward_batch(reward_cfg, accs, lats,
+                                                   ref_total_s)
                 order = lambda z: jnp.swapaxes(z, 0, 1).reshape(
                     T * K, *z.shape[2:])
-                nxt = jnp.concatenate([states[1:], states[-1:]])
-                done = jnp.zeros((T, K), jnp.float32).at[-1].set(1.0)
-                ring = device_replay_push(
-                    ring, order(states), order(actions),
-                    jnp.repeat(rewards, T).astype(jnp.float32),
-                    order(nxt), order(done))
+                with jax.named_scope("replay_push"):
+                    nxt = jnp.concatenate([states[1:], states[-1:]])
+                    done = jnp.zeros((T, K), jnp.float32).at[-1].set(1.0)
+                    ring = device_replay_push(
+                        ring, order(states), order(actions),
+                        jnp.repeat(rewards, T).astype(jnp.float32),
+                        order(nxt), order(done))
                 if n > 0:     # this batch's update chunk, in-scan
                     def ustep(c, k2):
                         batch = device_replay_sample(ring, k2,
                                                      cfg.batch_size)
                         return update_step(cfg, c, batch)
 
-                    st, _losses = jax.lax.scan(
-                        ustep, st, skeys,
-                        unroll=min(_UPDATE_SCAN_UNROLL, n))
+                    with jax.named_scope("update"):
+                        st, _losses = jax.lax.scan(
+                            ustep, st, skeys,
+                            unroll=min(_UPDATE_SCAN_UNROLL, n))
                 # in-carry best-policy tracking; strict > keeps the
                 # earliest argmax, the rule run()'s host loop applies
                 j = jnp.argmax(rewards)
@@ -800,6 +809,7 @@ class FusedCompressionSearch(BatchedCompressionSearch):
         self.epoch_batches = max(0, epoch_batches)
         self._epoch_cache: dict = {}
         self.last_epoch_best: Optional[tuple] = None
+        spans.watch_gc()
 
     # ------------------------------------------------------------------
     def _rollout_args(self, first_episode: int, k: int) -> tuple:
@@ -924,22 +934,27 @@ class FusedCompressionSearch(BatchedCompressionSearch):
                   n_batches: int) -> List[EpisodeRecord]:
         """E episode batches — rollout, validation, reward, ring write,
         updates, metrics — as ONE jit execution, then ONE host readback
-        that rehydrates the records in bulk."""
+        that rehydrates the records in bulk. Each host phase is a
+        ``search.epoch.*`` span (``core/spans.py``)."""
         if n_batches <= 0:
             return []
-        self._flush_updates()          # epoch budgets are computed fresh
-        schedule = self._update_schedule(first_episode, n_batches)
-        fn = self._epoch_fn_for(schedule)
-        out = fn(self.cmodel.params,
-                 *self._epoch_args(first_episode, n_batches))
-        self.dispatch_log.append("epoch")
-        return self._finish_epoch(first_episode, n_batches, out)
+        with spans.span("search.epoch", first_episode=first_episode):
+            with spans.span("search.epoch.args",
+                            first_episode=first_episode):
+                self._flush_updates()   # epoch budgets are computed fresh
+                schedule = self._update_schedule(first_episode, n_batches)
+                fn = self._epoch_fn_for(schedule)
+                args = self._epoch_args(first_episode, n_batches)
+            with spans.span("search.epoch.dispatch",
+                            first_episode=first_episode):
+                out = fn(self.cmodel.params, *args)
+            self.dispatch_log.append("epoch")
+            return self._finish_epoch(first_episode, n_batches, out)
 
     def _finish_epoch(self, first_episode: int, n_batches: int,
                       out: tuple) -> List[EpisodeRecord]:
         """Adopt the carried state/ring/PRNG, do the epoch's single
         device->host transfer, and build the records."""
-        cfg = self.cfg
         K, T = self.batch_size, len(self.steps)
         st, ring, rkey, best, ys = out
         self.replay.adopt(ring, n_batches * T * K)
@@ -948,10 +963,24 @@ class FusedCompressionSearch(BatchedCompressionSearch):
         accs, lats, rewards, keep, wb, ab = ys
         # THE one host readback per epoch: metrics, policies, the norm
         # stats, and the in-carry best — records need no device values
-        got = jax.device_get(
-            (accs, lats, rewards, keep, wb, ab,
-             (st.norm_count, st.norm_mean, st.norm_var),
-             (best[0], best[1])))
+        host = (accs, lats, rewards, keep, wb, ab,
+                (st.norm_count, st.norm_mean, st.norm_var),
+                (best[0], best[1]))
+        # the wait is its own span, so the readback span is the copy
+        with spans.span("search.epoch.wait", first_episode=first_episode):
+            jax.block_until_ready(host)
+        with spans.span("search.epoch.readback",
+                        first_episode=first_episode):
+            got = jax.device_get(host)
+        with spans.span("search.epoch.records",
+                        first_episode=first_episode):
+            return self._epoch_records(first_episode, n_batches, got)
+
+    def _epoch_records(self, first_episode: int, n_batches: int,
+                       got: tuple) -> List[EpisodeRecord]:
+        """The epoch's ``EpisodeRecord``s from its host readback."""
+        cfg = self.cfg
+        K = self.batch_size
         accs, lats, rewards, keep, wb, ab, norm, best_hv = got
         self.agent.norm.count = float(norm[0])
         self.agent.norm.mean = np.asarray(norm[1], np.float32)
@@ -1130,14 +1159,33 @@ class PopulationSearch:
         """
         if n_batches <= 0:
             return [[] for _ in self.members]
-        for m in self.members:
-            m._flush_updates()
-        scheds = {m._update_schedule(first_episode, n_batches)
-                  for m in self.members}
-        if len(scheds) != 1 or not self._epochs_fusable():
-            return [m.run_epoch(first_episode, n_batches)
-                    for m in self.members]
-        schedule = next(iter(scheds))
+        with spans.span("search.epoch", first_episode=first_episode):
+            with spans.span("search.epoch.args",
+                            first_episode=first_episode):
+                for m in self.members:
+                    m._flush_updates()
+                scheds = {m._update_schedule(first_episode, n_batches)
+                          for m in self.members}
+                fused = len(scheds) == 1 and self._epochs_fusable()
+                if fused:
+                    fn, args = self._epoch_call(first_episode, n_batches,
+                                                next(iter(scheds)))
+            if not fused:
+                return [m.run_epoch(first_episode, n_batches)
+                        for m in self.members]
+            with spans.span("search.epoch.dispatch",
+                            first_episode=first_episode):
+                outs = fn(*args)
+            res = []
+            for i, m in enumerate(self.members):
+                m.dispatch_log.append("epoch")   # ONE shared dispatch
+                res.append(m._finish_epoch(first_episode, n_batches,
+                                           tree_index(outs, i)))
+            return res
+
+    def _epoch_call(self, first_episode: int, n_batches: int,
+                    schedule: tuple) -> tuple:
+        """(the compiled population epoch, its stacked arguments)."""
         m0 = self.members[0]
         params = m0.cmodel.params
         args = [m._epoch_args(first_episode, n_batches)
@@ -1151,14 +1199,8 @@ class PopulationSearch:
                          m0._make_epoch_fn(schedule),
                          in_axes=(None,) + (0,) * len(args[0])),
                              donate_argnums=(1, 2))))
-        outs = hit[1](self._params_for_dispatch(params),
-                      *self._stack_for_dispatch(args))
-        res = []
-        for i, m in enumerate(self.members):
-            m.dispatch_log.append("epoch")   # ONE shared dispatch
-            res.append(m._finish_epoch(first_episode, n_batches,
-                                       tree_index(outs, i)))
-        return res
+        return hit[1], (self._params_for_dispatch(params),
+                        *self._stack_for_dispatch(args))
 
     def _run_epoch_chunk(self, first_episode: int,
                          k: int) -> List[List[EpisodeRecord]]:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.constraints import legalize
 from repro.core.latency import fifo_cached
 from repro.core.policy import (Policy, PolicyBatch, policies_from_batch,
@@ -312,7 +313,8 @@ def run_sensitivity(cmodel, batch, chunk: int = DEFAULT_CHUNK,
     plan = feature_probe_plan(cmodel.specs)
 
     def compute():
-        kls = _plan_kls(cmodel, batch, plan, chunk)
+        with spans.span("sensitivity"):
+            kls = _plan_kls(cmodel, batch, plan, chunk)
         return (batch, cmodel.params,
                 _result_from_plan(cmodel.specs, plan, kls))
 

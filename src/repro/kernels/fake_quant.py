@@ -77,6 +77,7 @@ def fake_quant_2d(x: jnp.ndarray, bits, *, br: int, bc: int,
         out_shape=[jax.ShapeDtypeStruct((1, C), jnp.float32),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)],
         interpret=interpret,
+        name="fake_quant_range",
     )(x)
     bits_arr = jnp.reshape(jnp.asarray(bits, jnp.int32), (1, 1))
     return pl.pallas_call(
@@ -89,4 +90,5 @@ def fake_quant_2d(x: jnp.ndarray, bits, *, br: int, bc: int,
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
         interpret=interpret,
+        name="fake_quant_apply",
     )(bits_arr, x, mn, mx)

@@ -80,6 +80,7 @@ def mlp3(x, w1, b1, w2, b2, w3, b3, *, sigmoid: bool = False,
                    jax.ShapeDtypeStruct((B, D1), x.dtype),
                    jax.ShapeDtypeStruct((B, D2), x.dtype)],
         interpret=interpret,
+        name="mlp3",
     )(x, w1, b1, w2, b2, w3, b3)
 
 
@@ -105,4 +106,5 @@ def polyak_flat(target, online, tau, *, br: int, interpret: bool = True):
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), target.dtype),
         interpret=interpret,
+        name="polyak",
     )(tau_arr, target, online)
