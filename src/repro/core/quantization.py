@@ -27,29 +27,34 @@ _fq_ops = None          # lazy kernels.ops handle (kernels import late —
                         # the kernel package must not load at model-import)
 
 
-@jax.custom_jvp
-def _fused_fake_quant_ste(xf: jnp.ndarray, bits) -> jnp.ndarray:
-    """Kernel-backed quant-dequant with a straight-through JVP —
-    ``pallas_call`` has no differentiation rule, so the identity
-    tangent (exactly the STE) is attached here and ``jax.grad`` never
-    traces into the kernel."""
+def _kernel_ops():
     global _fq_ops
     if _fq_ops is None:
         from repro.kernels import ops
         _fq_ops = ops
-    return _fq_ops.fused_fake_quant(xf, bits)
+    return _fq_ops
 
 
-@_fused_fake_quant_ste.defjvp
-def _fused_fake_quant_ste_jvp(primals, tangents):
-    return _fused_fake_quant_ste(*primals), tangents[0]
+@jax.custom_jvp
+def _fake_quant_apply_ste(xf: jnp.ndarray, bits, mn: jnp.ndarray,
+                          mx: jnp.ndarray) -> jnp.ndarray:
+    """The kernel's quantize-dequantize pass with a straight-through JVP
+    — ``pallas_call`` has no differentiation rule, so the identity
+    tangent (exactly the STE) is attached here and ``jax.grad`` never
+    traces into the kernel. The range takes no tangent."""
+    return _kernel_ops().fake_quant_apply(xf, bits, mn, mx)
+
+
+@_fake_quant_apply_ste.defjvp
+def _fake_quant_apply_ste_jvp(primals, tangents):
+    return _fake_quant_apply_ste(*primals), tangents[0]
 
 
 def _kernel_route(x: jnp.ndarray, axis) -> bool:
-    """True when this fake-quant call should run through the fused
-    Pallas kernel (``kernels.ops.fused_fake_quant``): the kernel only
-    implements the per-channel-last layout (range reduced over every
-    non-final axis), and only a TPU backend compiles it to Mosaic —
+    """True when this fake-quant call should run through the Pallas
+    kernels (``kernels.ops.fake_quant_range``, ``fake_quant_apply``):
+    they only implement the per-channel-last layout (range reduced over
+    every non-final axis), and only a TPU backend compiles them to Mosaic —
     everywhere else the reference jnp path stays the default.
     ``GALEN_FQ_KERNEL=1`` forces the kernel (interpreted off-TPU, for
     parity tests); ``GALEN_FQ_KERNEL=0`` forces the reference path even
@@ -72,6 +77,16 @@ def _minmax(x: jnp.ndarray, axis) -> tuple[jnp.ndarray, jnp.ndarray]:
     return x_min, x_min + span
 
 
+def _quantize_in_range(xf: jnp.ndarray, bits, x_min: jnp.ndarray,
+                       x_max: jnp.ndarray):
+    bits = jnp.asarray(bits, jnp.float32)
+    n = 2.0 ** bits - 1.0
+    s = n / (x_max - x_min)
+    z = jnp.floor(s * x_min) + 2.0 ** (bits - 1.0)
+    q = jnp.clip(jnp.floor(s * xf - z), -n, n)
+    return q, s, z
+
+
 def quantize(x: jnp.ndarray, bits, axis) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Paper Eq. 3: Q(r) = clip(floor(s*r - z), -n, n).
 
@@ -80,17 +95,42 @@ def quantize(x: jnp.ndarray, bits, axis) -> tuple[jnp.ndarray, jnp.ndarray, jnp.
     except the channel one).
     """
     xf = x.astype(jnp.float32)
-    bits = jnp.asarray(bits, jnp.float32)
-    n = 2.0 ** bits - 1.0
-    x_min, x_max = _minmax(xf, axis)
-    s = n / (x_max - x_min)
-    z = jnp.floor(s * x_min) + 2.0 ** (bits - 1.0)
-    q = jnp.clip(jnp.floor(s * xf - z), -n, n)
-    return q, s, z
+    return _quantize_in_range(xf, bits, *_minmax(xf, axis))
 
 
 def dequantize(q: jnp.ndarray, s: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     return (q + z + 0.5) / s  # +0.5: mid-rise reconstruction of the floor
+
+
+def _reference_fq(xf: jnp.ndarray, bits, x_min: jnp.ndarray,
+                  x_max: jnp.ndarray) -> jnp.ndarray:
+    """The reference quantize-dequantize of f32 ``xf`` with a per-channel
+    range; bits >= 32 selects ``xf``."""
+    q, s, z = _quantize_in_range(xf, jnp.clip(jnp.asarray(bits), 1, 31),
+                                 x_min, x_max)
+    return jnp.where(jnp.asarray(bits) >= 32, xf, dequantize(q, s, z))
+
+
+def _straight_through(xf: jnp.ndarray, xq: jnp.ndarray, bits) -> jnp.ndarray:
+    """Forward the quantized values, identity gradient. The pass-through
+    selects ``xf`` itself: ``xf + (xf - xf)`` flushes denormals to zero,
+    so 32 bits would not be the identity."""
+    return jnp.where(jnp.asarray(bits) >= 32, xf,
+                     xf + jax.lax.stop_gradient(xq - xf))
+
+
+def _quant_dequant(xf: jnp.ndarray, bits, src: jnp.ndarray,
+                   axis) -> jnp.ndarray:
+    """f32 ``xf`` quantize-dequantized with the per-channel range of
+    ``src`` (``xf`` itself, or the tensor xf's rows were taken from),
+    reduced over ``axis``; the range takes no gradient. bits >= 32
+    selects ``xf``."""
+    src = jax.lax.stop_gradient(src)
+    if _kernel_route(src, axis):
+        mn, mx = _kernel_ops().fake_quant_range(src)
+        return _fake_quant_apply_ste(xf, jnp.asarray(bits, jnp.int32),
+                                     mn, mx)
+    return _reference_fq(xf, bits, *_minmax(src, axis))
 
 
 def fake_quant(x: jnp.ndarray, bits, axis=None) -> jnp.ndarray:
@@ -101,25 +141,25 @@ def fake_quant(x: jnp.ndarray, bits, axis=None) -> jnp.ndarray:
     """
     if axis is None:
         axis = tuple(range(x.ndim - 1))
-    orig_dtype = x.dtype
     # the scope tags these ops in a profiler trace (metadata only)
     with jax.named_scope("fake_quant"):
         xf = x.astype(jnp.float32)
-        if _kernel_route(x, axis):
-            # one-pass fused minmax/quant/dequant (bits >= 32 selects
-            # pass-through inside the kernel)
-            xq = _fused_fake_quant_ste(xf, jnp.asarray(bits, jnp.int32))
-        else:
-            q, s, z = quantize(xf, jnp.clip(jnp.asarray(bits), 1, 31),
-                               axis)
-            xq = dequantize(q, s, z)
-            xq = jnp.where(jnp.asarray(bits) >= 32, xf, xq)
-        # Straight-through estimator: forward quantized values, identity
-        # grad. The pass-through selects ``xf`` itself: ``xf + (xf - xf)``
-        # flushes denormals to zero, so 32 bits would not be the identity.
-        out = jnp.where(jnp.asarray(bits) >= 32, xf,
-                        xf + jax.lax.stop_gradient(xq - xf))
-        return out.astype(orig_dtype)
+        xq = _quant_dequant(xf, bits, xf, axis)
+        return _straight_through(xf, xq, bits).astype(x.dtype)
+
+
+def fake_quant_rows(table: jnp.ndarray, idx: jnp.ndarray, bits) -> jnp.ndarray:
+    """``jnp.take(fake_quant_weight(table, bits), idx, axis=0)``, bit for
+    bit and in gradient, with the quantize-dequantize run on the taken
+    rows only: the embedding lookup reads a few hundred rows of a
+    vocabulary-sized table. The per-channel range is still the whole
+    table's (policy-independent, so a vmap over bit widths computes it
+    once)."""
+    with jax.named_scope("fake_quant"), jax.named_scope("embed_rows"):
+        xf = jnp.take(table, idx, axis=0).astype(jnp.float32)
+        xq = _quant_dequant(xf, bits, table.astype(jnp.float32),
+                            tuple(range(table.ndim - 1)))
+        return _straight_through(xf, xq, bits).astype(table.dtype)
 
 
 def fake_quant_weight(w: jnp.ndarray, bits) -> jnp.ndarray:

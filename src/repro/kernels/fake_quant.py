@@ -7,14 +7,17 @@ Two passes, each tiled over (rows, channels) so VMEM use is bounded by the
 block shape whatever R is (an activation slab or a vocab-sized embedding
 table has far more rows than one block can hold):
 
-1. ``_range_kernel`` reduces per-channel min/max over row blocks. The row
-   axis is the innermost grid axis and the (1, bc) min/max outputs stay
-   resident across it, accumulating.
-2. ``_quant_kernel`` derives scale/offset from the range and writes the
-   quantize-clip-dequantize of each (br, bc) block.
+1. ``_range_kernel`` (``fake_quant_range_2d``) reduces per-channel min/max
+   over row blocks. The row axis is the innermost grid axis and the
+   (1, bc) min/max outputs stay resident across it, accumulating.
+2. ``_quant_kernel`` (``fake_quant_apply_2d``) derives scale/offset from
+   the range and writes the quantize-clip-dequantize of each (br, bc)
+   block.
 
-So x is read twice and written once. Shapes must be kernel-legal before
-the call (``kernels.ops.fused_fake_quant`` pads them): C a multiple of
+So x is read twice and written once. The second pass is elementwise once
+the range is known, so it may run on a gathered subset of x's rows with
+x's range (the embedding lookup does). Shapes must be kernel-legal before
+the call (``kernels.ops._fq_layout`` pads them): C a multiple of
 ``bc`` (itself a multiple of 128) and R a multiple of ``br`` (a multiple
 of 8, or R itself). ``bits`` is an int32 scalar in SMEM, held as a
 (1, 1) array so that a vmapped bit width stays a legal block.
@@ -61,14 +64,13 @@ def _quant_kernel(bits_ref, x_ref, mn_ref, mx_ref, o_ref):
     o_ref[...] = jnp.where(bits >= 32.0, x, deq).astype(o_ref.dtype)
 
 
-def fake_quant_2d(x: jnp.ndarray, bits, *, br: int, bc: int,
-                  interpret: bool = True) -> jnp.ndarray:
-    """x [R, C]: quantize-dequantize with per-channel (last axis) dynamic
-    range reduced over axis 0, on (br, bc) blocks. ``bits`` may be a
-    traced int scalar."""
+def fake_quant_range_2d(x: jnp.ndarray, *, br: int, bc: int,
+                        interpret: bool = True):
+    """x [R, C] -> (min, max), each [1, C]: the per-channel range reduced
+    over axis 0, on (br, bc) blocks."""
     R, C = x.shape
     grid_r, grid_c = R // br, C // bc
-    mn, mx = pl.pallas_call(
+    return pl.pallas_call(
         _range_kernel,
         grid=(grid_c, grid_r),
         in_specs=[pl.BlockSpec((br, bc), lambda j, i: (i, j))],
@@ -79,6 +81,17 @@ def fake_quant_2d(x: jnp.ndarray, bits, *, br: int, bc: int,
         interpret=interpret,
         name="fake_quant_range",
     )(x)
+
+
+def fake_quant_apply_2d(x: jnp.ndarray, bits, mn: jnp.ndarray,
+                        mx: jnp.ndarray, *, br: int, bc: int,
+                        interpret: bool = True) -> jnp.ndarray:
+    """x [R, C]: quantize-dequantize with the per-channel range rows
+    ``mn``, ``mx`` [1, C], on (br, bc) blocks. The range need not be x's
+    own: any rows of a tensor take that tensor's range. ``bits`` may be
+    a traced int scalar."""
+    R, C = x.shape
+    grid_r, grid_c = R // br, C // bc
     bits_arr = jnp.reshape(jnp.asarray(bits, jnp.int32), (1, 1))
     return pl.pallas_call(
         _quant_kernel,

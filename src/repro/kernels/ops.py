@@ -80,26 +80,51 @@ def _fq_row_block(R: int) -> int:
     return _FQ_MAX_BR
 
 
-@jax.jit
-def fused_fake_quant(x: jnp.ndarray, bits) -> jnp.ndarray:
-    """Per-channel (last axis) fake quant of an arbitrary-rank tensor.
-
-    Channels are zero-padded to a lane multiple and rows to a row-block
-    multiple by repeating the last row; both are exact, since a channel's
-    range reduces over its own rows only and a repeated row changes no
-    min or max. The padding is sliced off the result."""
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    R, C = x2.shape
+def _fq_layout(x: jnp.ndarray):
+    """x viewed as [R, C] (channels last) and made kernel-legal:
+    ``(xp, br, bc)``. Channels are zero-padded to a lane multiple and
+    rows to a row-block multiple by repeating the last row; both are
+    exact, since a channel's range reduces over its own rows only and a
+    repeated row changes no min or max. Callers slice the padding off."""
+    x2 = x.reshape(-1, x.shape[-1])
+    R = x2.shape[0]
     br = _fq_row_block(R)
     xp = _pad_to(x2, _LANE, 1)
     if R % br:
         xp = jnp.pad(xp, ((0, (-R) % br), (0, 0)), mode="edge")
-    Cp = xp.shape[1]
-    bc = next(b for b in (512, 384, 256, _LANE) if Cp % b == 0)
-    out = _fq.fake_quant_2d(xp, bits, br=br, bc=bc,
-                            interpret=not _on_tpu())
-    return out[:R, :C].reshape(shape)
+    bc = next(b for b in (512, 384, 256, _LANE) if xp.shape[1] % b == 0)
+    return xp, br, bc
+
+
+@jax.jit
+def fake_quant_range(x: jnp.ndarray):
+    """Per-channel (last axis) min and max of an arbitrary-rank tensor,
+    reduced over every other axis: two f32 rows [1, Cp], Cp the channels
+    padded to a lane multiple, as ``fake_quant_apply`` takes them."""
+    xp, br, bc = _fq_layout(x)
+    mn, mx = _fq.fake_quant_range_2d(xp, br=br, bc=bc,
+                                     interpret=not _on_tpu())
+    return mn, mx
+
+
+@jax.jit
+def fake_quant_apply(x: jnp.ndarray, bits, mn: jnp.ndarray,
+                     mx: jnp.ndarray) -> jnp.ndarray:
+    """Per-channel (last axis) quantize-dequantize of an arbitrary-rank
+    tensor with the range ``fake_quant_range`` gave, of x or of any
+    tensor x's rows were taken from."""
+    shape = x.shape
+    xp, br, bc = _fq_layout(x)
+    out = _fq.fake_quant_apply_2d(xp, bits, mn, mx, br=br, bc=bc,
+                                  interpret=not _on_tpu())
+    return out[:x.size // shape[-1], :shape[-1]].reshape(shape)
+
+
+@jax.jit
+def fused_fake_quant(x: jnp.ndarray, bits) -> jnp.ndarray:
+    """Per-channel (last axis) fake quant of an arbitrary-rank tensor:
+    its range, then the quantize-dequantize with it."""
+    return fake_quant_apply(x, bits, *fake_quant_range(x))
 
 
 # --------------------------------------------------------------------------

@@ -17,12 +17,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.quantization import fake_quant_act, fake_quant_weight
+from repro.core.quantization import (fake_quant_act, fake_quant_rows,
+                                     fake_quant_weight)
 from repro.distributed.sharding import shard
 
 # Short aliases used throughout the model code.
 fq_act = fake_quant_act
 fq_weight = fake_quant_weight
+fq_rows = fake_quant_rows
 
 
 def dtype_of(name: str):

@@ -150,8 +150,12 @@ def _embed_inputs(cfg: ArchConfig, params, tokens, embeds, cspec):
         return embeds  # [B, S, d] straight from the (stub) frontend
     table = L.getw(params, "embed", L.dtype_of(cfg.compute_dtype))
     if ebits is not None:
-        table = L.fq_weight(table, ebits)
-    x = jnp.take(table, tokens, axis=0).astype(L.dtype_of(cfg.compute_dtype))
+        # only the looked-up rows are fake-quantized (with the table's
+        # range): the same values as quantizing the whole table first
+        x = L.fq_rows(table, tokens, ebits)
+    else:
+        x = jnp.take(table, tokens, axis=0)
+    x = x.astype(L.dtype_of(cfg.compute_dtype))
     if cfg.frontend == "vision_stub" and embeds is not None:
         P = embeds.shape[1]
         x = jnp.concatenate([embeds.astype(x.dtype), x[:, P:]], axis=1)

@@ -117,3 +117,164 @@ def test_kernel_route_layout_guard(monkeypatch):
     assert not _kernel_route(jnp.zeros(8), (0,))
     monkeypatch.setenv("GALEN_FQ_KERNEL", "0")
     assert not _kernel_route(x2, (0,))          # forced off
+
+
+# ------------------------------------- gather-first embedding fake quant
+
+# 2**7 * 67 rows: no multiple-of-8 divisor in (128, 512], as Qwen2's
+# 151,936 = 2**7 * 1187 has none, so the kernel's range pass takes
+# 128-row blocks; 200 channels pad to 256 lanes
+_TABLE_ROWS, _TABLE_D = 128 * 67, 200
+_ROWS_BITS = [1, 2, 3, 4, 6, 8, 32]
+# repeated and out-of-order ids, the first and the last row among them
+_TOKENS = np.array([[7, 3, _TABLE_ROWS - 1, 3, 0, 411],
+                    [42, 42, 1, 8000, 7, 7]], np.int32)
+
+
+def _embed_table():
+    """Rows of log-uniform norms (3-30, as the benchmark shapes Qwen2's
+    table), channel 0 constant at zero, one denormal entry."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    t = jax.random.normal(k1, (_TABLE_ROWS, _TABLE_D))
+    norms = jnp.exp(jax.random.uniform(k2, (_TABLE_ROWS, 1),
+                                       minval=np.log(3.0),
+                                       maxval=np.log(30.0)))
+    t = t / jnp.linalg.norm(t, axis=1, keepdims=True) * norms
+    return t.at[:, 0].set(0.0).at[3, 5].set(1e-40)
+
+
+def _take_of_fake_quant(table, idx, bits):
+    """The whole-table formulation fake_quant_rows replaces."""
+    return jnp.take(fake_quant_weight(table, bits), idx, axis=0)
+
+
+def _assert_bitwise(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+
+
+@pytest.fixture(scope="module")
+def embed_table():
+    return _embed_table()
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+@pytest.mark.parametrize("bits", _ROWS_BITS)
+def test_fake_quant_rows_equals_take_of_fake_quant(embed_table, route, bits,
+                                                   monkeypatch):
+    """Gather-first equals quantize-the-table-then-take, bit for bit, on
+    the reference route and on the (interpreted) kernel route."""
+    from repro.core.quantization import fake_quant_rows
+    monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+    idx = jnp.asarray(_TOKENS)
+    got = jax.jit(fake_quant_rows)(embed_table, idx, jnp.int32(bits))
+    want = jax.jit(_take_of_fake_quant)(embed_table, idx, jnp.int32(bits))
+    _assert_bitwise(got, want)
+    if bits == 32:    # a select, so the denormal survives
+        _assert_bitwise(got, jnp.take(embed_table, idx, axis=0))
+        assert np.asarray(got)[0, 1, 5] != 0.0
+    else:
+        assert not np.array_equal(np.asarray(got),
+                                  np.asarray(jnp.take(embed_table, idx,
+                                                      axis=0)))
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+def test_fake_quant_rows_vmapped_bits(embed_table, route, monkeypatch):
+    """Under vmap over K=4 bit widths with shared tokens, as validation
+    runs it."""
+    from repro.core.quantization import fake_quant_rows
+    monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+    idx = jnp.asarray(_TOKENS)
+    bits = jnp.array([1, 3, 8, 32], jnp.int32)
+    got = jax.jit(jax.vmap(lambda b: fake_quant_rows(embed_table, idx, b)))(
+        bits)
+    want = jax.jit(jax.vmap(
+        lambda b: _take_of_fake_quant(embed_table, idx, b)))(bits)
+    assert got.shape == (4,) + _TOKENS.shape + (_TABLE_D,)
+    _assert_bitwise(got, want)
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+@pytest.mark.parametrize("bits", [2, 32])
+def test_fake_quant_rows_gradient(embed_table, route, bits, monkeypatch):
+    """The table's gradient is the old formulation's: identity through
+    the taken rows, then take's scatter-add (repeated ids accumulate);
+    the range takes none."""
+    from repro.core.quantization import fake_quant_rows
+    monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+    idx = jnp.asarray(_TOKENS)
+    cot = jax.random.normal(jax.random.PRNGKey(12),
+                            _TOKENS.shape + (_TABLE_D,))
+
+    def grad_of(f):
+        return jax.jit(jax.grad(
+            lambda t: jnp.sum(f(t, idx, jnp.int32(bits)) * cot)))(
+                embed_table)
+
+    got = grad_of(fake_quant_rows)
+    _assert_bitwise(got, grad_of(_take_of_fake_quant))
+    np.testing.assert_array_equal(np.asarray(got[42]),
+                                  np.asarray(cot[1, 0] + cot[1, 1]))
+
+
+def _tiny_tied_lm():
+    from repro.configs.base import ArchConfig
+    from repro.core.compress import CompressibleLM
+    from repro.models import model as M
+
+    cfg = ArchConfig(name="tied", num_layers=2, d_model=64, num_heads=4,
+                     num_kv_heads=2, head_dim=16, d_ff=128, vocab_size=320,
+                     scan_layers=True, tie_embeddings=True)
+    return CompressibleLM(cfg, M.init(cfg, jax.random.PRNGKey(0)))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+def test_validation_quantizes_only_embedding_rows(route, monkeypatch):
+    """The vmapped validation forward of a tied-embedding LM makes no
+    [K, V, d] fake-quantized table, marks the lookup with one
+    ``embed_rows`` scope, and gives the whole-table formulation's logits
+    exactly."""
+    from repro.core.policy import Policy, map_actions, stack_policies
+    from repro.models import layers as L
+    monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+    cm = _tiny_tied_lm()
+    cfg, K = cm.cfg, 4
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+                                          0, cfg.vocab_size)}
+    rng = np.random.default_rng(5)
+    pb = stack_policies(cm.specs, [
+        Policy([map_actions(s, rng.random(3), "pq") for s in cm.specs])
+        for _ in range(K)])
+    pols = tuple(jnp.asarray(a, jnp.int32)
+                 for a in (pb.keep, pb.w_bits, pb.a_bits))
+    build = cm.cspec_builder()
+    logits = jax.vmap(
+        lambda p, k, w, a: cm.logits(batch, build(k, w, a), p),
+        in_axes=(None, 0, 0, 0))
+
+    jaxpr = jax.make_jaxpr(logits)(cm.params, *pols).jaxpr
+    table = (K, cfg.vocab_size, cfg.d_model)
+    assert not any(getattr(v.aval, "shape", None) == table
+                   for e in _eqns(jaxpr) for v in e.outvars)
+    scopes = {str(e.source_info.name_stack).split("embed_rows")[0]
+              for e in _eqns(jaxpr)
+              if "embed_rows" in str(e.source_info.name_stack)}
+    assert len(scopes) == 1 and "fake_quant" in scopes.pop()
+
+    got = jax.jit(logits)(cm.params, *pols)
+    monkeypatch.setattr(L, "fq_rows", _take_of_fake_quant)
+    want = jax.jit(logits)(cm.params, *pols)
+    _assert_bitwise(got, want)

@@ -79,6 +79,18 @@ def test_fake_quant_vmapped_bits_compiles(compile_tpu):
         _s((896, 4864)), _s((4,), jnp.int32))
 
 
+def test_fake_quant_embedding_rows_compiles(compile_tpu):
+    """Validation's lookup: the whole table's range once, then the
+    apply pass on 2 x 128 taken rows for K=4 bit widths."""
+    def rows(table, idx, b):
+        mn, mx = ops.fake_quant_range(table)
+        x = jnp.take(table, idx, axis=0)
+        return jax.vmap(lambda bb: ops.fake_quant_apply(x, bb, mn, mx))(b)
+
+    compile_tpu(rows, _s((151936, 896)), _s((2, 128), jnp.int32),
+                _s((4,), jnp.int32))
+
+
 @pytest.mark.parametrize("dims,final", [((16, 400, 300, 1), "sigmoid"),
                                         ((17, 400, 300, 1), "linear")])
 def test_mlp3_compiles(compile_tpu, dims, final):
