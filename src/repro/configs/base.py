@@ -30,6 +30,7 @@ class SSMConfig:
     expand: int = 2                       # d_inner = expand * d_model
     conv_width: int = 4
     chunk_size: int = 256                 # SSD chunked-scan block length
+    conv_bias: bool = False               # depthwise conv carries a bias
 
 
 @dataclass(frozen=True)
@@ -50,6 +51,8 @@ class ArchConfig:
     window: int = 4096                    # for attention == "sliding" / local layers
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"      # rope|none (NoPE)
+    attention_multiplier: Optional[float] = None  # softmax scale (1/sqrt(hd))
     # hybrid block pattern, tiled to num_layers; entries: "attn"|"rglru"|"ssm"
     block_pattern: Tuple[str, ...] = ("attn",)
     lru_width: int = 0                    # RG-LRU width (0 => d_model)
@@ -61,10 +64,17 @@ class ArchConfig:
 
     # --- embeddings / norms ---
     norm: str = "rmsnorm"                 # rmsnorm|layernorm|nonparametric_ln
+    norm_eps: float = 1e-6
     tie_embeddings: bool = False
     frontend: str = "none"                # none|vision_stub|audio_stub
     frontend_len: int = 0                 # prefix positions fed by the stub
     is_encoder: bool = False              # encoder-only (no causal mask, no decode)
+    # Granite-style scale factors (1.0 = none): input embeddings are
+    # multiplied, each sublayer's output is multiplied before its
+    # residual add, the logits are divided
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # --- numerics / compile ---
     param_dtype: str = "float32"

@@ -37,6 +37,23 @@ def _head_granularity(head_dim: int, lane: int = 128) -> int:
 # LayerSpec enumeration for ArchConfig LMs
 # ===========================================================================
 
+def _mlp_specs(cfg: ArchConfig, i: int) -> List[LayerSpec]:
+    """Layer i's dense FFN units: up (+gate), pruned by ℓ1 channel, and
+    down, whose input shrinks with it."""
+    d, ff = cfg.d_model, cfg.d_ff
+    gated = 2 if cfg.mlp in ("swiglu", "geglu") else 1
+    return [
+        LayerSpec(name=f"L{i}.mlp_up", kind="mlp_up", layer_idx=i,
+                  in_dim=d, out_dim=ff, prunable=True, prune_dim=ff,
+                  prune_granularity=128,
+                  flops_per_token=2.0 * d * ff * gated,
+                  weight_elems=d * ff * gated, act_elems_per_token=d),
+        LayerSpec(name=f"L{i}.mlp_down", kind="mlp_down", layer_idx=i,
+                  in_dim=ff, out_dim=d, dep_group=f"L{i}.ff",
+                  flops_per_token=2.0 * ff * d,
+                  weight_elems=ff * d, act_elems_per_token=ff)]
+
+
 def lm_layer_specs(cfg: ArchConfig) -> List[LayerSpec]:
     specs: List[LayerSpec] = []
     d = cfg.d_model
@@ -93,19 +110,7 @@ def lm_layer_specs(cfg: ArchConfig) -> List[LayerSpec]:
                         weight_elems=ff * d, act_elems_per_token=ff,
                         extra={"dense_residual": True}))
             else:
-                ff = cfg.d_ff
-                gated = 2 if cfg.mlp in ("swiglu", "geglu") else 1
-                specs.append(LayerSpec(
-                    name=f"L{i}.mlp_up", kind="mlp_up", layer_idx=i,
-                    in_dim=d, out_dim=ff, prunable=True, prune_dim=ff,
-                    prune_granularity=128,
-                    flops_per_token=2.0 * d * ff * gated,
-                    weight_elems=d * ff * gated, act_elems_per_token=d))
-                specs.append(LayerSpec(
-                    name=f"L{i}.mlp_down", kind="mlp_down", layer_idx=i,
-                    in_dim=ff, out_dim=d, dep_group=f"L{i}.ff",
-                    flops_per_token=2.0 * ff * d,
-                    weight_elems=ff * d, act_elems_per_token=ff))
+                specs += _mlp_specs(cfg, i)
         elif kind == "ssm":
             d_inner, nheads, conv_dim = B.ssm_dims(cfg)
             d_proj = 2 * d_inner + 2 * cfg.ssm.d_state + nheads
@@ -122,6 +127,8 @@ def lm_layer_specs(cfg: ArchConfig) -> List[LayerSpec]:
                 in_dim=d_inner, out_dim=d, dep_group=f"L{i}.ssm_heads",
                 flops_per_token=2.0 * d_inner * d,
                 weight_elems=d_inner * d, act_elems_per_token=d_inner))
+            if cfg.mlp != "none":
+                specs += _mlp_specs(cfg, i)
         elif kind == "rglru":
             w = cfg.lru_width
             specs.append(LayerSpec(
@@ -135,19 +142,7 @@ def lm_layer_specs(cfg: ArchConfig) -> List[LayerSpec]:
                 in_dim=w, out_dim=d, dep_group=f"L{i}.lru",
                 flops_per_token=2.0 * w * d,
                 weight_elems=w * d, act_elems_per_token=w))
-            ff = cfg.d_ff
-            gated = 2 if cfg.mlp in ("swiglu", "geglu") else 1
-            specs.append(LayerSpec(
-                name=f"L{i}.mlp_up", kind="mlp_up", layer_idx=i,
-                in_dim=d, out_dim=ff, prunable=True, prune_dim=ff,
-                prune_granularity=128,
-                flops_per_token=2.0 * d * ff * gated,
-                weight_elems=d * ff * gated, act_elems_per_token=d))
-            specs.append(LayerSpec(
-                name=f"L{i}.mlp_down", kind="mlp_down", layer_idx=i,
-                in_dim=ff, out_dim=d, dep_group=f"L{i}.ff",
-                flops_per_token=2.0 * ff * d,
-                weight_elems=ff * d, act_elems_per_token=ff))
+            specs += _mlp_specs(cfg, i)
     specs.append(LayerSpec(
         name="head", kind="head", layer_idx=cfg.num_layers,
         in_dim=d, out_dim=cfg.vocab_size, quantizable=True,
@@ -218,6 +213,14 @@ def build_lm_cspec(cfg: ArchConfig, params, policy: Policy,
         else:
             by_layer.setdefault(s.layer_idx, {})[s.kind] = c
 
+    def mlp_cs(cm, p_l):
+        cu, cd = cm.get("mlp_up"), cm.get("mlp_down")
+        ff_mask = None
+        if cu is not None and cu.keep < cfg.d_ff:
+            scores = _unit_prune_scores(cfg, p_l, "mlp_up")
+            ff_mask = pruning.keep_mask(scores, cu.keep)
+        return {"up": _qs(cu), "down": _qs(cd), "ff_mask": ff_mask}
+
     layer_cspecs = []
     for i, kind in enumerate(cfg.layer_kinds):
         p_l = _layer_params(params, i, scanned)
@@ -255,14 +258,7 @@ def build_lm_cspec(cfg: ArchConfig, params, policy: Policy,
                     moe_cs["dense_ff_mask"] = dmask
                 cs["moe"] = moe_cs
             else:
-                cu, cd = cm.get("mlp_up"), cm.get("mlp_down")
-                ff_mask = None
-                if cu is not None and cu.keep < cfg.d_ff:
-                    scores = _unit_prune_scores(cfg, p_l, "mlp_up")
-                    ff_mask = pruning.keep_mask(scores, cu.keep)
-                cs["mlp"] = {"up": _qs(cu),
-                             "down": _qs(cd),
-                             "ff_mask": ff_mask}
+                cs["mlp"] = mlp_cs(cm, p_l)
         elif kind == "ssm":
             ci, co = cm.get("ssm_in"), cm.get("ssm_out")
             nheads = B.ssm_dims(cfg)[1]
@@ -273,6 +269,8 @@ def build_lm_cspec(cfg: ArchConfig, params, policy: Policy,
             cs["ssm"] = {"in": _qs(ci),
                          "out": _qs(co),
                          "head_mask": head_mask}
+            if cfg.mlp != "none":
+                cs["mlp"] = mlp_cs(cm, p_l)
         elif kind == "rglru":
             ci, co = cm.get("rglru_in"), cm.get("rglru_out")
             wmask = None
@@ -282,14 +280,7 @@ def build_lm_cspec(cfg: ArchConfig, params, policy: Policy,
             cs["rglru"] = {"in": _qs(ci),
                            "out": _qs(co),
                            "width_mask": wmask}
-            cu, cd = cm.get("mlp_up"), cm.get("mlp_down")
-            ff_mask = None
-            if cu is not None and cu.keep < cfg.d_ff:
-                scores = _unit_prune_scores(cfg, p_l, "mlp_up")
-                ff_mask = pruning.keep_mask(scores, cu.keep)
-            cs["mlp"] = {"up": _qs(cu),
-                         "down": _qs(cd),
-                         "ff_mask": ff_mask}
+            cs["mlp"] = mlp_cs(cm, p_l)
         layer_cspecs.append(cs)
 
     if True:  # fill masks for BOTH paths: keeps the cspec pytree structure
@@ -388,6 +379,10 @@ def make_lm_cspec_builder(cfg: ArchConfig, params,
                 return jnp.ones((dim,), jnp.float32)
             return pruning.keep_mask_dynamic(scores[i], keep[i])
 
+        def mlp_cs(i):
+            return {"up": qs((i, "mlp_up")), "down": qs((i, "mlp_down")),
+                    "ff_mask": mask((i, "mlp_up"), cfg.d_ff)}
+
         layer_cspecs = []
         for i, kind in enumerate(cfg.layer_kinds):
             cs: dict[str, Any] = {}
@@ -409,22 +404,20 @@ def make_lm_cspec_builder(cfg: ArchConfig, params,
                                                        cfg.d_ff)
                     cs["moe"] = moe_cs
                 else:
-                    cs["mlp"] = {"up": qs((i, "mlp_up")),
-                                 "down": qs((i, "mlp_down")),
-                                 "ff_mask": mask((i, "mlp_up"), cfg.d_ff)}
+                    cs["mlp"] = mlp_cs(i)
             elif kind == "ssm":
                 nheads = B.ssm_dims(cfg)[1]
                 cs["ssm"] = {"in": qs((i, "ssm_in")),
                              "out": qs((i, "ssm_out")),
                              "head_mask": mask((i, "ssm_in"), nheads)}
+                if cfg.mlp != "none":
+                    cs["mlp"] = mlp_cs(i)
             elif kind == "rglru":
                 cs["rglru"] = {"in": qs((i, "rglru_in")),
                                "out": qs((i, "rglru_out")),
                                "width_mask": mask((i, "rglru_in"),
                                                   cfg.lru_width)}
-                cs["mlp"] = {"up": qs((i, "mlp_up")),
-                             "down": qs((i, "mlp_down")),
-                             "ff_mask": mask((i, "mlp_up"), cfg.d_ff)}
+                cs["mlp"] = mlp_cs(i)
             layer_cspecs.append(cs)
         if scanned:
             blocks_cs = jax.tree.map(lambda *xs: jnp.stack(xs),

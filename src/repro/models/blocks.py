@@ -57,15 +57,17 @@ def apply_attention(p, x, cfg: ArchConfig, cspec=None, positions=None):
     if positions is None:
         positions = jnp.arange(S)[None, :]
     q, k, v = _qkv(p, x, cfg, cspec)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
     q = shard(q, "batch", "seq", "heads", None)
     k = shard(k, "batch", "seq", "kv_heads", None)
     v = shard(v, "batch", "seq", "kv_heads", None)
     causal = not cfg.is_encoder
     window = cfg.window if cfg.attention == "sliding" else 0
     o = L.attention(q, k, v, causal=causal, window=window,
-                    head_mask=_mask(cspec, "head_mask"))
+                    head_mask=_mask(cspec, "head_mask"),
+                    scale=cfg.attention_multiplier)
     o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
     return L.linear(p["wo"], o, _qs(cspec, "o"))
 
@@ -124,9 +126,10 @@ def decode_attention_block(p, x, cache, pos, cfg: ArchConfig, cspec=None):
     B = x.shape[0]
     H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q, k, v = _qkv(p, x, cfg, cspec)
-    pp = jnp.full((B, 1), pos)
-    q = L.rope(q, pp, cfg.rope_theta)
-    k = L.rope(k, pp, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        pp = jnp.full((B, 1), pos)
+        q = L.rope(q, pp, cfg.rope_theta)
+        k = L.rope(k, pp, cfg.rope_theta)
     W = cache["k"].shape[1]
     ring = cfg.attention == "sliding"
     slot = jnp.mod(pos, W) if ring else pos
@@ -137,7 +140,8 @@ def decode_attention_block(p, x, cache, pos, cfg: ArchConfig, cspec=None):
     v_cache = _cache_read(new_cache, "v", x.dtype)
     o = L.decode_attention(q, k_cache, v_cache, pos + 1,
                            window=cfg.window if ring else 0, ring=ring,
-                           head_mask=_mask(cspec, "head_mask"))
+                           head_mask=_mask(cspec, "head_mask"),
+                           scale=cfg.attention_multiplier)
     o = o.reshape(B, 1, H * D)
     out = L.linear(p["wo"], o, _qs(cspec, "o"))
     return out, new_cache
@@ -341,6 +345,8 @@ def init_ssm(key, cfg: ArchConfig, dtype):
         "dt_bias": jnp.zeros((nheads,), jnp.float32),
         "norm_scale": jnp.ones((d_inner,), dtype),
     }
+    if s.conv_bias:
+        p["conv_b"] = jnp.zeros((conv_dim,), dtype)
     return p
 
 
@@ -361,6 +367,7 @@ def ssd_chunked(xh, dA, Bm, Cm, chunk: int, init_state=None):
     """
     b, s, h, pdim = xh.shape
     n = Bm.shape[-1]
+    chunk = min(chunk, s)          # a shorter sequence is one chunk, unpadded
     pad = (-s) % chunk
     if pad:
         xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -403,7 +410,14 @@ def ssd_chunked(xh, dA, Bm, Cm, chunk: int, init_state=None):
 
 
 def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
-    """Shared pre/post projection logic. x: [B,S,d]."""
+    """The Mamba-2 mixer (arXiv:2405.21060; HF ``Mamba2Mixer``), shared by
+    the full-sequence and decode paths. x: [B,S,d].
+
+    in_proj -> [z, xBC, dt]; depthwise causal conv (+ bias) over xBC,
+    then SiLU; split into x, B, C; dt = softplus(dt + dt_bias),
+    A = -exp(A_log); SSD (chunked, or one recurrence step when decoding)
+    + D·x; pruned heads zeroed; gated RMSNorm norm(y · silu(z)) over
+    d_inner; out_proj."""
     s = cfg.ssm
     d_inner, nheads, conv_dim = ssm_dims(cfg)
     qs_in, qs_out = _qs(cspec, "in"), _qs(cspec, "out")
@@ -416,8 +430,9 @@ def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
         w_in = L.fq_weight(w_in, qs_in["w_bits"])
     proj = jnp.einsum("bsd,dk->bsk", xin, w_in.astype(x.dtype))
     z, xbc, dt = jnp.split(proj, [d_inner, d_inner + conv_dim], axis=-1)
-    y_conv, new_conv = L.causal_conv1d(jax.nn.silu(xbc), p["conv_w"],
-                                       conv_state)
+    y_conv, new_conv = L.causal_conv1d(xbc, p["conv_w"], conv_state,
+                                       bias=p.get("conv_b"))
+    y_conv = jax.nn.silu(y_conv)
     xs, Bm, Cm = jnp.split(y_conv, [d_inner, d_inner + s.d_state], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"][None, None])           # [B,S,h]
@@ -435,16 +450,17 @@ def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
         y = jnp.einsum("bn,bhpn->bhp", Cm[:, 0].astype(jnp.float32),
                        new_state)[:, None]
     else:
-        y, new_state = ssd_chunked(xh_dt, dA, Bm.astype(jnp.float32),
-                                   Cm.astype(jnp.float32), s.chunk_size,
-                                   ssm_state)
+        with jax.named_scope("ssd"):
+            y, new_state = ssd_chunked(xh_dt, dA, Bm.astype(jnp.float32),
+                                       Cm.astype(jnp.float32), s.chunk_size,
+                                       ssm_state)
     y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
     if head_mask is not None:
         y = y * head_mask[None, None, :, None]
     y = y.reshape(*x.shape[:2], d_inner).astype(x.dtype)
     # gated RMSNorm (mamba2)
     y = L.apply_norm("rmsnorm", {"scale": p["norm_scale"]},
-                     y * jax.nn.silu(z))
+                     y * jax.nn.silu(z), cfg.norm_eps)
     w_out = L.getw(p, "out_proj", y.dtype)
     if qs_out is not None:
         y = L.fq_act(y, qs_out["a_bits"])
@@ -454,7 +470,9 @@ def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
 
 
 def apply_ssm(p, x, cfg: ArchConfig, cspec=None):
-    out, _, _ = _ssm_inner(p, x, cfg, cspec, None, None)
+    # the scope tags the mixer's ops in a profiler trace (metadata only)
+    with jax.named_scope("mamba"):
+        out, _, _ = _ssm_inner(p, x, cfg, cspec, None, None)
     return out
 
 
@@ -469,8 +487,9 @@ def init_ssm_cache(cfg: ArchConfig, batch: int, dtype):
 
 
 def decode_ssm(p, x, cache, pos, cfg: ArchConfig, cspec=None):
-    out, conv, state = _ssm_inner(p, x, cfg, cspec, cache["conv"],
-                                  cache["state"], decode=True)
+    with jax.named_scope("mamba"):
+        out, conv, state = _ssm_inner(p, x, cfg, cspec, cache["conv"],
+                                      cache["state"], decode=True)
     return out, {"conv": conv, "state": state}
 
 

@@ -159,8 +159,10 @@ def _attn_scores_mask(qpos, kpos, causal: bool, window: int):
 
 def attention(q, k, v, *, causal: bool, window: int = 0,
               q_chunk: int = 512, k_chunk: int = 1024,
-              head_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """GQA attention. q: [B,S,H,D]; k,v: [B,S,KV,D]. window=0 -> unlimited.
+              head_mask: Optional[jnp.ndarray] = None,
+              scale: Optional[float] = None) -> jnp.ndarray:
+    """GQA attention. q: [B,S,H,D]; k,v: [B,S,KV,D]. window=0 -> unlimited;
+    ``scale`` None -> 1/sqrt(D).
 
     For S <= q_chunk falls back to one dense block; otherwise scans q-chunks
     (outer) and k-chunks (inner, online softmax) so the live score tensor is
@@ -169,7 +171,7 @@ def attention(q, k, v, *, causal: bool, window: int = 0,
     B, S, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qq = q.reshape(B, S, KV, G, D)
     positions = jnp.arange(S)
 
@@ -235,7 +237,8 @@ def attention(q, k, v, *, causal: bool, window: int = 0,
 
 def decode_attention(q, k_cache, v_cache, cache_len, *,
                      window: int = 0, ring: bool = False,
-                     head_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                     head_mask: Optional[jnp.ndarray] = None,
+                     scale: Optional[float] = None) -> jnp.ndarray:
     """Single-token attention against a cache.
 
     q: [B,1,H,D]; caches: [B,W,KV,D]; cache_len: current length (scalar).
@@ -247,7 +250,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     G = H // KV
     qq = q.reshape(B, KV, G, D)
     s = jnp.einsum("bkgd,blkd->bkgl", qq, k_cache).astype(jnp.float32)
-    s = s / math.sqrt(D)
+    s = s / math.sqrt(D) if scale is None else s * scale
     slot = jnp.arange(W)
     valid = slot < cache_len if not ring else slot < jnp.minimum(cache_len, W)
     if window > 0 and not ring:
@@ -280,13 +283,17 @@ def mlp_act(kind: str, gate: jnp.ndarray, up: Optional[jnp.ndarray]):
 # ---------------------------------------------------------------------------
 
 def causal_conv1d(x: jnp.ndarray, w: jnp.ndarray,
-                  state: Optional[jnp.ndarray] = None):
-    """x: [B,S,C]; w: [K,C] depthwise. Returns y ([B,S,C]) and new state
-    ([B,K-1,C]) holding the last K-1 inputs for streaming decode."""
+                  state: Optional[jnp.ndarray] = None,
+                  bias: Optional[jnp.ndarray] = None):
+    """x: [B,S,C]; w: [K,C] depthwise; bias: [C] or None. Returns y
+    ([B,S,C]) and new state ([B,K-1,C]) holding the last K-1 inputs for
+    streaming decode."""
     K = w.shape[0]
     if state is None:
         state = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
     xs = jnp.concatenate([state, x], axis=1)            # [B, S+K-1, C]
     y = sum(xs[:, i:i + x.shape[1]] * w[i][None, None] for i in range(K))
+    if bias is not None:
+        y = y + bias[None, None]
     new_state = xs[:, -(K - 1):] if K > 1 else state
     return y.astype(x.dtype), new_state

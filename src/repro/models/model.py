@@ -38,8 +38,12 @@ def _init_block(kind: str, key, cfg: ArchConfig, dtype):
             p["mlp"] = B.init_mlp(ks[1], cfg, dtype)
         return p
     if kind == "ssm":
-        return {"norm": L.norm_init(cfg.norm, cfg.d_model, dtype),
-                "ssm": B.init_ssm(ks[0], cfg, dtype)}
+        p = {"norm": L.norm_init(cfg.norm, cfg.d_model, dtype),
+             "ssm": B.init_ssm(ks[0], cfg, dtype)}
+        if cfg.mlp != "none":
+            p["mlp_norm"] = L.norm_init(cfg.norm, cfg.d_model, dtype)
+            p["mlp"] = B.init_mlp(ks[1], cfg, dtype)
+        return p
     if kind == "rglru":
         return {"mix_norm": L.norm_init(cfg.norm, cfg.d_model, dtype),
                 "rglru": B.init_rglru(ks[0], cfg, dtype),
@@ -48,26 +52,43 @@ def _init_block(kind: str, key, cfg: ArchConfig, dtype):
     raise ValueError(kind)
 
 
+def _norm(cfg: ArchConfig, p, x):
+    return L.apply_norm(cfg.norm, p, x, cfg.norm_eps)
+
+
+def _residual(cfg: ArchConfig, x, out):
+    """x + out, the sublayer's output scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
+
+
+def _ffn(p, x, cfg: ArchConfig, cs):
+    """The block's FFN sublayer (MLP or MoE), if it has one."""
+    if "mlp" not in p and "moe" not in p:
+        return x
+    h = _norm(cfg, p["mlp_norm"], x)
+    if "moe" in p:
+        return _residual(cfg, x, B.apply_moe(p["moe"], h, cfg, cs.get("moe")))
+    return _residual(cfg, x, B.apply_mlp(p["mlp"], h, cfg, cs.get("mlp")))
+
+
 def _apply_block(kind: str, p, x, cfg: ArchConfig, cspec, positions):
     cs = cspec or {}
     if kind == "attn":
-        h = L.apply_norm(cfg.norm, p["attn_norm"], x)
-        x = x + B.apply_attention(p["attn"], h, cfg, cs.get("attn"), positions)
-        h = L.apply_norm(cfg.norm, p["mlp_norm"], x)
-        if "moe" in p:
-            x = x + B.apply_moe(p["moe"], h, cfg, cs.get("moe"))
-        else:
-            x = x + B.apply_mlp(p["mlp"], h, cfg, cs.get("mlp"))
-        return x
-    if kind == "ssm":
-        h = L.apply_norm(cfg.norm, p["norm"], x)
-        return x + B.apply_ssm(p["ssm"], h, cfg, cs.get("ssm"))
-    if kind == "rglru":
-        h = L.apply_norm(cfg.norm, p["mix_norm"], x)
-        x = x + B.apply_rglru(p["rglru"], h, cfg, cs.get("rglru"))
-        h = L.apply_norm(cfg.norm, p["mlp_norm"], x)
-        return x + B.apply_mlp(p["mlp"], h, cfg, cs.get("mlp"))
-    raise ValueError(kind)
+        h = _norm(cfg, p["attn_norm"], x)
+        x = _residual(cfg, x, B.apply_attention(p["attn"], h, cfg,
+                                                cs.get("attn"), positions))
+    elif kind == "ssm":
+        h = _norm(cfg, p["norm"], x)
+        x = _residual(cfg, x, B.apply_ssm(p["ssm"], h, cfg, cs.get("ssm")))
+    elif kind == "rglru":
+        h = _norm(cfg, p["mix_norm"], x)
+        x = _residual(cfg, x, B.apply_rglru(p["rglru"], h, cfg,
+                                            cs.get("rglru")))
+    else:
+        raise ValueError(kind)
+    return _ffn(p, x, cfg, cs)
 
 
 def _init_block_cache(kind: str, cfg: ArchConfig, batch: int, max_len: int,
@@ -84,28 +105,19 @@ def _init_block_cache(kind: str, cfg: ArchConfig, batch: int, max_len: int,
 def _decode_block(kind: str, p, x, cache, pos, cfg: ArchConfig, cspec):
     cs = cspec or {}
     if kind == "attn":
-        h = L.apply_norm(cfg.norm, p["attn_norm"], x)
+        h = _norm(cfg, p["attn_norm"], x)
         o, cache = B.decode_attention_block(p["attn"], h, cache, pos, cfg,
                                             cs.get("attn"))
-        x = x + o
-        h = L.apply_norm(cfg.norm, p["mlp_norm"], x)
-        if "moe" in p:
-            x = x + B.apply_moe(p["moe"], h, cfg, cs.get("moe"))
-        else:
-            x = x + B.apply_mlp(p["mlp"], h, cfg, cs.get("mlp"))
-        return x, cache
-    if kind == "ssm":
-        h = L.apply_norm(cfg.norm, p["norm"], x)
+    elif kind == "ssm":
+        h = _norm(cfg, p["norm"], x)
         o, cache = B.decode_ssm(p["ssm"], h, cache, pos, cfg, cs.get("ssm"))
-        return x + o, cache
-    if kind == "rglru":
-        h = L.apply_norm(cfg.norm, p["mix_norm"], x)
+    elif kind == "rglru":
+        h = _norm(cfg, p["mix_norm"], x)
         o, cache = B.decode_rglru(p["rglru"], h, cache, pos, cfg,
                                   cs.get("rglru"))
-        x = x + o
-        h = L.apply_norm(cfg.norm, p["mlp_norm"], x)
-        return x + B.apply_mlp(p["mlp"], h, cfg, cs.get("mlp")), cache
-    raise ValueError(kind)
+    else:
+        raise ValueError(kind)
+    return _ffn(p, _residual(cfg, x, o), cfg, cs), cache
 
 
 # ---------------------------------------------------------------------------
@@ -156,6 +168,8 @@ def _embed_inputs(cfg: ArchConfig, params, tokens, embeds, cspec):
     else:
         x = jnp.take(table, tokens, axis=0)
     x = x.astype(L.dtype_of(cfg.compute_dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.frontend == "vision_stub" and embeds is not None:
         P = embeds.shape[1]
         x = jnp.concatenate([embeds.astype(x.dtype), x[:, P:]], axis=1)
@@ -171,7 +185,10 @@ def _unembed(cfg: ArchConfig, params, x, cspec):
     if hbits is not None:
         w = L.fq_weight(w, hbits)
     logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
-    return shard(logits.astype(jnp.float32), "batch", "seq", "vocab")
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return shard(logits, "batch", "seq", "vocab")
 
 
 def forward(cfg: ArchConfig, params, tokens=None, embeds=None, cspec=None,
@@ -209,7 +226,7 @@ def forward(cfg: ArchConfig, params, tokens=None, embeds=None, cspec=None,
                     else jax.checkpoint_policies.dots_saveable,
                     static_argnums=(2,))   # cfg is static
             x = fn(p_l, x, cfg, cs_l, positions)
-    x = L.apply_norm(cfg.norm, params["final_norm"], x)
+    x = _norm(cfg, params["final_norm"], x)
     return _unembed(cfg, params, x, cspec)
 
 
@@ -256,5 +273,5 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, pos, cspec=None,
             x, c = _decode_block(cfg.layer_kinds[i], p_l, x, c_l, pos, cfg,
                                  cs_l)
             new_cache.append(c)
-    x = L.apply_norm(cfg.norm, params["final_norm"], x)
+    x = _norm(cfg, params["final_norm"], x)
     return _unembed(cfg, params, x, cspec), new_cache

@@ -15,6 +15,7 @@ _MODULES = {
     "mixtral-8x22b": "repro.configs.mixtral_8x22b",
     "arctic-480b": "repro.configs.arctic_480b",
     "mamba2-780m": "repro.configs.mamba2_780m",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
     "hubert-xlarge": "repro.configs.hubert_xlarge",
 }
 

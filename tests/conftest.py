@@ -64,3 +64,13 @@ def tiny_resnet():
     params = R.init(cfg, jax.random.PRNGKey(0))
     batch = blob_images(4, 16, 8, seed=5)
     return CompressibleResNet(cfg, params), batch
+
+
+@pytest.fixture(autouse=True)
+def _empty_span_record():
+    """Each test starts with the program's span record empty: the record
+    is process-wide, and a test file that runs a search would otherwise
+    leave its spans to whichever file the same worker runs next."""
+    from repro.core import spans
+    spans.drain()
+    yield

@@ -142,7 +142,8 @@ def test_accuracy_batch_matches_scalar(tiny_lm):
 
 
 @pytest.mark.parametrize("arch", ["mixtral-8x22b", "mamba2-780m",
-                                  "recurrentgemma-2b", "arctic-480b"])
+                                  "recurrentgemma-2b", "arctic-480b",
+                                  "granite-4.0-h-micro"])
 def test_accuracy_policy_batch_parity_archs(arch):
     """The traced cspec builder must mirror build_lm_cspec on every
     layer family — moe (incl. dense residual), ssm, rglru, attn."""

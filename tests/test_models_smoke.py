@@ -56,7 +56,8 @@ def test_train_step_smoke(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "mixtral-8x22b",
-                                  "mamba2-780m", "recurrentgemma-2b"])
+                                  "mamba2-780m", "recurrentgemma-2b",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_prefill(arch):
     cfg = get_config(arch, smoke=True).replace(param_dtype="float32",
                                                compute_dtype="float32")
