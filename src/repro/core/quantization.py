@@ -14,6 +14,7 @@ statically (zero overhead for the uncompressed dry-run).
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -35,19 +36,21 @@ def _kernel_ops():
     return _fq_ops
 
 
-@jax.custom_jvp
-def _fake_quant_apply_ste(xf: jnp.ndarray, bits, mn: jnp.ndarray,
-                          mx: jnp.ndarray) -> jnp.ndarray:
-    """The kernel's quantize-dequantize pass with a straight-through JVP
-    — ``pallas_call`` has no differentiation rule, so the identity
-    tangent (exactly the STE) is attached here and ``jax.grad`` never
-    traces into the kernel. The range takes no tangent."""
-    return _kernel_ops().fake_quant_apply(xf, bits, mn, mx)
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4,))
+def _fake_quant_apply_ste(x: jnp.ndarray, bits, mn: jnp.ndarray,
+                          mx: jnp.ndarray, dtype) -> jnp.ndarray:
+    """The kernel's quantize-dequantize pass, which writes the
+    straight-through value in ``dtype``, with a straight-through JVP —
+    ``pallas_call`` has no differentiation rule, so the identity tangent
+    (exactly the STE) is attached here and ``jax.grad`` never traces
+    into the kernel. The range takes no tangent."""
+    return _kernel_ops().fake_quant_apply(x, bits, mn, mx, out_dtype=dtype)
 
 
 @_fake_quant_apply_ste.defjvp
-def _fake_quant_apply_ste_jvp(primals, tangents):
-    return _fake_quant_apply_ste(*primals), tangents[0]
+def _fake_quant_apply_ste_jvp(dtype, primals, tangents):
+    return (_fake_quant_apply_ste(*primals, dtype),
+            tangents[0].astype(dtype))
 
 
 def _kernel_route(x: jnp.ndarray, axis) -> bool:
@@ -119,57 +122,64 @@ def _straight_through(xf: jnp.ndarray, xq: jnp.ndarray, bits) -> jnp.ndarray:
                      xf + jax.lax.stop_gradient(xq - xf))
 
 
-def _quant_dequant(xf: jnp.ndarray, bits, src: jnp.ndarray,
-                   axis) -> jnp.ndarray:
-    """f32 ``xf`` quantize-dequantized with the per-channel range of
-    ``src`` (``xf`` itself, or the tensor xf's rows were taken from),
-    reduced over ``axis``; the range takes no gradient. bits >= 32
-    selects ``xf``."""
+def _fake_quant_in_range(x: jnp.ndarray, bits, src: jnp.ndarray, axis,
+                         dtype) -> jnp.ndarray:
+    """``x`` quantize-dequantized with the per-channel range of ``src``
+    (``x`` itself, or the tensor x's rows were taken from), reduced over
+    ``axis``, with straight-through gradients, in ``dtype``; the range
+    takes no gradient. bits >= 32 selects ``x``. The kernel route reads
+    both in their stored dtype and writes the straight-through value in
+    ``dtype`` itself; the reference route computes it in f32 and casts."""
     src = jax.lax.stop_gradient(src)
     if _kernel_route(src, axis):
         mn, mx = _kernel_ops().fake_quant_range(src)
-        return _fake_quant_apply_ste(xf, jnp.asarray(bits, jnp.int32),
-                                     mn, mx)
-    return _reference_fq(xf, bits, *_minmax(src, axis))
+        return _fake_quant_apply_ste(x, jnp.asarray(bits, jnp.int32),
+                                     mn, mx, jnp.dtype(dtype))
+    xf = x.astype(jnp.float32)
+    xq = _reference_fq(xf, bits, *_minmax(src.astype(jnp.float32), axis))
+    return _straight_through(xf, xq, bits).astype(dtype)
 
 
-def fake_quant(x: jnp.ndarray, bits, axis=None) -> jnp.ndarray:
+def fake_quant(x: jnp.ndarray, bits, axis=None, dtype=None) -> jnp.ndarray:
     """Quantize-dequantize with straight-through gradients.
 
     ``bits`` may be a traced int scalar; bits >= 32 selects pass-through.
     ``axis=None`` -> per-channel over the LAST axis (paper: per channel).
+    ``dtype``: the dtype of the consumer, e.g. the matmul that reads the
+    result (None: x's dtype); the result is rounded to it once.
     """
     if axis is None:
         axis = tuple(range(x.ndim - 1))
     # the scope tags these ops in a profiler trace (metadata only)
     with jax.named_scope("fake_quant"):
-        xf = x.astype(jnp.float32)
-        xq = _quant_dequant(xf, bits, xf, axis)
-        return _straight_through(xf, xq, bits).astype(x.dtype)
+        return _fake_quant_in_range(
+            x, bits, x, axis, x.dtype if dtype is None else dtype)
 
 
-def fake_quant_rows(table: jnp.ndarray, idx: jnp.ndarray, bits) -> jnp.ndarray:
-    """``jnp.take(fake_quant_weight(table, bits), idx, axis=0)``, bit for
-    bit and in gradient, with the quantize-dequantize run on the taken
-    rows only: the embedding lookup reads a few hundred rows of a
+def fake_quant_rows(table: jnp.ndarray, idx: jnp.ndarray, bits,
+                    dtype=None) -> jnp.ndarray:
+    """``jnp.take(fake_quant_weight(table, bits, dtype), idx, axis=0)``,
+    bit for bit and in gradient, with the quantize-dequantize run on the
+    taken rows only: the embedding lookup reads a few hundred rows of a
     vocabulary-sized table. The per-channel range is still the whole
     table's (policy-independent, so a vmap over bit widths computes it
     once)."""
     with jax.named_scope("fake_quant"), jax.named_scope("embed_rows"):
-        xf = jnp.take(table, idx, axis=0).astype(jnp.float32)
-        xq = _quant_dequant(xf, bits, table.astype(jnp.float32),
-                            tuple(range(table.ndim - 1)))
-        return _straight_through(xf, xq, bits).astype(table.dtype)
+        return _fake_quant_in_range(jnp.take(table, idx, axis=0), bits,
+                                    table, tuple(range(table.ndim - 1)),
+                                    table.dtype if dtype is None else dtype)
 
 
-def fake_quant_weight(w: jnp.ndarray, bits) -> jnp.ndarray:
-    """Weights: per-OUTPUT-channel range (last axis is the out dim here)."""
-    return fake_quant(w, bits, axis=tuple(range(w.ndim - 1)))
+def fake_quant_weight(w: jnp.ndarray, bits, dtype=None) -> jnp.ndarray:
+    """Weights: per-OUTPUT-channel range (last axis is the out dim here),
+    in the matmul's ``dtype`` (None: w's)."""
+    return fake_quant(w, bits, axis=tuple(range(w.ndim - 1)), dtype=dtype)
 
 
-def fake_quant_act(x: jnp.ndarray, bits) -> jnp.ndarray:
-    """Activations: per-channel over the feature (last) axis."""
-    return fake_quant(x, bits, axis=tuple(range(x.ndim - 1)))
+def fake_quant_act(x: jnp.ndarray, bits, dtype=None) -> jnp.ndarray:
+    """Activations: per-channel over the feature (last) axis, in the
+    matmul's ``dtype`` (None: x's)."""
+    return fake_quant(x, bits, axis=tuple(range(x.ndim - 1)), dtype=dtype)
 
 
 def bits_for_mode(mode: str, mix_bits: int = MAX_MIX_BITS) -> int:

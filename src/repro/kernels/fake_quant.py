@@ -11,16 +11,19 @@ table has far more rows than one block can hold):
    over row blocks. The row axis is the innermost grid axis and the
    (1, bc) min/max outputs stay resident across it, accumulating.
 2. ``_quant_kernel`` (``fake_quant_apply_2d``) derives scale/offset from
-   the range and writes the quantize-clip-dequantize of each (br, bc)
-   block.
+   the range and writes the straight-through value of the
+   quantize-clip-dequantize of each (br, bc) block, cast to the dtype of
+   the matmul that reads it.
 
-So x is read twice and written once. The second pass is elementwise once
-the range is known, so it may run on a gathered subset of x's rows with
-x's range (the embedding lookup does). Shapes must be kernel-legal before
-the call (``kernels.ops._fq_layout`` pads them): C a multiple of
-``bc`` (itself a multiple of 128) and R a multiple of ``br`` (a multiple
-of 8, or R itself). ``bits`` is an int32 scalar in SMEM, held as a
-(1, 1) array so that a vmapped bit width stays a legal block.
+Both passes read x in the dtype it is stored in and compute in f32, so
+x is read twice and written once, in the consumer's dtype. The second
+pass is elementwise once the range is known, so it may run on a gathered
+subset of x's rows with x's range (the embedding lookup does). Shapes
+must be kernel-legal before the call (``kernels.ops._fq_layout`` pads
+them): C a multiple of ``bc`` (itself a multiple of 128) and R a
+multiple of ``br`` (a multiple of 8, or R itself). ``bits`` is an int32
+scalar in SMEM, held as a (1, 1) array so that a vmapped bit width stays
+a legal block.
 """
 from __future__ import annotations
 
@@ -59,9 +62,12 @@ def _quant_kernel(bits_ref, x_ref, mn_ref, mx_ref, o_ref):
     z = jnp.floor(s * x_min) + 2.0 ** (b - 1.0)
     q = jnp.clip(jnp.floor(s * x - z), -n, n)
     deq = (q + z + 0.5) / s
-    # bits >= 32 passes x through untouched (a select, no arithmetic, so
-    # denormals survive)
-    o_ref[...] = jnp.where(bits >= 32.0, x, deq).astype(o_ref.dtype)
+    # the straight-through value x + (deq - x) of
+    # core.quantization._straight_through, rounded once to the output
+    # dtype; bits >= 32 passes x through untouched (a select, no
+    # arithmetic, so denormals survive)
+    o = jnp.where(bits >= 32.0, x, x + (deq - x))
+    o_ref[...] = o.astype(o_ref.dtype)
 
 
 def fake_quant_range_2d(x: jnp.ndarray, *, br: int, bc: int,
@@ -85,11 +91,13 @@ def fake_quant_range_2d(x: jnp.ndarray, *, br: int, bc: int,
 
 def fake_quant_apply_2d(x: jnp.ndarray, bits, mn: jnp.ndarray,
                         mx: jnp.ndarray, *, br: int, bc: int,
+                        out_dtype=None,
                         interpret: bool = True) -> jnp.ndarray:
     """x [R, C]: quantize-dequantize with the per-channel range rows
-    ``mn``, ``mx`` [1, C], on (br, bc) blocks. The range need not be x's
-    own: any rows of a tensor take that tensor's range. ``bits`` may be
-    a traced int scalar."""
+    ``mn``, ``mx`` [1, C], on (br, bc) blocks, written as the
+    straight-through value in ``out_dtype`` (None: x's dtype). The range
+    need not be x's own: any rows of a tensor take that tensor's range.
+    ``bits`` may be a traced int scalar."""
     R, C = x.shape
     grid_r, grid_c = R // br, C // bc
     bits_arr = jnp.reshape(jnp.asarray(bits, jnp.int32), (1, 1))
@@ -101,7 +109,8 @@ def fake_quant_apply_2d(x: jnp.ndarray, bits, mn: jnp.ndarray,
                   pl.BlockSpec((1, bc), lambda i, j: (0, j)),
                   pl.BlockSpec((1, bc), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (R, C), x.dtype if out_dtype is None else out_dtype),
         interpret=interpret,
         name="fake_quant_apply",
     )(bits_arr, x, mn, mx)

@@ -107,15 +107,17 @@ def fake_quant_range(x: jnp.ndarray):
     return mn, mx
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("out_dtype",))
 def fake_quant_apply(x: jnp.ndarray, bits, mn: jnp.ndarray,
-                     mx: jnp.ndarray) -> jnp.ndarray:
+                     mx: jnp.ndarray, out_dtype=None) -> jnp.ndarray:
     """Per-channel (last axis) quantize-dequantize of an arbitrary-rank
     tensor with the range ``fake_quant_range`` gave, of x or of any
-    tensor x's rows were taken from."""
+    tensor x's rows were taken from: the straight-through value, in
+    ``out_dtype`` (None: x's dtype)."""
     shape = x.shape
     xp, br, bc = _fq_layout(x)
     out = _fq.fake_quant_apply_2d(xp, bits, mn, mx, br=br, bc=bc,
+                                  out_dtype=out_dtype,
                                   interpret=not _on_tpu())
     return out[:x.size // shape[-1], :shape[-1]].reshape(shape)
 

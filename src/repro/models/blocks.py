@@ -271,8 +271,8 @@ def apply_moe(p, x, cfg: ArchConfig, cspec=None):
     w_down = L.getw(p, "w_down", dt)
     if qs_up is not None:
         xe = L.fq_act(xe, qs_up["a_bits"])
-        w_up = L.fq_weight(w_up, qs_up["w_bits"])
-        w_gate = L.fq_weight(w_gate, qs_up["w_bits"])
+        w_up = L.fq_weight(w_up, qs_up["w_bits"], xe.dtype)
+        w_gate = L.fq_weight(w_gate, qs_up["w_bits"], xe.dtype)
     up = jnp.einsum("gecd,edf->gecf", xe, w_up.astype(xe.dtype))
     gate = jnp.einsum("gecd,edf->gecf", xe, w_gate.astype(xe.dtype))
     h = L.mlp_act("swiglu" if cfg.mlp == "swiglu" else "geglu", gate, up)
@@ -281,7 +281,7 @@ def apply_moe(p, x, cfg: ArchConfig, cspec=None):
     h = shard(h, "batch", "experts", None, "ff")
     if qs_down is not None:
         h = L.fq_act(h, qs_down["a_bits"])
-        w_down = L.fq_weight(w_down, qs_down["w_bits"])
+        w_down = L.fq_weight(w_down, qs_down["w_bits"], h.dtype)
     ye = jnp.einsum("gecf,efd->gecd", h, w_down.astype(h.dtype))
     if m.combine == "reduce_scatter":
         # §Perf A2: the down-proj contracts over the model-sharded ff dim;
@@ -427,7 +427,7 @@ def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
     xin = x
     if qs_in is not None:
         xin = L.fq_act(xin, qs_in["a_bits"])
-        w_in = L.fq_weight(w_in, qs_in["w_bits"])
+        w_in = L.fq_weight(w_in, qs_in["w_bits"], x.dtype)
     proj = jnp.einsum("bsd,dk->bsk", xin, w_in.astype(x.dtype))
     z, xbc, dt = jnp.split(proj, [d_inner, d_inner + conv_dim], axis=-1)
     y_conv, new_conv = L.causal_conv1d(xbc, p["conv_w"], conv_state,
@@ -464,7 +464,7 @@ def _ssm_inner(p, x, cfg, cspec, conv_state, ssm_state, *, decode=False):
     w_out = L.getw(p, "out_proj", y.dtype)
     if qs_out is not None:
         y = L.fq_act(y, qs_out["a_bits"])
-        w_out = L.fq_weight(w_out, qs_out["w_bits"])
+        w_out = L.fq_weight(w_out, qs_out["w_bits"], y.dtype)
     out = jnp.einsum("bsd,dk->bsk", y, w_out.astype(y.dtype))
     return out, new_conv, new_state
 
@@ -553,8 +553,8 @@ def apply_rglru(p, x, cfg: ArchConfig, cspec=None):
     xin = x
     if qs_in is not None:
         xin = L.fq_act(xin, qs_in["a_bits"])
-        w_x = L.fq_weight(w_x, qs_in["w_bits"])
-        w_y = L.fq_weight(w_y, qs_in["w_bits"])
+        w_x = L.fq_weight(w_x, qs_in["w_bits"], x.dtype)
+        w_y = L.fq_weight(w_y, qs_in["w_bits"], x.dtype)
     y = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", xin, w_y.astype(x.dtype)))
     u = jnp.einsum("bsd,dw->bsw", xin, w_x.astype(x.dtype))
     u, _ = L.causal_conv1d(u, p["conv_w"])
@@ -566,7 +566,7 @@ def apply_rglru(p, x, cfg: ArchConfig, cspec=None):
     g = shard(g, "batch", "seq", "ff")
     if qs_out is not None:
         g = L.fq_act(g, qs_out["a_bits"])
-        w_out = L.fq_weight(w_out, qs_out["w_bits"])
+        w_out = L.fq_weight(w_out, qs_out["w_bits"], g.dtype)
     return jnp.einsum("bsw,wd->bsd", g, w_out.astype(g.dtype))
 
 
@@ -586,8 +586,8 @@ def decode_rglru(p, x, cache, pos, cfg: ArchConfig, cspec=None):
     xin = x
     if qs_in is not None:
         xin = L.fq_act(xin, qs_in["a_bits"])
-        w_x = L.fq_weight(w_x, qs_in["w_bits"])
-        w_y = L.fq_weight(w_y, qs_in["w_bits"])
+        w_x = L.fq_weight(w_x, qs_in["w_bits"], x.dtype)
+        w_y = L.fq_weight(w_y, qs_in["w_bits"], x.dtype)
     y = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", xin, w_y.astype(x.dtype)))
     u = jnp.einsum("bsd,dw->bsw", xin, w_x.astype(x.dtype))
     u, conv = L.causal_conv1d(u, p["conv_w"], cache["conv"])
@@ -598,6 +598,6 @@ def decode_rglru(p, x, cache, pos, cfg: ArchConfig, cspec=None):
         g = g * wmask.astype(g.dtype)
     if qs_out is not None:
         g = L.fq_act(g, qs_out["a_bits"])
-        w_out = L.fq_weight(w_out, qs_out["w_bits"])
+        w_out = L.fq_weight(w_out, qs_out["w_bits"], g.dtype)
     out = jnp.einsum("bsw,wd->bsd", g, w_out.astype(g.dtype))
     return out, {"state": h, "conv": conv}

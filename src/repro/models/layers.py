@@ -49,7 +49,7 @@ def apply_quant(x: jnp.ndarray, w: jnp.ndarray, qs: Optional[dict]):
     """Apply activation/weight fake quantization per the spec."""
     if qs is not None:
         x = fake_quant_act(x, qs["a_bits"])
-        w = fake_quant_weight(w, qs["w_bits"])
+        w = fake_quant_weight(w, qs["w_bits"], x.dtype)
     return x, w
 
 

@@ -160,14 +160,15 @@ def _embed_inputs(cfg: ArchConfig, params, tokens, embeds, cspec):
     ebits = None if cspec is None else cspec.get("embed_bits")
     if cfg.frontend == "audio_stub":
         return embeds  # [B, S, d] straight from the (stub) frontend
-    table = L.getw(params, "embed", L.dtype_of(cfg.compute_dtype))
+    dtype = L.dtype_of(cfg.compute_dtype)
+    table = L.getw(params, "embed", dtype)
     if ebits is not None:
         # only the looked-up rows are fake-quantized (with the table's
         # range): the same values as quantizing the whole table first
-        x = L.fq_rows(table, tokens, ebits)
+        x = L.fq_rows(table, tokens, ebits, dtype)
     else:
         x = jnp.take(table, tokens, axis=0)
-    x = x.astype(L.dtype_of(cfg.compute_dtype))
+    x = x.astype(dtype)
     if cfg.embedding_multiplier != 1.0:
         x = x * cfg.embedding_multiplier
     if cfg.frontend == "vision_stub" and embeds is not None:
@@ -183,7 +184,7 @@ def _unembed(cfg: ArchConfig, params, x, cspec):
     else:
         w = L.getw(params, "unembed", x.dtype)
     if hbits is not None:
-        w = L.fq_weight(w, hbits)
+        w = L.fq_weight(w, hbits, x.dtype)
     logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
     logits = logits.astype(jnp.float32)
     if cfg.logits_scaling != 1.0:

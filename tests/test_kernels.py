@@ -388,3 +388,75 @@ def test_fused_polyak_paper_sizes(dims):
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref_out)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
+
+
+# ------------------------ the apply pass writes the matmul's operand
+
+_FQ_BITS = [1, 2, 4, 6, 8, 32]
+_FQ_DTYPES = [(jnp.float32, jnp.float32), (jnp.float32, jnp.bfloat16),
+              (jnp.bfloat16, jnp.float32), (jnp.bfloat16, jnp.bfloat16)]
+
+
+def _fq_reference(x, bits, out_dtype):
+    """The reference route: quantize-dequantize in f32, the
+    straight-through select, then one cast to the consumer's dtype."""
+    from repro.core import quantization as Q
+    xf = x.astype(jnp.float32)
+    xq = Q._reference_fq(xf, bits, *Q._minmax(xf, tuple(range(x.ndim - 1))))
+    return Q._straight_through(xf, xq, bits).astype(out_dtype)
+
+
+def _fq_kernel(x, bits, out_dtype):
+    return ops.fake_quant_apply(x, bits, *ops.fake_quant_range(x),
+                                out_dtype=out_dtype)
+
+
+def _assert_bits_equal(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    u = {2: np.uint16, 4: np.uint32}[a.dtype.itemsize]
+    np.testing.assert_array_equal(a.view(u), b.view(u))
+
+
+def _fq_input(shape, dtype):
+    """Normal values, one channel constant, one denormal entry."""
+    x = jax.random.normal(jax.random.PRNGKey(sum(shape)), shape) * 3.0
+    x = x.at[..., 1].set(0.5).at[..., 0, 2].set(1e-40)
+    return x.astype(dtype)
+
+
+# 1100 rows have no multiple-of-8 divisor, so the rows are padded, and
+# 200 channels pad to 256 lanes
+@pytest.mark.parametrize("din,dout", _FQ_DTYPES)
+@pytest.mark.parametrize("bits", _FQ_BITS)
+def test_fake_quant_apply_writes_the_straight_through_operand(bits, din,
+                                                             dout):
+    """The kernel's output, in the consumer's dtype, is the reference
+    route's straight-through value cast once, bit for bit; at 32 bits
+    it is x itself, the denormal included."""
+    x = _fq_input((1100, 200), din)
+    got = jax.jit(_fq_kernel, static_argnums=2)(x, jnp.int32(bits), dout)
+    _assert_bits_equal(got, jax.jit(_fq_reference, static_argnums=2)(
+        x, jnp.int32(bits), dout))
+    if bits == 32:
+        _assert_bits_equal(got, x.astype(dout))
+
+
+# Granite-4.0-H-Micro's in_proj width, 8512 (padded to 8576), on 1024
+# weight rows shared by K=4 policies, and on an activation slab of 2 x 64
+# tokens, one per policy
+@pytest.mark.parametrize("din,dout", _FQ_DTYPES)
+@pytest.mark.parametrize("shape,shared", [((1024, 8512), True),
+                                          ((4, 2, 64, 8512), False)])
+def test_fake_quant_apply_operand_vmapped_bits(shape, shared, din, dout):
+    x = _fq_input(shape, din)
+    bits = jnp.array([1, 4, 8, 32], jnp.int32)
+    axes = (None if shared else 0, 0)
+
+    def run(f):
+        return jax.jit(jax.vmap(lambda xx, b: f(xx, b, dout),
+                                in_axes=axes))(x, bits)
+
+    got = run(_fq_kernel)
+    assert got.shape == (4,) + (shape if shared else shape[1:])
+    _assert_bits_equal(got, run(_fq_reference))

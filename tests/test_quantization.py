@@ -143,15 +143,16 @@ def _embed_table():
     return t.at[:, 0].set(0.0).at[3, 5].set(1e-40)
 
 
-def _take_of_fake_quant(table, idx, bits):
+def _take_of_fake_quant(table, idx, bits, dtype=None):
     """The whole-table formulation fake_quant_rows replaces."""
-    return jnp.take(fake_quant_weight(table, bits), idx, axis=0)
+    return jnp.take(fake_quant_weight(table, bits, dtype), idx, axis=0)
 
 
 def _assert_bitwise(a, b):
     a, b = np.asarray(a), np.asarray(b)
     assert a.shape == b.shape and a.dtype == b.dtype
-    np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+    u = {2: np.uint16, 4: np.uint32}[a.dtype.itemsize]
+    np.testing.assert_array_equal(a.view(u), b.view(u))
 
 
 @pytest.fixture(scope="module")
@@ -278,3 +279,131 @@ def test_validation_quantizes_only_embedding_rows(route, monkeypatch):
     monkeypatch.setattr(L, "fq_rows", _take_of_fake_quant)
     want = jax.jit(logits)(cm.params, *pols)
     _assert_bitwise(got, want)
+
+
+# ------------------------------ fake quant in the consumer's dtype
+
+_BITS = [1, 2, 4, 6, 8, 32]
+
+
+@pytest.mark.parametrize("dout", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("din", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("bits", _BITS)
+def test_fake_quant_in_consumer_dtype_is_one_cast(bits, din, dout,
+                                                  monkeypatch):
+    """``fake_quant(x, bits, dtype=d)`` is the input-dtype result cast to
+    d, bit for bit, on the (interpreted) kernel route and the reference
+    route alike: the straight-through value rounded once."""
+    from repro.core.quantization import fake_quant_act
+    x = (jax.random.normal(jax.random.PRNGKey(bits), (3, 40, 200)) * 3.0
+         ).astype(din)
+    b = jnp.int32(bits)
+    outs = {}
+    for route in ("0", "1"):
+        monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+        got = jax.jit(fake_quant_act, static_argnums=2)(x, b, dout)
+        assert got.dtype == dout
+        outs[route] = got
+        if jnp.dtype(din).itemsize >= jnp.dtype(dout).itemsize:
+            _assert_bitwise(got, jax.jit(fake_quant_act)(x, b).astype(dout))
+    _assert_bitwise(outs["1"], outs["0"])
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+@pytest.mark.parametrize("bits", [2, 32])
+def test_fake_quant_weight_bf16_gradient_is_identity(route, bits,
+                                                     monkeypatch):
+    """Through ``fake_quant_weight(w, bits, dtype=bf16)`` the f32 weight's
+    gradient is the cotangent itself (rounded to the bf16 the forward
+    emits): the straight-through estimator on both routes."""
+    monkeypatch.setenv("GALEN_FQ_KERNEL", route)
+    w = jax.random.normal(jax.random.PRNGKey(3), (64, 200))
+    cot = jax.random.normal(jax.random.PRNGKey(4), (64, 200))
+    g = jax.jit(jax.grad(lambda t: jnp.sum(
+        fake_quant_weight(t, jnp.int32(bits), jnp.bfloat16)
+        .astype(jnp.float32) * cot)))(w)
+    _assert_bitwise(g, cot.astype(jnp.bfloat16).astype(jnp.float32))
+
+
+def _f32_operand_fake_quant(x, bits, dtype=None):
+    """Fake quant as validation ran it when the apply pass wrote f32: the
+    quantize-dequantize and straight-through select in f32, cast back to
+    x's own dtype whatever the consumer reads (which casts again)."""
+    from repro.core.quantization import (_minmax, _reference_fq,
+                                         _straight_through)
+    with jax.named_scope("fake_quant"):
+        xf = x.astype(jnp.float32)
+        xq = _reference_fq(xf, bits, *_minmax(xf, tuple(range(x.ndim - 1))))
+        return _straight_through(xf, xq, bits).astype(x.dtype)
+
+
+def _hybrid_smoke_lm():
+    from repro.configs.granite_4_0_h_micro import SMOKE
+    from repro.core.compress import CompressibleLM
+    from repro.models import model as M
+    return CompressibleLM(SMOKE, M.init(SMOKE, jax.random.PRNGKey(0)))
+
+
+def _vmapped_validation(cm, K=4, seq=16):
+    """``(logits_fn, args)``: the validation forward vmapped over K
+    policies' bit widths and keeps with the weights shared, as the
+    search's validation runs it."""
+    from repro.core.policy import Policy, map_actions, stack_policies
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, seq),
+                                          0, cm.cfg.vocab_size)}
+    rng = np.random.default_rng(5)
+    pb = stack_policies(cm.specs, [
+        Policy([map_actions(s, rng.random(3), "pq") for s in cm.specs])
+        for _ in range(K)])
+    pols = tuple(jnp.asarray(a, jnp.int32)
+                 for a in (pb.keep, pb.w_bits, pb.a_bits))
+    build = cm.cspec_builder()
+    fn = jax.vmap(lambda p, k, w, a: cm.logits(batch, build(k, w, a), p),
+                  in_axes=(None, 0, 0, 0))
+    return fn, (cm.params,) + pols
+
+
+def _f32_weight_copies(jaxpr, params, K):
+    """Outputs under the ``fake_quant`` scope that are f32 [K, in, out]
+    copies of a weight, for K policies."""
+    mats = {leaf.shape[-2:] for leaf in jax.tree.leaves(params)
+            if leaf.ndim >= 2}
+    mats |= {m[::-1] for m in mats}
+    return [v.aval for e in _eqns(jaxpr)
+            if "fake_quant" in str(e.source_info.name_stack)
+            for v in e.outvars
+            if getattr(v.aval, "dtype", None) == jnp.float32
+            and v.aval.shape[:1] == (K,) and v.aval.shape[1:] in mats]
+
+
+@pytest.mark.parametrize("build_lm", [_tiny_tied_lm, _hybrid_smoke_lm],
+                         ids=["tied", "hybrid"])
+def test_validation_fake_quant_emits_matmul_operand(build_lm, monkeypatch):
+    """On the kernel route, every apply pass of the vmapped validation
+    writes the compute dtype, no f32 [K, in, out] copy of a weight is
+    made, and the logits are the f32-operand formulation's, bit for
+    bit."""
+    from repro.models import layers as L
+    monkeypatch.setenv("GALEN_FQ_KERNEL", "1")
+    jax.clear_caches()      # no trace made on another route is reused
+    cm, K = build_lm(), 4
+    compute = L.dtype_of(cm.cfg.compute_dtype)
+    logits, args = _vmapped_validation(cm, K)
+
+    jaxpr = jax.make_jaxpr(logits)(*args).jaxpr
+    applies = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"
+               and e.params["name"] == "fake_quant_apply"]
+    assert applies and all(v.aval.dtype == compute
+                           for e in applies for v in e.outvars)
+    assert not _f32_weight_copies(jaxpr, cm.params, K)
+
+    got = jax.jit(logits)(*args)
+    for name in ("fake_quant_act", "fake_quant_weight", "fq_act",
+                 "fq_weight"):
+        monkeypatch.setattr(L, name, _f32_operand_fake_quant)
+    monkeypatch.setattr(L, "fq_rows", lambda t, i, b, dtype=None: jnp.take(
+        _f32_operand_fake_quant(t, b), i, axis=0))
+    jax.clear_caches()      # the layer scan's body was traced unpatched
+    old = jax.make_jaxpr(logits)(*args).jaxpr
+    assert _f32_weight_copies(old, cm.params, K)    # what the check finds
+    _assert_bitwise(got, jax.jit(logits)(*args))

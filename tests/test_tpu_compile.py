@@ -91,6 +91,30 @@ def test_fake_quant_embedding_rows_compiles(compile_tpu):
                 _s((4,), jnp.int32))
 
 
+def _fq_bf16(x, bits):
+    """One policy's fake quant through both passes, written as the bf16
+    operand of the matmul that reads it."""
+    mn, mx = ops.fake_quant_range(x)
+    return ops.fake_quant_apply(x, bits, mn, mx, out_dtype=jnp.bfloat16)
+
+
+# Granite-4.0-H-Micro widths: in_proj 2048 x 8512 (8512 channels pad to
+# 8576), the FFN's down projection 8192 x 2048, and a bf16 activation
+# slab of 2 x 512 tokens x 8192 for each of K=4 policies
+@pytest.mark.parametrize("shape,dtype", [
+    ((2048, 8512), jnp.float32), ((8192, 2048), jnp.float32),
+    ((4, 1024, 8192), jnp.bfloat16)])
+def test_fake_quant_bf16_apply_vmapped_compiles(compile_tpu, shape, dtype):
+    """Validation vmaps the bit width over K policies: a weight is shared
+    (its block fetched once per policy), an activation is each policy's
+    own."""
+    shared = len(shape) == 2
+    fn = jax.vmap(_fq_bf16, in_axes=(None if shared else 0, 0))
+    compiled = compile_tpu(fn, _s(shape, dtype), _s((4,), jnp.int32))
+    out = compiled.out_info
+    assert out.dtype == jnp.bfloat16 and out.shape == (4,) + shape[-2:]
+
+
 @pytest.mark.parametrize("dims,final", [((16, 400, 300, 1), "sigmoid"),
                                         ((17, 400, 300, 1), "linear")])
 def test_mlp3_compiles(compile_tpu, dims, final):
