@@ -4,11 +4,10 @@ compression ratios c (pruning / quantization / joint).
 Reports MACs fraction, BOPs, oracle latency ratio, accuracy before and
 after a short QAT retrain (the paper retrains 30 epochs).
 
-``engine`` picks how the three agents are searched: "scalar" (the
-reference loop, default), or "population" — batched rollouts with the
-p/q/pq agents sharing every update dispatch through one
-``jit(vmap(update_chunk))`` (``PopulationSearch``; action dims padded
-to the joint agent's 3)."""
+``engine`` picks how the three agents are searched: "independent" (one
+search after another, default), or "population" — the p/q/pq agents
+run in lockstep as one ``PopulationSearch`` (action dims padded to the
+joint agent's 3)."""
 from __future__ import annotations
 
 import json
@@ -17,8 +16,8 @@ import time
 
 import jax
 
-from benchmarks.search_setup import lm_search
-from repro.core.search import BatchedCompressionSearch, PopulationSearch
+from benchmarks.common import EPISODES, lm_search
+from repro.core.search import PopulationSearch
 from repro.optim.optimizer import OptimizerConfig, adamw_init
 from repro.train.train_step import make_train_step
 
@@ -50,7 +49,7 @@ AGENTS = (("p", "Pruning Agent"), ("q", "Quantization A."),
 
 def _search_trio(c, engine: str):
     """(search, result, elapsed_s) per agent, under the chosen engine."""
-    if engine == "scalar":
+    if engine == "independent":
         out = []
         for methods, _label in AGENTS:
             t0 = time.time()
@@ -61,11 +60,10 @@ def _search_trio(c, engine: str):
     if engine == "population":
         # members share one episode count (PopulationSearch runs the
         # population in lockstep); use the trio's maximum so no agent
-        # gets a smaller search budget than under the scalar engine
-        from benchmarks.search_setup import EPISODES
+        # gets a smaller search budget than when searched independently
         episodes = max(EPISODES[m] for m, _label in AGENTS)
-        searches = [lm_search(m, c, seed=1, cls=BatchedCompressionSearch,
-                              episodes=episodes, action_dim=3, batch_size=8)
+        searches = [lm_search(m, c, seed=1, episodes=episodes,
+                              action_dim=3)
                     for m, _label in AGENTS]
         t0 = time.time()
         results = PopulationSearch(searches).run(episodes=episodes)
@@ -75,7 +73,7 @@ def _search_trio(c, engine: str):
 
 
 def run(cs=(0.5, 0.35), retrain: bool = True, verbose: bool = True,
-        engine: str = "scalar"):
+        engine: str = "independent"):
     rows = []
     for c in cs:
         trio = _search_trio(c, engine)
@@ -106,15 +104,11 @@ def run(cs=(0.5, 0.35), retrain: bool = True, verbose: bool = True,
     return rows
 
 
-def main(out="artifacts/bench_table1.json",
-         engine_out="artifacts/bench_engine.json"):
+def main(out="artifacts/bench_table1.json"):
     rows = run()
     os.makedirs(os.path.dirname(out), exist_ok=True)
     with open(out, "w") as f:
         json.dump(rows, f, indent=1)
-    # engine throughput rows (scalar-vs-batched + population; own schema)
-    from benchmarks.search_setup import main as engine_main
-    engine_main(out=engine_out)
     return rows
 
 

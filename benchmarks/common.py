@@ -9,6 +9,8 @@
 Trained weights are cached under artifacts/ so every benchmark and test
 reuses one training run. The latency-oracle context is the batch-1 decode
 scenario (single-stream serving — the embedded-device analogue).
+``lm_search`` and ``resnet_search`` build the search engine on them with
+the paper-table settings.
 """
 from __future__ import annotations
 
@@ -19,7 +21,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.compress import CompressibleLM, CompressibleResNet
+from repro.core.ddpg import DDPGConfig
 from repro.core.latency import LatencyContext
+from repro.core.reward import RewardConfig
+from repro.core.search import CompressionSearch, SearchConfig
+from repro.core.sensitivity import SensitivityResult, run_sensitivity
 from repro.models.resnet import ResNetConfig
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
@@ -37,6 +44,16 @@ RESNET_CFG = ResNetConfig(name="testbed-resnet", stages=(2, 2, 2),
 SERVE_CTX = LatencyContext(tokens=1, seq_ctx=512, mode="decode", batch=1)
 # image-classification context for the ResNet testbed (per-image latency)
 IMG_CTX = LatencyContext(tokens=1, seq_ctx=0, mode="prefill", batch=1)
+
+FULL = os.environ.get("GALEN_BENCH_FULL", "0") == "1"
+
+# paper: 310 (quant) / 410 (prune, joint) episodes, 10 warm-up.
+EPISODES = {"p": 410, "q": 310, "pq": 410} if FULL else \
+    {"p": 60, "q": 50, "pq": 60}
+WARMUP = 10
+UPDATES = 48 if FULL else 24
+
+_sens_cache = {}
 
 
 def _cache(path, builder):
@@ -79,3 +96,48 @@ def get_resnet_testbed(steps: int = 200):
     params = jax.tree.map(jnp.asarray, d["params"])
     val = jax.tree.map(jnp.asarray, d["val"])
     return RESNET_CFG, params, val, d["acc"]
+
+
+def lm_search(methods: str, c: float, seed: int = 0, episodes=None,
+              sens_enabled: bool = True,
+              action_dim: int = 0) -> CompressionSearch:
+    """The search engine on the LM testbed. ``action_dim`` > the
+    method's native count pads the agent's action space (required for
+    mixed-method PopulationSearch members)."""
+    cfg, params, val, acc = get_lm_testbed()
+    # smaller eval batch: ~2x faster episodes, ±2% accuracy noise (the
+    # paper also validates on a small split during search)
+    val = {k: v[:32] for k, v in val.items()}
+    cm = CompressibleLM(cfg, params)
+    key = ("lm", sens_enabled)
+    if key not in _sens_cache:
+        # disabled: an empty table gives constant sensitivity features
+        _sens_cache[key] = run_sensitivity(cm, val) if sens_enabled \
+            else SensitivityResult({s.name: {} for s in cm.specs})
+    scfg = SearchConfig(
+        methods=methods,
+        episodes=episodes or EPISODES[methods],
+        reward=RewardConfig(target_ratio=c, beta=-3.0),
+        ddpg=DDPGConfig(warmup_episodes=WARMUP, updates_per_episode=UPDATES,
+                        batch_size=128, buffer_size=2000,
+                        action_dim=action_dim or 1),
+        seed=seed)
+    return CompressionSearch(cm, val, scfg, SERVE_CTX, sens=_sens_cache[key])
+
+
+def resnet_search(methods: str, c: float, seed: int = 0,
+                  episodes=None) -> CompressionSearch:
+    """The search engine on the ResNet testbed."""
+    rcfg, params, val, acc = get_resnet_testbed()
+    cm = CompressibleResNet(rcfg, params)
+    if "resnet" not in _sens_cache:
+        _sens_cache["resnet"] = run_sensitivity(cm, val)
+    scfg = SearchConfig(
+        methods=methods,
+        episodes=episodes or EPISODES[methods],
+        reward=RewardConfig(target_ratio=c, beta=-3.0),
+        ddpg=DDPGConfig(warmup_episodes=WARMUP, updates_per_episode=UPDATES,
+                        batch_size=128, buffer_size=2000),
+        seed=seed)
+    return CompressionSearch(cm, val, scfg, IMG_CTX,
+                             sens=_sens_cache["resnet"])

@@ -4,7 +4,7 @@ from __future__ import annotations
 import json
 import os
 
-from benchmarks.search_setup import lm_search
+from benchmarks.common import lm_search
 
 
 def render_policy(specs, policy, width: int = 24) -> list[str]:

@@ -5,15 +5,14 @@ from __future__ import annotations
 import json
 import os
 
-from benchmarks.search_setup import lm_search
+from benchmarks.common import FULL, lm_search
 
 CS_FULL = (0.25, 0.3, 0.4, 0.5, 0.6, 0.7)
 CS_FAST = (0.3, 0.5, 0.7)
 
 
 def run(cs=None, agents=("p", "q", "pq"), verbose=True):
-    import benchmarks.search_setup as S
-    cs = cs or (CS_FULL if S.FULL else CS_FAST)
+    cs = cs or (CS_FULL if FULL else CS_FAST)
     rows = []
     labels = {"p": "pruning", "q": "quantization", "pq": "joint"}
     for c in cs:

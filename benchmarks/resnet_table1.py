@@ -9,7 +9,7 @@ from __future__ import annotations
 import json
 import os
 
-from benchmarks.search_setup import resnet_search
+from benchmarks.common import resnet_search
 
 
 def run(cs=(0.5, 0.35), verbose=True):

@@ -10,8 +10,6 @@ sweeps (hours on one CPU core).
   fig3    — policy analysis (paper Fig. 3)
   table2  — sensitivity ablation (paper Tab. 2 / Fig. 6-7)
   fig5    — sequential vs joint (paper App. A)         [FULL only]
-  roofline— §Roofline table from the dry-run artifacts
-  kernels — Pallas kernel micro-bench (CPU interpret)
 """
 from __future__ import annotations
 
@@ -34,11 +32,10 @@ def _stage(name, fn):
 
 
 def main() -> None:
-    from benchmarks import (agent_comparison, kernel_bench, policy_analysis,
-                            rate_sweep, roofline, sensitivity_ablation)
+    from benchmarks import (agent_comparison, policy_analysis, rate_sweep,
+                            sensitivity_ablation)
 
     print("name,us_per_call,derived")
-    _stage("bench.kernels", lambda: kernel_bench.run(verbose=True))
     _stage("bench.table1_agent_comparison", lambda: agent_comparison.main())
     _stage("bench.fig4_rate_sweep", lambda: rate_sweep.main())
     _stage("bench.fig3_policy_analysis", lambda: policy_analysis.main())
@@ -48,7 +45,6 @@ def main() -> None:
         _stage("bench.fig5_sequential_vs_joint",
                lambda: sequential_vs_joint.main())
         _stage("bench.resnet_table1", lambda: resnet_table1.main())
-    _stage("bench.roofline", lambda: roofline.main(verbose=True))
     print("bench.done,0,ok")
 
 

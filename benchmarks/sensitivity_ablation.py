@@ -7,8 +7,7 @@ import os
 
 import numpy as np
 
-from benchmarks.common import get_lm_testbed
-from benchmarks.search_setup import lm_search
+from benchmarks.common import get_lm_testbed, lm_search
 from repro.core.compress import CompressibleLM
 from repro.core.sensitivity import full_sweep
 

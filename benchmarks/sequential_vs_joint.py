@@ -12,13 +12,20 @@ import copy
 import json
 import os
 
-from benchmarks.search_setup import lm_search
+import jax.numpy as jnp
+
+from benchmarks.common import lm_search
+from repro.core.policy import stack_policies
 
 
 def _frozen_steps(search, frozen_policy, frozen_methods):
     """Apply a previous policy's CMPs as the starting reference so the
     second-stage agent only controls its own method's parameters."""
     search.ref_policy = copy.deepcopy(frozen_policy)
+    # the rollout starts every episode from these (keep, w_bits, a_bits)
+    pb = stack_policies(search.specs, [search.ref_policy])
+    search._ref_rows = tuple(jnp.asarray(x[0], jnp.float32)
+                             for x in (pb.keep, pb.w_bits, pb.a_bits))
     # re-derive reference latency from the frozen starting point
     from repro.core.latency import policy_latency
     search.ref_lat_frozen = policy_latency(search.specs, search.ref_policy,

@@ -230,7 +230,7 @@ def kernel_checks(cm, ddpg_cfg) -> dict:
 def one_chip(cfg, sizes: Sizes, seed: int) -> dict:
     from repro.core.measure import measure_policy
     from repro.core.policy import Policy
-    from repro.core.search import FusedCompressionSearch, SearchConfig
+    from repro.core.search import CompressionSearch, SearchConfig
     from repro.core.sensitivity import run_sensitivity
     from repro.launch.serve import decode_loop, sustained_throughput
     from repro.train.train_step import make_prefill_step
@@ -246,7 +246,7 @@ def one_chip(cfg, sizes: Sizes, seed: int) -> dict:
 
     K, E = sizes.search_k, sizes.epoch_batches
     with phase("search"):
-        search = FusedCompressionSearch(
+        search = CompressionSearch(
             cm, val, SearchConfig(methods="pq", oracle_mode="analytic",
                                   seed=seed),
             serve_ctx(sizes), sens=sens, batch_size=K, epoch_batches=E)
@@ -329,7 +329,7 @@ def _fleet_records(results):
 def fleet_parity(cfg, sizes: Sizes, seed: int, data: int) -> dict:
     """Run the fleet sharded over ``data`` devices and on one device,
     from the same seeds, and compare the records."""
-    from repro.core.search import (FleetSearch, FusedCompressionSearch,
+    from repro.core.search import (CompressionSearch, FleetSearch,
                                    SearchConfig)
     from repro.core.sensitivity import run_sensitivity
     from repro.launch.mesh import make_dev_mesh
@@ -344,7 +344,7 @@ def fleet_parity(cfg, sizes: Sizes, seed: int, data: int) -> dict:
         check_sensitivity(sens)
 
     def arm(mesh):
-        members = [FusedCompressionSearch(
+        members = [CompressionSearch(
             cm, val, SearchConfig(methods="pq", oracle_mode="analytic",
                                   seed=seed + p),
             serve_ctx(sizes), sens=sens, batch_size=K, epoch_batches=E)

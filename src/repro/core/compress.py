@@ -511,7 +511,8 @@ class _BatchedAccuracyMixin:
         """The pure traced-cspec validator: ``(params, keep, w_bits,
         a_bits)`` with (K, L) int32 policy arrays -> (K,) accuracies,
         un-jitted so callers can inline it into a larger traced program
-        (the epoch-fused engine chains it inside its ``lax.scan`` body).
+        (the search's epoch program chains it inside its ``lax.scan``
+        body).
 
         The weights are an argument, never a closed-over constant: a
         jitted program that captured them would embed them in its HLO

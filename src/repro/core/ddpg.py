@@ -28,10 +28,10 @@ stats/reward moving average/PRNG key) manipulated by pure functions:
     dispatch.
 
 ``DDPGAgent`` remains as a thin compatibility shim over ``AgentState``:
-``act``/``act_batch`` keep the fast host-numpy rollout forward,
-``update(replay)`` keeps the original host-sampled scalar semantics, and
-``update_chunk(replay, n)`` dispatches the fused scan. Host-authoritative
-pieces (running norm, reward-MA between dispatches, numpy rollout RNG)
+``act`` is the host-numpy actor (the reference ``agent_act`` is tested
+against), ``update(replay)`` keeps the original host-sampled scalar
+semantics, and ``update_chunk(replay, n)`` dispatches the fused scan.
+Host-authoritative pieces (running norm, reward-MA between dispatches)
 are synced into the pytree right before each fused dispatch.
 """
 from __future__ import annotations
@@ -117,17 +117,6 @@ def _mlp(params, x, final_act=None):
 
 def actor_forward(params, state):
     return _mlp(params, state, jax.nn.sigmoid)   # actions in [0, 1]
-
-
-def _actor_forward_np(params, x: np.ndarray) -> np.ndarray:
-    """Host-side actor forward. The actor MLP is tiny, so during
-    batched rollouts a numpy matmul chain beats the per-call XLA
-    dispatch + device sync by an order of magnitude."""
-    for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
-        if i < len(params) - 1:
-            x = np.maximum(x, 0.0)
-    return 1.0 / (1.0 + np.exp(-x))
 
 
 def critic_forward(params, state, action):
@@ -427,9 +416,7 @@ def population_update_chunk_vmap(cfg: DDPGConfig, states: AgentState,
 #     moment buffers update in place.
 #
 # On MXU-class backends folding P into the GEMM batch axis is where the
-# wall-clock win comes from; on the 1-core CI box the vmapped GEMMs already
-# run at the machine's measured ~140 GF/s peak, so the gain there is the
-# removed overhead only (see benchmarks/search_setup.py update_floor rows).
+# wall-clock win is expected; it has not been measured on a chip.
 
 
 def _fused_adam_polyak(params, grads, st, target, lr, tau,
@@ -664,12 +651,11 @@ def tree_index(tree, i: int):
 class DDPGAgent:
     """Thin stateful facade over the functional core.
 
-    Keeps the original call sites — ``act`` / ``act_batch`` / ``update``
-    / ``sigma_at`` / ``observe_states`` — while all parameters live in
-    ``self.state`` (an ``AgentState``). The rollout path stays host-side
-    numpy (fast for the tiny MLPs); the update path either takes the
-    original one-host-sample-per-call route (``update``) or the fused
-    scan (``update_chunk``).
+    Keeps the original call sites — ``act`` / ``update`` / ``sigma_at``
+    / ``observe_states`` — while all parameters live in ``self.state``
+    (an ``AgentState``). ``act`` is the host-numpy actor; the update
+    path either takes the original one-host-sample-per-call route
+    (``update``) or the fused scan (``update_chunk``).
     """
 
     def __init__(self, cfg: DDPGConfig, seed: int = 0):
@@ -681,7 +667,7 @@ class DDPGAgent:
         self._ma_dirty = False       # True: state.reward_ma is newer
         self.np_rng = np.random.default_rng(seed)
         self._update = jax.jit(self._update_impl)
-        self._actor_host = None            # numpy actor copy for rollouts
+        self._actor_host = None            # numpy actor copy for ``act``
 
     # --- reward-MA facade: after a fused chunk the device value is
     # authoritative; pull it lazily so dispatching a chunk never blocks
@@ -719,7 +705,7 @@ class DDPGAgent:
     @actor.setter
     def actor(self, v):
         self.state = self.state._replace(actor=v)
-        self._actor_host = None     # rollouts must see the new weights
+        self._actor_host = None     # ``act`` must see the new weights
 
     @property
     def critic(self):
@@ -767,9 +753,15 @@ class DDPGAgent:
         if random:
             return self.np_rng.uniform(0, 1, self.cfg.action_dim) \
                 .astype(np.float32)
-        s = self.norm.normalize(state.astype(np.float32))
-        mu = _actor_forward_np(self._host_actor(),
-                               np.atleast_2d(s))[0].astype(np.float32)
+        # the actor forward in numpy: a host reference independent of
+        # the traced ``actor_forward`` (and of its kernel route)
+        x = self.norm.normalize(state.astype(np.float32))
+        actor = self._host_actor()
+        for i, layer in enumerate(actor):
+            x = x @ layer["w"] + layer["b"]
+            if i < len(actor) - 1:
+                x = np.maximum(x, 0.0)
+        mu = (1.0 / (1.0 + np.exp(-x))).astype(np.float32)
         if sigma > 0:
             # truncated normal on [0, 1] around mu (paper Eq. 7)
             for _ in range(16):
@@ -779,45 +771,6 @@ class DDPGAgent:
             a = np.clip(self.np_rng.normal(mu, sigma), 0, 1)
             return a.astype(np.float32)
         return mu.astype(np.float32)
-
-    def act_batch(self, states: np.ndarray, sigmas: np.ndarray,
-                  random_mask: np.ndarray) -> np.ndarray:
-        """Batched ``act``: one actor forward over K stacked states.
-
-        ``sigmas`` and ``random_mask`` are per-row (episodes in a batch
-        keep their own sigma-schedule position and warmup flag). Noise
-        is the same truncated normal on [0, 1], rejection-sampled
-        row-wise with the shared agent RNG.
-        """
-        states = np.atleast_2d(np.asarray(states, np.float32))
-        K, A = states.shape[0], self.cfg.action_dim
-        sigmas = np.broadcast_to(np.asarray(sigmas, np.float32), (K,))
-        random_mask = np.broadcast_to(np.asarray(random_mask, bool), (K,))
-        out = np.empty((K, A), np.float32)
-        if random_mask.any():
-            out[random_mask] = self.np_rng.uniform(
-                0, 1, (int(random_mask.sum()), A)).astype(np.float32)
-        det = ~random_mask
-        if not det.any():
-            return out
-        s = self.norm.normalize(states[det])
-        mu = _actor_forward_np(self._host_actor(), s).astype(np.float32)
-        sig = sigmas[det][:, None]
-        a = mu.copy()
-        pending = sigmas[det] > 0
-        for _ in range(16):
-            if not pending.any():
-                break
-            rows = np.where(pending)[0]
-            cand = self.np_rng.normal(mu[rows], sig[rows])
-            ok = np.all((cand >= 0) & (cand <= 1), axis=1)
-            a[rows[ok]] = cand[ok]
-            pending[rows[ok]] = False
-        if pending.any():
-            rows = np.where(pending)[0]
-            a[rows] = np.clip(self.np_rng.normal(mu[rows], sig[rows]), 0, 1)
-        out[det] = a.astype(np.float32)
-        return out
 
     def sigma_at(self, episode: int) -> float:
         e = max(0, episode - self.cfg.warmup_episodes)

@@ -54,8 +54,8 @@ def compute_reward(cfg: RewardConfig, acc: float, latency: float,
 def compute_reward_batch(cfg: RewardConfig, acc, latency, ref_latency,
                          xp=jnp):
     """``compute_reward`` over (K,) arrays. Traceable with the default
-    ``xp=jnp`` (the fused/epoch engines); the numpy engines pass
-    ``xp=np`` to keep their record tail off the device."""
+    ``xp=jnp`` (the search engine); a host reference passes ``xp=np``
+    to stay off the device."""
     ratio = latency / (cfg.target_ratio * ref_latency)
     if cfg.kind == "absolute":
         return acc + cfg.beta * xp.abs(ratio - 1.0)

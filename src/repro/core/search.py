@@ -4,107 +4,62 @@ prediction, hardware-oracle validation, and DDPG optimization.
 Three agents (paper §Proposed Agents) share this loop and differ only in
 ``methods``:  "p" (pruning), "q" (quantization), "pq" (joint).
 
-How the episode engines work
-----------------------------
-Three engines share the per-episode semantics (sigma decay schedule,
-warmup flags, shared-episode-reward transition scheme, hardware
-legality) and differ only in how much of an episode batch runs per
-host dispatch:
+The engine
+----------
+``CompressionSearch`` runs episodes in batches of K (``batch_size``)
+and whole batches in epochs of E (``epoch_batches``). An epoch
+(``run_epoch``) is ONE ``jit`` program: a ``lax.scan`` over its E
+batches whose body chains
 
-* ``CompressionSearch.run_episode`` — the scalar reference path: walk
-  the actionable units in order, build the agent state (which probes
-  the analytic latency oracle under the partial policy), act, map the
-  continuous action to a legal CMP, then validate the finished policy
-  (one jitted accuracy eval + one oracle call) and push the transitions
-  with the shared episode reward.
+* the rollout — a ``lax.scan`` over the L actionable units in which a
+  traceable oracle (``JaxBatchOracle``) builds the latency features,
+  ``agent_act_batch`` runs the actor with in-scan PRNG exploration,
+  ``map_actions_batch`` projects the actions onto legal CMPs, and the
+  (K, L) policy arrays ride in the carry (``make_rollout_fn``);
+* the validation of the K policies (``accuracy_policy_fn``), the reward,
+  the ``DeviceReplay`` ring write of the shared-episode-reward
+  transitions, and the batch's DDPG update scan (``make_epoch_fn``).
 
-* ``BatchedCompressionSearch`` — K episodes per rollout, still L host
-  steps: ``build_state_batch`` + one vectorized numpy oracle call per
-  layer step, ``DDPGAgent.act_batch`` (host numpy actor), a Python
-  ``map_actions`` loop over the K episodes, then one fused
-  cspec+accuracy jit call and a single bulk ring write.
+The carry is ``(AgentState, ring, rollout PRNG, best-policy argmax)``;
+the agent and ring buffers are donated so they update in place, and the
+metrics come back as (E, K) arrays in one host readback per epoch.
+Steady epochs share one update schedule and hence one compiled program.
 
-* ``FusedCompressionSearch`` — the whole K-episode rollout is ONE
-  ``jit(lax.scan)`` over the layer steps: a traceable ``JaxBatchOracle``
-  builds the latency features, ``agent_act_batch`` runs the actor (with
-  in-scan PRNG for warmup/sigma exploration), ``map_actions_batch``
-  projects actions to legal CMPs as array ops, and the (K, L) policy
-  arrays live in the scan carry. Validation and learning then reuse the
-  fused paths (``accuracy_policy_batch`` + ``update_chunk``).
+The per-batch tail (``run_episode_batch``) runs one batch as separate
+dispatches — rollout, validation, ring write, update chunk — and is
+``run()``'s path for a trailing batch of fewer than K episodes. The
+epoch program consumes the PRNG streams with the same split pattern, so
+the per-batch tail run batch by batch reproduces an epoch draw for draw:
+it is the reference ``tests/test_epoch.py`` compares the epoch against.
+``dispatch_log`` names each dispatch ("epoch", or "rollout"/"validate"/
+"push"/"update").
 
-* epoch mode (``FusedCompressionSearch(..., epoch_batches=E)`` /
-  ``run_epoch``) — E whole episode batches as ONE ``jit(lax.scan)``
-  over batches: the scan body chains the fused rollout, the traced-
-  cspec validation, the reward, the ``DeviceReplay`` ring write, and
-  the update chunk as pure carry transitions over ``(AgentState, ring,
-  rollout PRNG, best-policy argmax)``. Metrics come back as (E, K)
-  device arrays with exactly one host readback per epoch; agent/ring
-  buffers are donated to the epoch executable so they update in place.
-
-Cost per episode batch (K episodes over L actionable units,
-post-compile; u = fused update-chunk dispatches):
-
-  ========  ====================  ===========================
-  engine    host environment      jit dispatches
-            steps per batch       per batch
-  ========  ====================  ===========================
-  scalar    K * L                 2K + u   (accuracy + ring
-                                  write per episode)
-  batched   L                     2 + u    (fused validation
-                                  + one bulk ring write)
-  fused     0                     3 + u    (<= 4 total)
-  epoch     0                     1 / E    (one dispatch and
-                                  one readback per E batches)
-  ========  ====================  ===========================
-
-A "host environment step" is one oracle probe + state build + actor
-forward + action->CMP mapping round-trip on the host; the fused
-engine's three dispatches are rollout, validation, and the replay ring
-write (its ``dispatch_log`` records them so benchmarks can assert the
-count never regresses; epoch mode logs one ``"epoch"`` entry per E
-batches). The numpy engines stay as the parity references —
-``tests/test_fused.py`` property-tests the fused rollout against
-``BatchedCompressionSearch`` step for step, and ``tests/test_epoch.py``
-property-tests epoch mode against the per-batch fused engine (records,
-final ``AgentState``, ring contents).
-
-Where the learning happens (PR 2: the functional agent core)
------------------------------------------------------------
-Both engines store transitions in a device-resident ``DeviceReplay``
-(``core/replay.py``) and dispatch *all* of an episode batch's critic/
-actor/target updates as ONE jitted ``lax.scan`` —
-``DDPGAgent.update_chunk`` over the ``AgentState`` pytree
-(``core/ddpg.py``). Replay sampling, reward moving-average centering,
-state standardization, and the Adam/soft-target math all run inside the
-scan; the only host sync per episode batch is the loss array. The
-scalar engine fuses its ``updates_per_episode`` steps the same way, so
-the two paths differ only in rollout batching.
-
-``PopulationSearch`` stacks P member searches (p/q/pq agents, multiple
-seeds, or one member per hardware target) and replaces their P separate
-update dispatches with one ``jit(vmap(update_chunk))`` over the stacked
-``AgentState``/replay pytrees. Members with different native action
-dimensionalities share one population by padding ``action_dim`` to the
-maximum (``map_actions`` consumes a prefix of the action vector, so
-trailing entries are inert for single-method agents). With
-``fuse_rollouts=True`` and ``FusedCompressionSearch`` members that
-share a step list (same methods — e.g. one member per hardware target,
-whose rate parameters enter the traced oracle as a vmappable
-``HwParams`` pytree), the P rollout dispatches also collapse into one
-``jit(vmap(rollout))``.
-
-Semantic notes, both at batch granularity: critic/actor updates for the
-K episodes of a batch run after the whole batch (same total update
-count) rather than interleaved between episodes, and the state
+Learning
+--------
+Transitions live in a device-resident ``DeviceReplay``
+(``core/replay.py``); a batch's critic/actor/target updates run as one
+``lax.scan`` of ``update_step`` over the ``AgentState`` pytree
+(``core/ddpg.py``), with replay sampling, reward moving-average
+centering and state standardization inside the scan. Updates for the K
+episodes of a batch run after the whole batch, and the state
 normalizer's running stats advance once per batch, so episodes within a
-batch act on the stats from the previous batch boundary. Within an
-update chunk the normalizer snapshot is frozen and the reward moving
-average advances per step — exactly the scalar ``DDPGAgent.update``
-semantics, property-tested in ``tests/test_agent_core.py``.
+batch act on the stats from the previous batch boundary.
+
+Populations
+-----------
+``PopulationSearch`` stacks P member searches (p/q/pq agents, several
+seeds, or one member per hardware target). With ``fuse_rollouts=True``
+and members that share one trace (same specs, sensitivity, context,
+methods, model and reward; hardware rates enter as a vmappable
+``HwParams`` pytree) the P epochs run as ONE ``jit(vmap(epoch))``.
+Members with different native action dimensionalities share a
+population by padding ``action_dim`` to the maximum (``map_actions``
+consumes a prefix of the action vector, so trailing entries are inert
+for single-method agents). ``FleetSearch`` shards that population over
+a device mesh and checkpoints its carry.
 """
 from __future__ import annotations
 
-import copy
 import time
 from dataclasses import dataclass, field
 from typing import List, NamedTuple, Optional, Sequence
@@ -128,20 +83,16 @@ from repro.core.ddpg import (_SCAN_UNROLL as _UPDATE_SCAN_UNROLL,
                              population_update_chunk, tree_index,
                              tree_stack, update_step)
 from repro.core.latency import (V5E, HardwareTarget, LatencyContext,
-                                fifo_cached, get_jax_oracle, policy_latency,
-                                policy_latency_batch)
+                                fifo_cached, get_jax_oracle, policy_latency)
 from repro.core.policy import (Policy, PolicyBatch, action_columns,
-                               map_actions, map_actions_batch, n_actions,
+                               map_actions_batch, n_actions,
                                policies_from_batch, stack_policies)
 from repro.core.replay import (DeviceReplay, device_replay_push,
                                device_replay_sample)
-from repro.core.reward import RewardConfig, compute_reward, \
-    compute_reward_batch
+from repro.core.reward import RewardConfig, compute_reward_batch
 from repro.core.sensitivity import SensitivityResult, run_sensitivity
 from repro.core import spans
-from repro.core.spec import effective_bits
-from repro.core.state import (StateTables, build_state, build_state_batch,
-                              fused_state_block, state_dim)
+from repro.core.state import StateTables, fused_state_block, state_dim
 
 
 @dataclass(frozen=True)
@@ -204,319 +155,9 @@ def _actionable(spec, methods: str) -> bool:
     return spec.quantizable or (spec.prunable and spec.prune_dim > 0)
 
 
-class CompressionSearch:
-    """Owns: the compressible model, the sensitivity table, the latency
-    oracle context, the agent, and the episode loop."""
-
-    def __init__(self, cmodel, val_batch, search_cfg: SearchConfig,
-                 ctx: LatencyContext, hw: HardwareTarget = V5E,
-                 sens: Optional[SensitivityResult] = None,
-                 calib_batch=None, calib=None):
-        self.cmodel = cmodel
-        self.specs = cmodel.specs
-        self.cfg = search_cfg
-        self.hw = hw
-        self.ctx = ctx
-        self.val_batch = val_batch
-        # latency-oracle flavor: a CalibrationTable rescales every oracle
-        # form's terms in calibrated/measured mode; analytic ignores it
-        mode = search_cfg.oracle_mode
-        if mode not in ("analytic", "calibrated", "measured"):
-            raise ValueError(
-                f"SearchConfig.oracle_mode must be analytic|calibrated|"
-                f"measured, got {mode!r}")
-        if mode != "analytic" and calib is None:
-            from repro.core.measure import load_calibration
-            calib = load_calibration(search_cfg.calibration_path or None)
-        self.calib = calib if mode != "analytic" else None
-        native = n_actions(search_cfg.methods)
-        ddpg_cfg = search_cfg.ddpg or DDPGConfig(
-            state_dim=state_dim(native), action_dim=native)
-        # a provided action_dim larger than the method's native one pads
-        # the action space (population members must share shapes); a
-        # smaller one is corrected up to native
-        a_dim = max(native, ddpg_cfg.action_dim)
-        if (ddpg_cfg.state_dim, ddpg_cfg.action_dim) != (state_dim(a_dim),
-                                                         a_dim):
-            ddpg_cfg = DDPGConfig(**{**ddpg_cfg.__dict__,
-                                     "state_dim": state_dim(a_dim),
-                                     "action_dim": a_dim})
-        self.agent = DDPGAgent(ddpg_cfg, seed=search_cfg.seed)
-        self.replay = DeviceReplay(ddpg_cfg.buffer_size, ddpg_cfg.state_dim,
-                                   a_dim, seed=search_cfg.seed)
-        # fused + memoized (ONE jit execution for the whole layer×probe
-        # grid, shared across every engine built on the same model and
-        # calibration batch — population members included)
-        self.sens = sens if sens is not None else run_sensitivity(
-            cmodel, calib_batch if calib_batch is not None else val_batch)
-        self._jit_acc = jax.jit(
-            lambda p, cs: cmodel.accuracy(val_batch, cs, p))
-        self.ref_policy = Policy.reference(self.specs)
-        self.ref_lat = policy_latency(self.specs, self.ref_policy, hw, ctx,
-                                      search_cfg.window, calib=self.calib)
-        self.ref_acc = float(self._jit_acc(
-            cmodel.params, cmodel.build_cspec(self.ref_policy)))
-        self.steps = [i for i, s in enumerate(self.specs)
-                      if _actionable(s, search_cfg.methods)]
-        self._pending_updates = 0
-        self._defer_updates = False     # PopulationSearch batches flushes
-
-    # ------------------------------------------------------------------
-    def _flush_updates(self):
-        """Dispatch the accumulated update budget as one fused chunk."""
-        n = self._pending_updates
-        self._pending_updates = 0
-        if n > 0 and len(self.replay) >= self.agent.cfg.batch_size:
-            self.agent.update_chunk(self.replay, n)
-
-    def _queue_updates(self, n: int):
-        self._pending_updates += n
-        if not self._defer_updates:
-            self._flush_updates()
-
-    # ------------------------------------------------------------------
-    def run_episode(self, episode: int) -> EpisodeRecord:
-        cfg = self.cfg
-        warmup = episode < self.agent.cfg.warmup_episodes
-        sigma = self.agent.sigma_at(episode)
-        partial = copy.deepcopy(self.ref_policy)
-        a_dim = self.agent.cfg.action_dim
-        prev_a = np.zeros(a_dim, np.float32)
-        states, actions = [], []
-        for t in self.steps:
-            s_vec = build_state(self.specs, t, partial, self.sens, prev_a,
-                                self.hw, self.ctx, self.ref_lat, cfg.window)
-            a = self.agent.act(s_vec, sigma, random=warmup)
-            cmp = map_actions(self.specs[t], a, cfg.methods)
-            # single-method agents preserve the other method's parameters
-            # from the reference policy (supports the sequential scheme:
-            # a frozen stage-1 policy as the starting point, paper App. A)
-            prev = partial.cmps[t]
-            if cfg.methods == "q":
-                cmp.keep = prev.keep
-            elif cfg.methods == "p":
-                cmp.mode, cmp.w_bits, cmp.a_bits = (prev.mode, prev.w_bits,
-                                                    prev.a_bits)
-            partial.cmps[t] = cmp
-            states.append(s_vec)
-            actions.append(a)
-            prev_a = a
-        policy = partial
-
-        cspec = self.cmodel.build_cspec(policy)
-        acc = float(self._jit_acc(self.cmodel.params, cspec))
-        lat = policy_latency(self.specs, policy, self.hw, self.ctx,
-                             cfg.window, calib=self.calib)
-        reward = compute_reward(cfg.reward, acc, lat.total_s,
-                                self.ref_lat.total_s)
-        # push transitions — one shared episode reward (paper §Schema),
-        # one bulk ring write for the whole chain
-        T = len(states)
-        st_arr = np.stack(states)
-        self.agent.observe_states(st_arr)
-        nxt = np.concatenate([st_arr[1:], st_arr[-1:]])
-        done = np.zeros(T, np.float32)
-        done[-1] = 1.0
-        self.replay.push_batch(st_arr, np.stack(actions),
-                               np.full(T, reward, np.float32), nxt, done)
-        if not warmup:
-            self._queue_updates(self.agent.cfg.updates_per_episode)
-
-        ratio = lat.total_s / (cfg.reward.target_ratio *
-                               self.ref_lat.total_s)
-        return EpisodeRecord(
-            episode=episode, reward=reward, accuracy=acc,
-            latency_s=lat.total_s, latency_ratio=ratio,
-            macs_frac=policy.macs_fraction(self.specs),
-            bops=policy.bops(self.specs) if cfg.track_bops else 0.0,
-            sigma=sigma, policy=policy)
-
-    # chunking hooks: the scalar engine advances one episode at a time;
-    # BatchedCompressionSearch overrides these to roll K per call
-    def _chunk_size(self) -> int:
-        return 1
-
-    def _run_chunk(self, first_episode: int,
-                   k: int) -> List[EpisodeRecord]:
-        return [self.run_episode(first_episode)]
-
-    def run(self, episodes: Optional[int] = None,
-            verbose: bool = False) -> SearchResult:
-        n = episodes or self.cfg.episodes
-        history: List[EpisodeRecord] = []
-        best = None
-        e = 0
-        while e < n:
-            k = min(self._chunk_size(), n - e)
-            for rec in self._run_chunk(e, k):
-                history.append(rec)
-                if best is None or rec.reward > best.reward:
-                    best = rec
-                if verbose and (rec.episode % 10 == 0
-                                or rec.episode == n - 1):
-                    print(f"  ep {rec.episode:4d} reward={rec.reward:+.4f} "
-                          f"acc={rec.accuracy:.3f} "
-                          f"lat_ratio={rec.latency_ratio:.3f} "
-                          f"sigma={rec.sigma:.3f}")
-            e += k
-        result = SearchResult(history=history, best=best,
-                              ref_latency_s=self.ref_lat.total_s,
-                              ref_accuracy=self.ref_acc)
-        if self.cfg.oracle_mode == "measured":
-            result.measured = self._measure_top_k(history)
-        return result
-
-    def _measure_top_k(self, history: List[EpisodeRecord]) -> List[dict]:
-        """Wall-clock the deployed forward of the top-K candidates (the
-        paper's measure-on-target step, applied only to finalists). The
-        measurement memo is FIFO-cached by container signature, so
-        candidates sharing a deployment are timed once."""
-        from repro.core import measure
-        k = max(1, self.cfg.measure_top_k)
-        top = sorted(history, key=lambda r: r.reward, reverse=True)[:k]
-        ref_s = measure.measure_policy(self.cmodel, self.ref_policy,
-                                       self.val_batch)
-        rows = []
-        for r in top:
-            t = measure.measure_policy(self.cmodel, r.policy,
-                                       self.val_batch)
-            rows.append({
-                "episode": r.episode, "reward": r.reward,
-                "predicted_s": r.latency_s,
-                "predicted_ratio": r.latency_s / self.ref_lat.total_s,
-                "measured_s": t, "measured_ref_s": ref_s,
-                "measured_ratio": t / ref_s if ref_s > 0 else float("inf"),
-            })
-        return rows
-
-
-class BatchedCompressionSearch(CompressionSearch):
-    """K episodes per rollout; see the module docstring for the engine.
-
-    Per-episode semantics (sigma schedule, warmup, shared episode
-    reward, legality constraints) match ``CompressionSearch``; only the
-    dispatch is amortized, so episode throughput scales with K.
-    """
-
-    def __init__(self, cmodel, val_batch, search_cfg: SearchConfig,
-                 ctx: LatencyContext, hw: HardwareTarget = V5E,
-                 sens: Optional[SensitivityResult] = None,
-                 calib_batch=None, calib=None, batch_size: int = 8):
-        super().__init__(cmodel, val_batch, search_cfg, ctx, hw=hw,
-                         sens=sens, calib_batch=calib_batch, calib=calib)
-        self.batch_size = max(1, batch_size)
-
-    # ------------------------------------------------------------------
-    def _batch_schedule(self, first_episode: int, k: int):
-        """(warmup mask, sigma) per episode row — THE one place the
-        batch's exploration schedule is derived (rollout and
-        finish/record paths must agree on it)."""
-        eps = range(first_episode, first_episode + k)
-        warmup = np.asarray(
-            [e < self.agent.cfg.warmup_episodes for e in eps])
-        sigmas = np.asarray([self.agent.sigma_at(e) for e in eps],
-                            np.float32)
-        return warmup, sigmas
-
-    def run_episode_batch(self, first_episode: int,
-                          k: int) -> List[EpisodeRecord]:
-        cfg = self.cfg
-        eps = list(range(first_episode, first_episode + k))
-        warmup, sigmas = self._batch_schedule(first_episode, k)
-        partials = [copy.deepcopy(self.ref_policy) for _ in eps]
-        # (K, L) policy arrays, updated in place as units are decided
-        pb = stack_policies(self.specs, partials)
-        a_dim = self.agent.cfg.action_dim
-        prev_a = np.zeros((k, a_dim), np.float32)
-        step_states, step_actions = [], []
-        for t in self.steps:
-            cur = policy_latency_batch(self.specs, pb, self.hw, self.ctx,
-                                       cfg.window, calib=self.calib)
-            S = build_state_batch(self.specs, t, cur, self.sens, prev_a,
-                                  self.ref_lat)
-            A = self.agent.act_batch(S, sigmas, warmup)
-            for j in range(k):
-                cmp = map_actions(self.specs[t], A[j], cfg.methods)
-                prev = partials[j].cmps[t]
-                if cfg.methods == "q":
-                    cmp.keep = prev.keep
-                elif cfg.methods == "p":
-                    cmp.mode, cmp.w_bits, cmp.a_bits = (
-                        prev.mode, prev.w_bits, prev.a_bits)
-                partials[j].cmps[t] = cmp
-                pb.keep[j, t] = cmp.keep
-                pb.w_bits[j, t], pb.a_bits[j, t] = effective_bits(cmp)
-            step_states.append(S)
-            step_actions.append(A)
-            prev_a = A
-
-        # --- batched validation: one fused cspec+accuracy jit call and
-        # one vectorized oracle call for the whole batch
-        accs = np.asarray(
-            self.cmodel.accuracy_policy_batch(self.val_batch, pb))
-        lats = policy_latency_batch(self.specs, pb, self.hw, self.ctx,
-                                    cfg.window, calib=self.calib).total_s
-        rewards = compute_reward_batch(cfg.reward, accs, lats,
-                                       self.ref_lat.total_s, xp=np)
-        return self._push_and_record(
-            eps, warmup, sigmas, partials, np.stack(step_states),
-            np.stack(step_actions), accs, lats, rewards)
-
-    def _log_dispatch(self, label: str):
-        """Hook for engines that account their jit dispatches (the
-        fused engine's ``dispatch_log``); no-op here."""
-
-    def _push_and_record(self, eps, warmup, sigmas, pols, states,
-                         actions, accs, lats,
-                         rewards) -> List[EpisodeRecord]:
-        """The engines' shared batch tail — THE definition of the
-        shared-episode-reward transition scheme: observe the (T, K, ·)
-        states, push per-episode chains as one bulk ring write
-        (reward repeated along each chain, done on the last step),
-        queue the live episodes' update budget, and build the records.
-        """
-        cfg = self.cfg
-        T, k = len(self.steps), len(eps)
-        self.agent.observe_states(states.reshape(T * k, -1))
-        nxt = np.concatenate([states[1:], states[-1:]])
-        done = np.zeros((T, k), np.float32)
-        done[-1] = 1.0
-        order = lambda x: x.swapaxes(0, 1).reshape(T * k, *x.shape[2:])
-        self.replay.push_batch(
-            order(states), order(actions),
-            np.repeat(rewards, T).astype(np.float32),
-            order(nxt), order(done))
-        self._log_dispatch("push")
-        n_live = int((~warmup).sum())
-        self._queue_updates(self.agent.cfg.updates_per_episode * n_live)
-
-        # record tail: ONE bulk conversion per batch (a single
-        # np.asarray readback each), not per-episode scalar float()s
-        acc_l, lat_l, rew_l, sig_l = (
-            np.asarray(x, np.float64).tolist()
-            for x in (accs, lats, rewards, sigmas))
-        denom = cfg.reward.target_ratio * self.ref_lat.total_s
-        records = []
-        for j, e in enumerate(eps):
-            records.append(EpisodeRecord(
-                episode=e, reward=rew_l[j],
-                accuracy=acc_l[j], latency_s=lat_l[j],
-                latency_ratio=lat_l[j] / denom,
-                macs_frac=pols[j].macs_fraction(self.specs),
-                bops=pols[j].bops(self.specs) if cfg.track_bops else 0.0,
-                sigma=sig_l[j], policy=pols[j]))
-        return records
-
-    def _chunk_size(self) -> int:
-        return self.batch_size
-
-    def _run_chunk(self, first_episode: int,
-                   k: int) -> List[EpisodeRecord]:
-        return self.run_episode_batch(first_episode, k)
-
 
 # ===========================================================================
-# Fused engine: the rollout environment as one jit(lax.scan)
+# The rollout environment as one jit(lax.scan)
 # ===========================================================================
 
 class MethodCols(NamedTuple):
@@ -540,8 +181,8 @@ def method_cols(methods: str) -> MethodCols:
 
 
 def make_rollout_fn(cfg: DDPGConfig, oracle, legal, static_tab, spec_steps):
-    """Build the pure rollout function the fused engine jits (and the
-    population engine ``jit(vmap)``s).
+    """Build the pure rollout function the engine's epoch program scans
+    (and its per-batch tail jits; a population ``jit(vmap)``s it).
 
     Closure constants: the agent config, the traceable oracle (specs/
     context tables; hardware rates stay in the ``hwp`` argument), the
@@ -584,7 +225,8 @@ def make_rollout_fn(cfg: DDPGConfig, oracle, legal, static_tab, spec_steps):
                 prunable=prunable[t], quantizable=quantizable[t],
                 mix_ok=mix_ok[t], ip=cols.ip, iw=cols.iw, ia=cols.ia)
             # single-method agents preserve the other method's reference
-            # parameters (same rule as the host engines)
+            # parameters (a frozen stage-1 policy can be the starting
+            # point of the sequential scheme, paper App. A)
             keep = keep.at[:, t].set(
                 jnp.where(cols.do_p, new_keep, keep[:, t]))
             wb = wb.at[:, t].set(jnp.where(cols.do_q, new_wb, wb[:, t]))
@@ -601,7 +243,7 @@ def make_rollout_fn(cfg: DDPGConfig, oracle, legal, static_tab, spec_steps):
 
 
 # ===========================================================================
-# Epoch-fused engine: E episode batches as one jit(lax.scan)
+# The epoch program: E episode batches as one jit(lax.scan)
 # ===========================================================================
 
 def _schedule_segments(schedule: tuple) -> List[tuple]:
@@ -630,7 +272,7 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
     rollout PRNG key, best)``.
 
     ``schedule`` is the STATIC per-batch fused-update step count (see
-    ``FusedCompressionSearch._update_schedule``): the update-sampling
+    ``CompressionSearch._update_schedule``): the update-sampling
     keys every batch will consume are derived at trace time with the
     exact ``chunk_sample_keys`` splits the per-batch path performs —
     ``jax.random.split`` is not prefix-stable across lengths, so a
@@ -682,7 +324,7 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
                         st, keep0, wb0, ab0, sig, warm, hwp, shares,
                         ref_total, cols, keys)
                     # the normalizer advances at the batch boundary,
-                    # exactly as the host engines' observe_states does
+                    # exactly as the per-batch tail's observe_states does
                     st = observe_states_pure(st, states.reshape(T * K, -1))
                 with jax.named_scope("validation"):
                     accs = acc_fn(params, keep.astype(jnp.int32),
@@ -749,46 +391,76 @@ def make_epoch_fn(cfg: DDPGConfig, reward_cfg: RewardConfig, rollout_fn,
 _EPOCH_CACHE_MAX = 16
 
 
-class FusedCompressionSearch(BatchedCompressionSearch):
-    """K episodes per rollout, the rollout itself ONE jit dispatch.
-
-    Same per-episode semantics as the numpy engines; the environment
-    (oracle features, actor, action->CMP projection, policy carry) runs
-    as a ``lax.scan`` over the layer steps, so an episode batch costs
-    rollout + validation + ring write + update chunk — at most 4 jit
-    executions — instead of ~2L host dispatches. ``dispatch_log``
-    records each fused-path dispatch ("rollout"/"validate"/"push"/
-    "update"); the weekly benchmark cross-checks it against measured
-    invocations of the compiled entry points
-    (``benchmarks.search_setup.fused_dispatch_probe``). In a fused
-    population, dispatches shared across members (rollout, update)
-    appear in every member's log.
+class CompressionSearch:
+    """The search engine: owns the compressible model, the sensitivity
+    table, the latency oracle, the agent and its replay ring, and runs
+    the episode loop as epoch programs (see the module docstring).
 
     Exploration randomness comes from a dedicated jax PRNG stream
     (``seed``-derived, separate from the agent's update-sampling key);
-    ``_last_batch_key`` exposes the per-batch key so parity tests can
-    replay the exact draws through the numpy reference engine.
-
-    With ``epoch_batches=E > 0`` the engine runs in epoch mode:
-    ``run()`` dispatches E batches at a time through ``run_epoch`` —
-    one jit execution (agent/ring buffers donated, so they update in
-    place) and one host readback per epoch, instead of <= 4 dispatches
-    and per-batch syncs. The epoch scan carries the same PRNG streams
-    and consumes them with the same split pattern as the per-batch
-    path, so a same-seed per-batch engine reproduces an epoch run
-    draw for draw (``tests/test_epoch.py``).
+    ``_last_batch_key`` exposes the per-batch tail's key so parity tests
+    can replay its exact draws through a host reference.
     """
 
     def __init__(self, cmodel, val_batch, search_cfg: SearchConfig,
                  ctx: LatencyContext, hw: HardwareTarget = V5E,
                  sens: Optional[SensitivityResult] = None,
                  calib_batch=None, calib=None, batch_size: int = 8,
-                 epoch_batches: int = 0):
-        super().__init__(cmodel, val_batch, search_cfg, ctx, hw=hw,
-                         sens=sens, calib_batch=calib_batch, calib=calib,
-                         batch_size=batch_size)
-        # calibration factors enter the traced oracle as constants —
-        # calibrated mode keeps the rollout at its 1-dispatch bound
+                 epoch_batches: int = 1):
+        if epoch_batches < 1:
+            raise ValueError(
+                f"epoch_batches must be >= 1 (whole batches run as epoch "
+                f"programs), got {epoch_batches}")
+        self.cmodel = cmodel
+        self.specs = cmodel.specs
+        self.cfg = search_cfg
+        self.hw = hw
+        self.ctx = ctx
+        self.val_batch = val_batch
+        # latency-oracle flavor: a CalibrationTable rescales every oracle
+        # form's terms in calibrated/measured mode; analytic ignores it
+        mode = search_cfg.oracle_mode
+        if mode not in ("analytic", "calibrated", "measured"):
+            raise ValueError(
+                f"SearchConfig.oracle_mode must be analytic|calibrated|"
+                f"measured, got {mode!r}")
+        if mode != "analytic" and calib is None:
+            from repro.core.measure import load_calibration
+            calib = load_calibration(search_cfg.calibration_path or None)
+        self.calib = calib if mode != "analytic" else None
+        native = n_actions(search_cfg.methods)
+        ddpg_cfg = search_cfg.ddpg or DDPGConfig(
+            state_dim=state_dim(native), action_dim=native)
+        # a provided action_dim larger than the method's native one pads
+        # the action space (population members must share shapes); a
+        # smaller one is corrected up to native
+        a_dim = max(native, ddpg_cfg.action_dim)
+        if (ddpg_cfg.state_dim, ddpg_cfg.action_dim) != (state_dim(a_dim),
+                                                         a_dim):
+            ddpg_cfg = DDPGConfig(**{**ddpg_cfg.__dict__,
+                                     "state_dim": state_dim(a_dim),
+                                     "action_dim": a_dim})
+        self.agent = DDPGAgent(ddpg_cfg, seed=search_cfg.seed)
+        self.replay = DeviceReplay(ddpg_cfg.buffer_size, ddpg_cfg.state_dim,
+                                   a_dim, seed=search_cfg.seed)
+        # fused + memoized (ONE jit execution for the whole layer×probe
+        # grid, shared across every engine built on the same model and
+        # calibration batch — population members included)
+        self.sens = sens if sens is not None else run_sensitivity(
+            cmodel, calib_batch if calib_batch is not None else val_batch)
+        self._jit_acc = jax.jit(
+            lambda p, cs: cmodel.accuracy(val_batch, cs, p))
+        self.ref_policy = Policy.reference(self.specs)
+        self.ref_lat = policy_latency(self.specs, self.ref_policy, hw, ctx,
+                                      search_cfg.window, calib=self.calib)
+        self.ref_acc = float(self._jit_acc(
+            cmodel.params, cmodel.build_cspec(self.ref_policy)))
+        self.steps = [i for i, s in enumerate(self.specs)
+                      if _actionable(s, search_cfg.methods)]
+        self._pending_updates = 0
+        self._defer_updates = False     # PopulationSearch batches flushes
+        self.batch_size = max(1, batch_size)
+        # calibration factors enter the traced oracle as constants
         self.oracle = get_jax_oracle(self.specs, hw, ctx, search_cfg.window,
                                      calib=self.calib)
         self.tables = StateTables(self.specs, self.steps, self.sens,
@@ -805,13 +477,37 @@ class FusedCompressionSearch(BatchedCompressionSearch):
         self._rollout_key = jax.random.PRNGKey(search_cfg.seed + 0x5EED)
         self._last_batch_key = None
         self.dispatch_log: List[str] = []
-        # epoch mode: run() rolls E batches per run_epoch dispatch
-        self.epoch_batches = max(0, epoch_batches)
+        self.epoch_batches = epoch_batches
         self._epoch_cache: dict = {}
         self.last_epoch_best: Optional[tuple] = None
         spans.watch_gc()
 
     # ------------------------------------------------------------------
+    def _flush_updates(self):
+        """Dispatch the accumulated update budget as one fused chunk."""
+        n = self._pending_updates
+        self._pending_updates = 0
+        if n > 0 and len(self.replay) >= self.agent.cfg.batch_size:
+            self.dispatch_log.append("update")
+            self.agent.update_chunk(self.replay, n)
+
+    def _queue_updates(self, n: int):
+        self._pending_updates += n
+        if not self._defer_updates:
+            self._flush_updates()
+
+    def _batch_schedule(self, first_episode: int, k: int):
+        """(warmup mask, sigma) per episode row — THE one place the
+        batch's exploration schedule is derived (rollout and
+        finish/record paths must agree on it)."""
+        eps = range(first_episode, first_episode + k)
+        warmup = np.asarray(
+            [e < self.agent.cfg.warmup_episodes for e in eps])
+        sigmas = np.asarray([self.agent.sigma_at(e) for e in eps],
+                            np.float32)
+        return warmup, sigmas
+
+    # ---------------------------------------------------- per-batch tail
     def _rollout_args(self, first_episode: int, k: int) -> tuple:
         """Per-batch argument tuple for ``_rollout_fn`` (every element
         stackable across population members); advances the rollout PRNG
@@ -826,6 +522,15 @@ class FusedCompressionSearch(BatchedCompressionSearch):
                 jnp.asarray(self.tables.shares),
                 jnp.asarray(self.tables.ref_total, jnp.float32),
                 self._cols, keys)
+
+    def run_episode_batch(self, first_episode: int,
+                          k: int) -> List[EpisodeRecord]:
+        """One batch of ``k`` episodes as separate dispatches: rollout,
+        validation, ring write, update chunk."""
+        args = self._rollout_args(first_episode, k)
+        out = self._rollout(*args)
+        self.dispatch_log.append("rollout")
+        return self._finish_batch(first_episode, k, out)
 
     def _finish_batch(self, first_episode: int, k: int,
                       out: tuple) -> List[EpisodeRecord]:
@@ -850,23 +555,47 @@ class FusedCompressionSearch(BatchedCompressionSearch):
             np.asarray(dev_states), np.asarray(dev_actions), accs, lats,
             rewards)
 
-    def _log_dispatch(self, label: str):
-        self.dispatch_log.append(label)
+    def _push_and_record(self, eps, warmup, sigmas, pols, states,
+                         actions, accs, lats,
+                         rewards) -> List[EpisodeRecord]:
+        """The per-batch tail's shared-episode-reward transition scheme
+        (the epoch scan writes the same ring rows): observe the
+        (T, K, ·) states, push per-episode chains as one bulk ring write
+        (reward repeated along each chain, done on the last step), queue
+        the live episodes' update budget, and build the records."""
+        cfg = self.cfg
+        T, k = len(self.steps), len(eps)
+        self.agent.observe_states(states.reshape(T * k, -1))
+        nxt = np.concatenate([states[1:], states[-1:]])
+        done = np.zeros((T, k), np.float32)
+        done[-1] = 1.0
+        order = lambda x: x.swapaxes(0, 1).reshape(T * k, *x.shape[2:])
+        self.replay.push_batch(
+            order(states), order(actions),
+            np.repeat(rewards, T).astype(np.float32),
+            order(nxt), order(done))
+        self.dispatch_log.append("push")
+        n_live = int((~warmup).sum())
+        self._queue_updates(self.agent.cfg.updates_per_episode * n_live)
 
-    def _flush_updates(self):
-        if self._pending_updates > 0 and \
-                len(self.replay) >= self.agent.cfg.batch_size:
-            self.dispatch_log.append("update")
-        super()._flush_updates()
+        # record tail: ONE bulk conversion per batch (a single
+        # np.asarray readback each), not per-episode scalar float()s
+        acc_l, lat_l, rew_l, sig_l = (
+            np.asarray(x, np.float64).tolist()
+            for x in (accs, lats, rewards, sigmas))
+        denom = cfg.reward.target_ratio * self.ref_lat.total_s
+        records = []
+        for j, e in enumerate(eps):
+            records.append(EpisodeRecord(
+                episode=e, reward=rew_l[j],
+                accuracy=acc_l[j], latency_s=lat_l[j],
+                latency_ratio=lat_l[j] / denom,
+                macs_frac=pols[j].macs_fraction(self.specs),
+                bops=pols[j].bops(self.specs) if cfg.track_bops else 0.0,
+                sigma=sig_l[j], policy=pols[j]))
+        return records
 
-    def run_episode_batch(self, first_episode: int,
-                          k: int) -> List[EpisodeRecord]:
-        args = self._rollout_args(first_episode, k)
-        out = self._rollout(*args)
-        self.dispatch_log.append("rollout")
-        return self._finish_batch(first_episode, k, out)
-
-    # ------------------------------------------------------- epoch mode
+    # ------------------------------------------------------------ epochs
     def _update_schedule(self, first_episode: int,
                          n_batches: int) -> tuple:
         """Per-batch fused-update step counts for an epoch, as a STATIC
@@ -909,7 +638,7 @@ class FusedCompressionSearch(BatchedCompressionSearch):
 
     def _make_epoch_fn(self, schedule: tuple):
         """The pure epoch function for this engine and schedule (the
-        population engine vmaps the same construction)."""
+        population vmaps the same construction)."""
         return make_epoch_fn(
             self.agent.cfg, self.cfg.reward, self._rollout_fn,
             self.cmodel.accuracy_policy_fn(self.val_batch),
@@ -1009,55 +738,104 @@ class FusedCompressionSearch(BatchedCompressionSearch):
                     sigma=float(sigmas[j]), policy=pols[j]))
         return records
 
+    # ------------------------------------------------------------ the loop
     def _chunk_size(self) -> int:
-        if self.epoch_batches > 0:
-            return self.batch_size * self.epoch_batches
-        return self.batch_size
+        return self.batch_size * self.epoch_batches
 
     def _run_chunk(self, first_episode: int,
                    k: int) -> List[EpisodeRecord]:
-        if self.epoch_batches > 0:
-            nb, rem = divmod(k, self.batch_size)
-            recs = self.run_epoch(first_episode, nb) if nb else []
-            if rem:       # trailing partial batch: the per-batch path
-                recs += self.run_episode_batch(
-                    first_episode + nb * self.batch_size, rem)
-            return recs
-        return self.run_episode_batch(first_episode, k)
+        nb, rem = divmod(k, self.batch_size)
+        recs = self.run_epoch(first_episode, nb)
+        if rem:           # trailing partial batch: the per-batch tail
+            recs += self.run_episode_batch(
+                first_episode + nb * self.batch_size, rem)
+        return recs
+
+    def run(self, episodes: Optional[int] = None,
+            verbose: bool = False) -> SearchResult:
+        n = episodes or self.cfg.episodes
+        history: List[EpisodeRecord] = []
+        best = None
+        e = 0
+        while e < n:
+            k = min(self._chunk_size(), n - e)
+            for rec in self._run_chunk(e, k):
+                history.append(rec)
+                if best is None or rec.reward > best.reward:
+                    best = rec
+                if verbose and (rec.episode % 10 == 0
+                                or rec.episode == n - 1):
+                    print(f"  ep {rec.episode:4d} reward={rec.reward:+.4f} "
+                          f"acc={rec.accuracy:.3f} "
+                          f"lat_ratio={rec.latency_ratio:.3f} "
+                          f"sigma={rec.sigma:.3f}")
+            e += k
+        result = SearchResult(history=history, best=best,
+                              ref_latency_s=self.ref_lat.total_s,
+                              ref_accuracy=self.ref_acc)
+        if self.cfg.oracle_mode == "measured":
+            result.measured = self._measure_top_k(history)
+        return result
+
+    def _measure_top_k(self, history: List[EpisodeRecord]) -> List[dict]:
+        """Wall-clock the deployed forward of the top-K candidates (the
+        paper's measure-on-target step, applied only to finalists). The
+        measurement memo is FIFO-cached by container signature, so
+        candidates sharing a deployment are timed once."""
+        from repro.core import measure
+        k = max(1, self.cfg.measure_top_k)
+        top = sorted(history, key=lambda r: r.reward, reverse=True)[:k]
+        ref_s = measure.measure_policy(self.cmodel, self.ref_policy,
+                                       self.val_batch)
+        rows = []
+        for r in top:
+            t = measure.measure_policy(self.cmodel, r.policy,
+                                       self.val_batch)
+            rows.append({
+                "episode": r.episode, "reward": r.reward,
+                "predicted_s": r.latency_s,
+                "predicted_ratio": r.latency_s / self.ref_lat.total_s,
+                "measured_s": t, "measured_ref_s": ref_s,
+                "measured_ratio": t / ref_s if ref_s > 0 else float("inf"),
+            })
+        return rows
+
+
+# The benchmark's driver imports the engine by the name it had when the
+# epoch program was one of several engine classes.
+FusedCompressionSearch = CompressionSearch
 
 
 class PopulationSearch:
-    """P member searches whose agents share every update dispatch.
+    """P member searches run in lockstep.
 
     This is the paper's actual workload shape: the p/q/pq agents (and,
     for hardware-specific policies, one member per target) search
-    concurrently. Members roll out independently (each already batched
-    over K episodes), but their per-chunk update budgets are dispatched
-    as ONE ``jit(vmap(update_chunk))`` over the stacked ``AgentState``
-    and ``DeviceReplay`` pytrees — P× fewer dispatches on the dominant
-    cost of the loop.
+    concurrently. With ``fuse_rollouts=True`` and members that share one
+    epoch trace (``_epochs_fusable``: same specs/sensitivity/context/
+    methods, hence the same step list, and the same model, validation
+    batch and reward — the multi-hardware-target scenario, or multiple
+    seeds), every epoch of all P members is ONE ``jit(vmap(epoch))``
+    over the stacked agent states, rings, PRNG keys and per-target
+    ``HwParams``/latency-share arguments. Otherwise each member runs its
+    own epoch programs.
+
+    A trailing partial batch runs through the members' per-batch tails:
+    their rollouts share one ``jit(vmap(rollout))`` when
+    ``_rollouts_fusable``, and their update budgets are dispatched as
+    one population update chunk when they agree (per-member flushes
+    otherwise).
 
     Requirements: members must share one ``DDPGConfig`` (pad
     ``action_dim`` to the population maximum for mixed-method
-    populations; see the module docstring) and one chunk size. Members
-    whose pending budgets diverge (e.g. different warmup positions)
-    fall back to per-member fused flushes for that chunk.
+    populations; see the module docstring) and one chunk size.
 
     Construction cost: members built on a common model + calibration
     batch share ONE sensitivity analysis — ``run_sensitivity`` is fused
     (one jit execution for the whole layer×probe grid) and memoized per
     (cmodel, batch, params) identity, so the population constructor
-    pays the analysis once, not P times (and rollout fusion requires
-    the shared table anyway — see ``_rollouts_fusable``).
-
-    With ``fuse_rollouts=True``, members that are all
-    ``FusedCompressionSearch`` over the same specs/sensitivity/context
-    with the same methods (hence the same step list — the multi-
-    hardware-target scenario, or multiple seeds) additionally share the
-    rollout dispatch: one ``jit(vmap(rollout))`` over the stacked agent
-    states, policy carries, and per-target ``HwParams``/latency-share
-    arguments. Incompatible members silently keep their own (still
-    fused) per-member rollout dispatch.
+    pays the analysis once, not P times (and shared traces require the
+    shared table anyway — see ``_rollouts_fusable``).
     """
 
     def __init__(self, members: Sequence[CompressionSearch],
@@ -1107,15 +885,14 @@ class PopulationSearch:
         if self._fusable is None:
             ms = self.members
             m0 = ms[0]
-            self._fusable = all(isinstance(m, FusedCompressionSearch)
-                                for m in ms) and \
-                all(m.specs is m0.specs and m.sens is m0.sens
-                    and m.ctx == m0.ctx
-                    and m.cfg.window == m0.cfg.window
-                    and m.cfg.methods == m0.cfg.methods
-                    and m.hw.mxu_align == m0.hw.mxu_align
-                    and m.calib is m0.calib
-                    for m in ms[1:])
+            self._fusable = all(
+                m.specs is m0.specs and m.sens is m0.sens
+                and m.ctx == m0.ctx
+                and m.cfg.window == m0.cfg.window
+                and m.cfg.methods == m0.cfg.methods
+                and m.hw.mxu_align == m0.hw.mxu_align
+                and m.calib is m0.calib
+                for m in ms[1:])
         return self._fusable
 
     def _run_fused_chunk(self, first_episode: int,
@@ -1133,18 +910,17 @@ class PopulationSearch:
         return [m._finish_batch(first_episode, k, tree_index(outs, i))
                 for i, m in enumerate(self.members)]
 
-    # ------------------------------------------------------- epoch mode
+    # ------------------------------------------------------------ epochs
     def _epochs_fusable(self) -> bool:
         """A shared epoch dispatch bakes the validator and the reward
         into one trace on top of the rollout requirements: members must
         share the compressible model, the validation batch, and the
         reward config (the per-target reference-latency scale stays an
-        argument) and all run in epoch mode."""
+        argument)."""
         if self._epoch_fusable is None:
             ms = self.members
             m0 = ms[0]
             self._epoch_fusable = self._rollouts_fusable() and \
-                all(getattr(m, "epoch_batches", 0) > 0 for m in ms) and \
                 all(m.cmodel is m0.cmodel
                     and m.val_batch is m0.val_batch
                     and m.cfg.reward == m0.cfg.reward for m in ms[1:])
@@ -1206,9 +982,8 @@ class PopulationSearch:
                          k: int) -> List[List[EpisodeRecord]]:
         K = self.members[0].batch_size
         nb, rem = divmod(k, K)
-        chunks = self.run_epoch(first_episode, nb) if nb \
-            else [[] for _ in self.members]
-        if rem:           # trailing partial batch: per-batch fused path
+        chunks = self.run_epoch(first_episode, nb)
+        if rem:           # trailing partial batch: the per-batch tails
             tail = self._run_fused_chunk(first_episode + nb * K, rem)
             chunks = [c + t for c, t in zip(chunks, tail)]
         return chunks
@@ -1230,11 +1005,11 @@ class PopulationSearch:
                 if self.fuse_rollouts and self._epochs_fusable():
                     chunks = self._run_epoch_chunk(e, k)
                 elif self.fuse_rollouts and self._rollouts_fusable() \
-                        and k <= self.members[0].batch_size:
+                        and k < self.members[0].batch_size:
                     chunks = self._run_fused_chunk(e, k)
                 else:
-                    # epoch members whose epochs can't share one trace
-                    # keep their own per-member epoch decomposition
+                    # members whose epochs can't share one trace keep
+                    # their own per-member epoch decomposition
                     chunks = [m._run_chunk(e, k) for m in self.members]
                 for i, recs in enumerate(chunks):
                     for rec in recs:
@@ -1276,8 +1051,7 @@ class PopulationSearch:
             for i, m in enumerate(self.members):
                 m.agent.adopt_state(tree_index(new_states, i))
                 m._pending_updates = 0
-                if isinstance(m, FusedCompressionSearch):
-                    m.dispatch_log.append("update")   # shared dispatch
+                m.dispatch_log.append("update")   # shared dispatch
         else:
             for m in self.members:
                 m._flush_updates()
@@ -1325,11 +1099,6 @@ class FleetSearch(PopulationSearch):
                  ckpt_every: int = 1, keep: int = 3,
                  ft_cfg: Optional[FaultToleranceConfig] = None):
         super().__init__(members, fuse_rollouts=fuse_rollouts)
-        for m in self.members:
-            if getattr(m, "epoch_batches", 0) <= 0:
-                raise ValueError(
-                    "FleetSearch members must be FusedCompressionSearch "
-                    "in epoch mode (epoch_batches > 0)")
         if not self._epochs_fusable():
             raise ValueError(
                 "FleetSearch members must share one epoch trace (same "

@@ -125,8 +125,8 @@ class StateTables:
     partial policy, laid out per scan step (one row per actionable
     unit): the static feature block, the reference-latency shares, and
     the spec index used for the in-scan decided-latency mask. Values
-    come from the same ``_static_features`` cache the numpy engines
-    read, so the two paths agree bit-for-bit on these features.
+    come from the same ``_static_features`` cache ``build_state_batch``
+    reads, so the two agree bit-for-bit on these features.
 
     ``this_share``/``rest_share``/``ref_total`` derive from ``ref_lat``
     and hence from the hardware target — the fused rollout takes them as

@@ -20,8 +20,7 @@ fleets need a FRESH process launched with::
 (the flag must precede every jax import — same recipe as
 ``launch/dryrun.py``). ``--data 0`` runs the same fleet without a mesh
 (plain single-device PopulationSearch dispatch), which is the parity
-arm the fleet tests and the ``fleet_scaling`` benchmark compare
-against.
+arm the fleet tests compare against.
 """
 from __future__ import annotations
 
@@ -35,8 +34,7 @@ import jax
 from repro.core.ddpg import DDPGConfig
 from repro.core.latency import LatencyContext
 from repro.core.reward import RewardConfig
-from repro.core.search import (FleetSearch, FusedCompressionSearch,
-                               SearchConfig)
+from repro.core.search import CompressionSearch, FleetSearch, SearchConfig
 from repro.distributed.fault_tolerance import elastic_data_axis
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_dev_mesh
@@ -69,10 +67,10 @@ def tiny_fleet(members: int = 4, data: Optional[int] = None,
                warmup_episodes: int = 4, ckpt_dir: Optional[str] = None,
                ckpt_every: int = 1, mesh=None) -> FleetSearch:
     """P same-method members (one per seed) on the tiny untrained LM —
-    the fleet the subprocess tests and the ``fleet_scaling`` benchmark
-    drive. Members share the model, validation batch, and ONE
-    sensitivity analysis, so the fleet constructor pays it once and the
-    epochs fuse into a single (sharded) dispatch."""
+    the fleet the subprocess tests drive. Members share the model,
+    validation batch, and ONE sensitivity analysis, so the fleet
+    constructor pays it once and the epochs fuse into a single
+    (sharded) dispatch."""
     import jax.random as jr
 
     from repro.configs.base import ArchConfig
@@ -95,9 +93,9 @@ def tiny_fleet(members: int = 4, data: Optional[int] = None,
                             updates_per_episode=updates,
                             batch_size=16, buffer_size=256),
             seed=seed0 + p)
-        m = FusedCompressionSearch(cm, batch, scfg, ctx, sens=sens,
-                                   batch_size=batch_size,
-                                   epoch_batches=epoch_batches)
+        m = CompressionSearch(cm, batch, scfg, ctx, sens=sens,
+                              batch_size=batch_size,
+                              epoch_batches=epoch_batches)
         sens = m.sens
         engines.append(m)
     if mesh is None:

@@ -1,7 +1,8 @@
-"""Batched episode engine: parity with the scalar path + properties.
+"""Batched host pieces: parity with the scalar path + properties.
 
-Covers the four vectorized pieces (oracle, state builder, actor,
-replay) and the assembled ``BatchedCompressionSearch``.
+Covers the vectorized oracle, accuracy and state builder against their
+scalar forms, the host replay buffer, the engine's per-episode
+schedule and legality, and ``PopulationSearch``.
 """
 import copy
 
@@ -13,14 +14,14 @@ from hypothesis import strategies as st
 
 from repro.configs.base import ArchConfig
 from repro.core.compress import lm_layer_specs
-from repro.core.ddpg import DDPGAgent, DDPGConfig
+from repro.core.ddpg import DDPGConfig
 from repro.core.latency import (V5E, LatencyContext, policy_latency,
                                 policy_latency_batch)
 from repro.core.policy import Policy, map_actions, stack_policies
 from repro.core.replay import ReplayBuffer
 from repro.core.reward import RewardConfig
-from repro.core.search import (BatchedCompressionSearch, CompressionSearch,
-                               PopulationSearch, SearchConfig)
+from repro.core.search import (CompressionSearch, PopulationSearch,
+                               SearchConfig)
 from repro.core.state import build_state, build_state_batch
 
 CFG = ArchConfig(name="o", num_layers=4, d_model=256, num_heads=8,
@@ -114,14 +115,14 @@ def test_oracle_reference_matches_scalar_object():
 
 # ------------------------------------------------------ accuracy / state
 
-def _mk_search(tiny_lm, cls=CompressionSearch, **kw):
+def _mk_search(tiny_lm, **kw):
     cm, batch = tiny_lm
     ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
     scfg = SearchConfig(
         methods="pq", episodes=6, reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                         batch_size=16, buffer_size=256))
-    return cls(cm, batch, scfg, ctx, **kw)
+    return CompressionSearch(cm, batch, scfg, ctx, **kw)
 
 
 def test_accuracy_batch_matches_scalar(tiny_lm):
@@ -192,30 +193,7 @@ def test_build_state_batch_matches_scalar(tiny_lm):
             np.testing.assert_allclose(got[j], want, atol=1e-6)
 
 
-# ------------------------------------------------------- actor / replay
-
-def test_act_batch_shapes_and_bounds():
-    cfg = DDPGConfig(state_dim=8, action_dim=3)
-    agent = DDPGAgent(cfg, seed=0)
-    states = np.random.default_rng(0).random((5, 8)).astype(np.float32)
-    a = agent.act_batch(states, np.full(5, 0.5), np.zeros(5, bool))
-    assert a.shape == (5, 3) and a.dtype == np.float32
-    assert np.all((a >= 0) & (a <= 1))
-    # warmup rows are uniform-random; mixed masks work
-    mixed = agent.act_batch(states, np.full(5, 0.5),
-                            np.asarray([1, 0, 1, 0, 0], bool))
-    assert mixed.shape == (5, 3)
-    assert np.all((mixed >= 0) & (mixed <= 1))
-
-
-def test_act_batch_sigma_zero_is_deterministic():
-    cfg = DDPGConfig(state_dim=8, action_dim=2)
-    agent = DDPGAgent(cfg, seed=0)
-    states = np.random.default_rng(1).random((4, 8)).astype(np.float32)
-    a1 = agent.act_batch(states, np.zeros(4), np.zeros(4, bool))
-    a2 = np.stack([agent.act(states[i], 0.0) for i in range(4)])
-    np.testing.assert_allclose(a1, a2, atol=1e-6)
-
+# ---------------------------------------------------------------- replay
 
 @pytest.mark.parametrize("capacity,chunks", [
     (64, (40,)),          # vectorized write, no wraparound
@@ -246,32 +224,9 @@ def test_push_batch_equals_sequential_push(capacity, chunks):
 
 # ------------------------------------------------------------ the engine
 
-@pytest.mark.parametrize("methods", ["p", "q", "pq"])
-def test_batched_search_runs_all_agents(tiny_lm, methods):
-    cm, batch = tiny_lm
-    ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
-    scfg = SearchConfig(
-        methods=methods, episodes=6,
-        reward=RewardConfig(target_ratio=0.5),
-        ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
-                        batch_size=16, buffer_size=256))
-    search = BatchedCompressionSearch(cm, batch, scfg, ctx, batch_size=4)
-    res = search.run()
-    assert len(res.history) == 6
-    assert [r.episode for r in res.history] == list(range(6))
-    for rec in res.history:
-        assert np.isfinite(rec.reward)
-        assert 0.0 <= rec.accuracy <= 1.0
-        assert rec.latency_s > 0
-        assert len(rec.policy.cmps) == len(search.specs)
-    # shared-episode-reward transitions, all pushed
-    assert len(search.replay) == min(256, 6 * len(search.steps))
-
-
 def test_batched_search_policies_legal(tiny_lm):
-    search = _mk_search(tiny_lm, cls=BatchedCompressionSearch,
-                        batch_size=3)
-    for rec in search.run_episode_batch(0, 3):
+    search = _mk_search(tiny_lm, batch_size=3)
+    for rec in search.run_epoch(0, 1):
         for s, c in zip(search.specs, rec.policy.cmps):
             if s.prunable and s.prune_dim:
                 assert c.keep % s.prune_granularity == 0 \
@@ -284,9 +239,8 @@ def test_batched_search_policies_legal(tiny_lm):
 
 def test_batched_search_sigma_schedule(tiny_lm):
     """Each episode in a batch keeps its own sigma/warmup position."""
-    search = _mk_search(tiny_lm, cls=BatchedCompressionSearch,
-                        batch_size=6)
-    recs = search.run_episode_batch(0, 6)
+    search = _mk_search(tiny_lm, batch_size=6)
+    recs = search.run_epoch(0, 1)
     want = [search.agent.sigma_at(e) for e in range(6)]
     got = [r.sigma for r in recs]
     np.testing.assert_allclose(got, want, atol=1e-6)
@@ -295,21 +249,21 @@ def test_batched_search_sigma_schedule(tiny_lm):
 # -------------------------------------------------------- the population
 
 def _mk_population_member(tiny_lm, methods, batch_size=3):
-    """Batched member with action_dim padded to the pq maximum so
-    p/q/pq agents stack into one vmappable population."""
+    """Member with action_dim padded to the pq maximum so p/q/pq
+    agents stack into one vmappable population."""
     cm, batch = tiny_lm
     ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
     scfg = SearchConfig(
         methods=methods, episodes=6, reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                         batch_size=16, buffer_size=256, action_dim=3))
-    return BatchedCompressionSearch(cm, batch, scfg, ctx,
-                                    batch_size=batch_size)
+    return CompressionSearch(cm, batch, scfg, ctx, batch_size=batch_size)
 
 
 def test_population_runs_mixed_methods(tiny_lm):
-    """p/q/pq members share update dispatches; per-member histories keep
-    scalar-engine semantics (episode order, sigma schedule, legality)."""
+    """p/q/pq members run as one population (each member's epochs
+    update inside their programs); per-member histories keep the
+    per-episode semantics (episode order, sigma schedule, legality)."""
     members = [_mk_population_member(tiny_lm, m) for m in ("p", "q", "pq")]
     pop = PopulationSearch(members)
     results = pop.run(episodes=6)
@@ -322,7 +276,7 @@ def test_population_runs_mixed_methods(tiny_lm):
         for rec in res.history:
             assert np.isfinite(rec.reward)
             assert len(rec.policy.cmps) == len(m.specs)
-        # updates ran (post-warmup budgets were dispatched and cleared)
+        # no update budget left pending
         assert m._pending_updates == 0
         assert not m._defer_updates
     # padded action dims: all members share the pq agent shape
@@ -350,7 +304,7 @@ def test_population_rejects_mismatched_configs(tiny_lm):
         methods="p", episodes=6, reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                         batch_size=16, buffer_size=256))   # native dims
-    native_p = BatchedCompressionSearch(cm, batch, scfg, ctx, batch_size=3)
+    native_p = CompressionSearch(cm, batch, scfg, ctx, batch_size=3)
     with pytest.raises(ValueError):
         PopulationSearch([native_pq, native_p])
     with pytest.raises(ValueError):

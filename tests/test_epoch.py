@@ -1,18 +1,16 @@
-"""Epoch-fused engine: parity with the per-batch fused engine.
+"""The epoch program: parity with the per-batch tail.
 
-The contract under test (ISSUE 4): ``FusedCompressionSearch`` in epoch
-mode (``epoch_batches=E`` / ``run_epoch``) runs E whole episode batches
-— fused rollout, traced-cspec validation, reward, ``DeviceReplay`` ring
-write, and the update chunk — as ONE ``jit(lax.scan)`` with donated
-buffers and a single host readback, and must reproduce the per-batch
-``FusedCompressionSearch`` exactly: episode records, the final
-``AgentState``, and the replay ring contents.
+The contract under test: ``run_epoch`` runs E whole episode batches —
+rollout, traced-cspec validation, reward, ``DeviceReplay`` ring write,
+and the update chunk — as ONE ``jit(lax.scan)`` with donated buffers
+and a single host readback, and must reproduce the engine's per-batch
+tail run batch by batch (``_run_batches``) exactly: episode records,
+the final ``AgentState``, and the replay ring contents.
 
-Unlike the PR 3 parity tests, no noise replay harness is needed: the
-epoch scan carries the SAME PRNG streams (the rollout key and the
-agent's update-sampling key) and consumes them with the same split
-pattern as the per-batch path, so two same-seed engines draw
-identically by construction. The comparison therefore exercises every
+No noise replay harness is needed: the epoch scan carries the SAME PRNG
+streams (the rollout key and the agent's update-sampling key) and
+consumes them with the same split pattern as the per-batch tail, so two
+same-seed engines draw identically by construction. The comparison therefore exercises every
 stage — exploration, the in-scan normalizer advance, validation,
 reward, the ring write order, and the masked in-scan update chunks
 (including warmup-straddling batches, whose static update schedules
@@ -30,7 +28,7 @@ from repro.core.latency import HardwareTarget, LatencyContext, V5E
 from repro.core.replay import (DeviceReplay, ReplayBuffer,
                                device_replay_push)
 from repro.core.reward import RewardConfig
-from repro.core.search import (FusedCompressionSearch, PopulationSearch,
+from repro.core.search import (CompressionSearch, PopulationSearch,
                                SearchConfig)
 
 
@@ -56,7 +54,7 @@ def _testbed():
     return _testbed_cache["lm"]
 
 
-def _mk(tiny_lm, methods, updates=2, batch_size=4, epoch_batches=0,
+def _mk(tiny_lm, methods, updates=2, batch_size=4, epoch_batches=1,
         seed=0, sens=None, episodes=16, hw=V5E):
     cm, batch = tiny_lm
     ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
@@ -65,9 +63,18 @@ def _mk(tiny_lm, methods, updates=2, batch_size=4, epoch_batches=0,
         reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=updates,
                         batch_size=16, buffer_size=256), seed=seed)
-    return FusedCompressionSearch(cm, batch, scfg, ctx, hw=hw, sens=sens,
-                                  batch_size=batch_size,
-                                  epoch_batches=epoch_batches)
+    return CompressionSearch(cm, batch, scfg, ctx, hw=hw, sens=sens,
+                             batch_size=batch_size,
+                             epoch_batches=epoch_batches)
+
+
+def _run_batches(search, episodes):
+    """The per-batch tail run batch by batch: the epoch's reference."""
+    K = search.batch_size
+    recs = []
+    for e in range(0, episodes, K):
+        recs += search.run_episode_batch(e, min(K, episodes - e))
+    return recs
 
 
 def _assert_records_match(recs_a, recs_b):
@@ -97,7 +104,7 @@ def _assert_trees_close(ta, tb, tol=2e-5):
     pytest.param("q", marks=pytest.mark.slow),
 ])
 def test_epoch_matches_per_batch_engine(tiny_lm, methods):
-    """run() through epochs of 2 batches == per-batch fused run: records
+    """run() through epochs of 2 batches == the per-batch tail: records
     (reward/accuracy/latency/sigma/policies) and ring contents within
     1e-5. The first epoch straddles the agent's warmup boundary, so
     both the partial-budget and the steady update schedules are
@@ -109,10 +116,10 @@ def test_epoch_matches_per_batch_engine(tiny_lm, methods):
     epoch = _mk(tiny_lm, methods, epoch_batches=2)
     ref = _mk(tiny_lm, methods, sens=epoch.sens)
     res_e = epoch.run(episodes=16)
-    res_r = ref.run(episodes=16)
+    recs_r = _run_batches(ref, 16)
     assert epoch.dispatch_log == ["epoch", "epoch"]
-    _assert_records_match(res_e.history, res_r.history)
-    assert res_e.best.episode == res_r.best.episode
+    _assert_records_match(res_e.history, recs_r)
+    assert res_e.best.episode == max(recs_r, key=lambda r: r.reward).episode
     # final agent state (actor/critic/targets/Adam/norm/reward-MA/key)
     _assert_trees_close(epoch.agent.state_for_dispatch(),
                         ref.agent.state_for_dispatch(), tol=1e-3)
@@ -127,12 +134,11 @@ def test_epoch_matches_per_batch_engine(tiny_lm, methods):
 
 def test_epoch_state_parity_no_updates(tiny_lm):
     """With the update amplifier off, the full final AgentState —
-    norm stats included — matches the per-batch engine within 1e-5."""
+    norm stats included — matches the per-batch tail within 1e-5."""
     epoch = _mk(tiny_lm, "pq", updates=0, epoch_batches=2)
     ref = _mk(tiny_lm, "pq", updates=0, sens=epoch.sens)
     res_e = epoch.run(episodes=16)
-    res_r = ref.run(episodes=16)
-    _assert_records_match(res_e.history, res_r.history)
+    _assert_records_match(res_e.history, _run_batches(ref, 16))
     _assert_trees_close(epoch.agent.state_for_dispatch(),
                         ref.agent.state_for_dispatch(), tol=1e-5)
 
@@ -148,7 +154,7 @@ def test_epoch_parity_random_seeds(seed):
     epoch = _mk(tb, "pq", epoch_batches=3, seed=s, batch_size=3)
     ref = _mk(tb, "pq", sens=epoch.sens, seed=s, batch_size=3)
     recs_e = epoch.run(episodes=9).history
-    recs_r = ref.run(episodes=9).history
+    recs_r = _run_batches(ref, 9)
     _assert_records_match(recs_e, recs_r)
     _assert_trees_close(epoch.agent.state_for_dispatch(),
                         ref.agent.state_for_dispatch(), tol=1e-3)
@@ -166,14 +172,13 @@ def test_epoch_best_tracking_matches_history(tiny_lm):
 
 
 def test_epoch_remainder_falls_back_to_batches(tiny_lm):
-    """Episode counts that don't fill an epoch run the tail through the
-    per-batch fused path — same numbering, same records."""
+    """Episode counts that don't fill a batch run the remainder through
+    the per-batch tail — same numbering, same records."""
     epoch = _mk(tiny_lm, "pq", epoch_batches=2)
     ref = _mk(tiny_lm, "pq", sens=epoch.sens)
-    res_e = epoch.run(episodes=14)        # 8 (epoch) + 4 + 2 remainder
-    res_r = ref.run(episodes=14)
+    res_e = epoch.run(episodes=14)  # epochs of 8 and 4, then 2 remainder
     assert [r.episode for r in res_e.history] == list(range(14))
-    _assert_records_match(res_e.history, res_r.history)
+    _assert_records_match(res_e.history, _run_batches(ref, 14))
     assert "rollout" in epoch.dispatch_log   # the per-batch tail ran
     assert "epoch" in epoch.dispatch_log
 
@@ -194,7 +199,7 @@ def test_epoch_dispatch_count(tiny_lm):
     acceptance bound), measured by wrapping the compiled epoch
     executables — with canaries proving the per-batch entry points
     (rollout/validate/push/update jits) and the host path never ran."""
-    from benchmarks.search_setup import assert_epoch_dispatch_count
+    from _dispatch_probes import assert_epoch_dispatch_count
     epoch = _mk(tiny_lm, "pq", epoch_batches=2)
     epoch.run(episodes=16)               # compile both schedules
     counts = assert_epoch_dispatch_count(epoch, first_episode=8,
@@ -243,8 +248,8 @@ def test_population_epoch_requires_shared_reward(tiny_lm):
             methods="pq", episodes=4, reward=RewardConfig(target_ratio=c),
             ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                             batch_size=16, buffer_size=256))
-        return FusedCompressionSearch(cm, batch, scfg, ctx, sens=sens,
-                                      batch_size=2, epoch_batches=2)
+        return CompressionSearch(cm, batch, scfg, ctx, sens=sens,
+                                 batch_size=2, epoch_batches=2)
 
     m0 = member(0.5)
     pop = PopulationSearch([m0, member(0.6, sens=m0.sens)],

@@ -22,8 +22,9 @@ import pytest
 
 from conftest import require_devices
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-ROOT = os.path.join(os.path.dirname(__file__), "..")
+TESTS = os.path.dirname(os.path.abspath(__file__))
+SRC = os.path.join(TESTS, "..", "src")
+ROOT = os.path.join(TESTS, "..")
 
 
 # ---------------------------------------------------------------------------
@@ -50,7 +51,7 @@ def _fleet_members(tiny_lm, n=2, epoch_batches=2):
     from repro.core.ddpg import DDPGConfig
     from repro.core.latency import LatencyContext
     from repro.core.reward import RewardConfig
-    from repro.core.search import FusedCompressionSearch, SearchConfig
+    from repro.core.search import CompressionSearch, SearchConfig
     cm, batch = tiny_lm
     ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
     members, sens = [], None
@@ -61,19 +62,18 @@ def _fleet_members(tiny_lm, n=2, epoch_batches=2):
             ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                             batch_size=16, buffer_size=256),
             seed=p)
-        m = FusedCompressionSearch(cm, batch, scfg, ctx, sens=sens,
-                                   batch_size=4,
-                                   epoch_batches=epoch_batches)
+        m = CompressionSearch(cm, batch, scfg, ctx, sens=sens,
+                              batch_size=4, epoch_batches=epoch_batches)
         sens = m.sens
         members.append(m)
     return members
 
 
 def test_fleet_rejects_non_epoch_members(tiny_lm):
-    from repro.core.search import FleetSearch
-    members = _fleet_members(tiny_lm, n=2, epoch_batches=0)
-    with pytest.raises(ValueError, match="epoch mode"):
-        FleetSearch(members)
+    """A member cannot leave epoch mode: the engine rejects
+    ``epoch_batches`` < 1."""
+    with pytest.raises(ValueError, match="epoch_batches"):
+        _fleet_members(tiny_lm, n=2, epoch_batches=0)
 
 
 def test_fleet_rejects_mesh_without_data_axis(tiny_lm):
@@ -157,8 +157,7 @@ _PARITY_RESUME = textwrap.dedent("""
     import json
     import tempfile
     import jax
-    from benchmarks.search_setup import \\
-        assert_population_epoch_dispatch_count
+    from _dispatch_probes import assert_population_epoch_dispatch_count
     from repro.launch.fleet import tiny_fleet
 
     d = tempfile.mkdtemp()
@@ -249,8 +248,8 @@ _ELASTIC_RESUME = textwrap.dedent("""
 
 def _run_subprocess(script):
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(SRC) + os.pathsep \
-        + os.path.abspath(ROOT)
+    env["PYTHONPATH"] = os.pathsep.join(
+        os.path.abspath(d) for d in (SRC, ROOT, TESTS))
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     res = subprocess.run([sys.executable, "-c", script], env=env,

@@ -1,17 +1,17 @@
-"""Fused rollout engine: parity with the numpy batched engine.
+"""The traced rollout: parity with the host-stepped reference.
 
-The contract under test (ISSUE 3): ``FusedCompressionSearch`` runs the
-whole episode environment — oracle features, actor with in-scan PRNG
-exploration, action->CMP projection, policy carry — as ONE
-``jit(lax.scan)``, and must reproduce ``BatchedCompressionSearch``
+The contract under test: the search engine runs the whole episode
+environment — oracle features, actor with in-scan PRNG exploration,
+action->CMP projection, policy carry — as ONE ``jit(lax.scan)``, and
+must reproduce the host-stepped rollout (``_search_ref.host_rollout``)
 step for step: states, actions, final ``PolicyBatch``, rewards.
 
-Exploration randomness is replayed through the numpy reference engine
-via the fused path's exposed per-batch key (the same idiom as PR 2's
-``chunk_sample_keys``), so the comparison exercises every
-deterministic stage: the jnp oracle vs the f64 numpy oracle, the
-static/decided state features, the vectorized Eq. 4/8 mapping +
-legalization, and the reward.
+Exploration randomness is replayed through the host reference via the
+per-batch tail's exposed batch key (the same idiom as
+``chunk_sample_keys``), so the comparison exercises every deterministic
+stage: the jnp oracle vs the f64 numpy oracle, the static/decided state
+features, the vectorized Eq. 4/8 mapping + legalization, and the
+reward.
 """
 import jax
 import jax.numpy as jnp
@@ -34,10 +34,11 @@ from repro.core.policy import (Policy, action_columns, map_actions,
                                map_actions_batch, n_actions,
                                policies_from_batch, stack_policies)
 from repro.core.reward import RewardConfig
-from repro.core.search import (BatchedCompressionSearch,
-                               FusedCompressionSearch, PopulationSearch,
+from repro.core.search import (CompressionSearch, PopulationSearch,
                                SearchConfig)
 from repro.core.spec import effective_bits
+
+from _search_ref import host_rollout
 
 CFG = ArchConfig(name="o", num_layers=4, d_model=256, num_heads=8,
                  num_kv_heads=4, head_dim=32, d_ff=1024, vocab_size=512)
@@ -152,104 +153,75 @@ def test_agent_act_batch_bounds_and_sigma_zero():
         cfg, agent.state, jnp.asarray(states), key,
         jnp.full(6, 0.5, jnp.float32), jnp.ones(6, bool)))
     assert np.all((warm >= 0) & (warm < 1))
-    # sigma=0 is the deterministic actor — must match the host path
+    # sigma=0 is the deterministic actor — must match the host actor
     det = np.asarray(agent_act_batch(
         cfg, agent.state, jnp.asarray(states), key,
         jnp.zeros(6, jnp.float32), jnp.zeros(6, bool)))
-    host = agent.act_batch(states, np.zeros(6), np.zeros(6, bool))
+    host = np.stack([agent.act(s, 0.0) for s in states])
     np.testing.assert_allclose(det, host, atol=1e-5)
 
 
 # ------------------------------------------------------- engine parity
 
-def _mk(tiny_lm, cls, methods, updates=0, batch_size=4, seed=0,
-        sens=None):
+def _mk(tiny_lm, methods, updates=0, batch_size=4, seed=0, sens=None):
     cm, batch = tiny_lm
     ctx = LatencyContext(tokens=1, seq_ctx=256, mode="decode", batch=1)
     scfg = SearchConfig(
         methods=methods, episodes=8, reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=updates,
                         batch_size=16, buffer_size=256), seed=seed)
-    return cls(cm, batch, scfg, ctx, sens=sens, batch_size=batch_size)
+    return CompressionSearch(cm, batch, scfg, ctx, sens=sens,
+                             batch_size=batch_size)
 
 
 @pytest.mark.parametrize("methods", ["p", "q", "pq"])
 def test_fused_rollout_matches_batched_engine(tiny_lm, methods):
     """States, actions, final PolicyBatch, and rewards within 1e-5 of
-    the numpy engine when the numpy engine replays the fused path's
+    the host-stepped reference when it replays the traced rollout's
     exact exploration draws — one batch straddling warmup, one fully
     live (norm stats advanced across the boundary)."""
     K = 4
-    fused = _mk(tiny_lm, FusedCompressionSearch, methods)
-    ref = _mk(tiny_lm, BatchedCompressionSearch, methods,
-              sens=fused.sens)
+    search = _mk(tiny_lm, methods)
     for first in (0, K):
-        args = fused._rollout_args(first, K)
+        args = search._rollout_args(first, K)
         st_snap = args[0]                 # agent state the scan consumed
-        out = fused._rollout(*args)
-        recs_f = fused._finish_batch(first, K, out)
+        out = search._rollout(*args)
+        recs_f = search._finish_batch(first, K, out)
 
-        keys = iter(jax.random.split(fused._last_batch_key,
-                                     len(fused.steps)))
-        captured = []
+        keys = iter(jax.random.split(search._last_batch_key,
+                                     len(search.steps)))
 
         def act_replay(S, sigmas, warm):
-            A = np.asarray(agent_act_batch(
-                ref.agent.cfg, st_snap, jnp.asarray(S, jnp.float32),
+            return np.asarray(agent_act_batch(
+                search.agent.cfg, st_snap, jnp.asarray(S, jnp.float32),
                 next(keys), jnp.asarray(sigmas, jnp.float32),
                 jnp.asarray(warm)))
-            captured.append((np.asarray(S, np.float32).copy(), A))
-            return A
 
-        ref.agent.act_batch = act_replay
-        recs_r = ref.run_episode_batch(first, K)
+        S_r, A_r, pols_r, accs_r, lats_r, rews_r, sigs_r = host_rollout(
+            search, first, K, act_replay)
 
         S_f, A_f = np.asarray(out[3]), np.asarray(out[4])
-        S_r = np.stack([c[0] for c in captured])
-        A_r = np.stack([c[1] for c in captured])
         np.testing.assert_allclose(S_f, S_r, atol=1e-5)
         np.testing.assert_allclose(A_f, A_r, atol=1e-5)
-        pb_f = stack_policies(fused.specs, [r.policy for r in recs_f])
-        pb_r = stack_policies(ref.specs, [r.policy for r in recs_r])
+        pb_f = stack_policies(search.specs, [r.policy for r in recs_f])
+        pb_r = stack_policies(search.specs, pols_r)
         np.testing.assert_array_equal(pb_f.keep, pb_r.keep)
         np.testing.assert_array_equal(pb_f.w_bits, pb_r.w_bits)
         np.testing.assert_array_equal(pb_f.a_bits, pb_r.a_bits)
-        for a, b in zip(recs_f, recs_r):
-            assert a.reward == pytest.approx(b.reward, abs=1e-5)
-            assert a.accuracy == pytest.approx(b.accuracy, abs=1e-6)
-            assert a.latency_s == pytest.approx(b.latency_s, rel=1e-5)
-            assert a.sigma == pytest.approx(b.sigma, abs=1e-6)
-
-
-@pytest.mark.parametrize("methods", ["p", "q", "pq"])
-def test_fused_search_runs_all_agents(tiny_lm, methods):
-    """End-to-end engine smoke: episode numbering, legality, replay
-    fill, finite records — with live update dispatches."""
-    search = _mk(tiny_lm, FusedCompressionSearch, methods, updates=2)
-    res = search.run(episodes=8)
-    assert [r.episode for r in res.history] == list(range(8))
-    for rec in res.history:
-        assert np.isfinite(rec.reward)
-        assert 0.0 <= rec.accuracy <= 1.0
-        assert rec.latency_s > 0
-        for s, c in zip(search.specs, rec.policy.cmps):
-            if s.prunable and s.prune_dim:
-                assert c.keep % s.prune_granularity == 0 \
-                    or c.keep == s.prune_dim
-            if c.mode == "MIX":
-                assert s.mix_supported
-            if not s.quantizable:
-                assert c.mode == "FP32"
-    assert len(search.replay) == min(256, 8 * len(search.steps))
+        for j, a in enumerate(recs_f):
+            assert a.reward == pytest.approx(rews_r[j], abs=1e-5)
+            assert a.accuracy == pytest.approx(accs_r[j], abs=1e-6)
+            assert a.latency_s == pytest.approx(lats_r[j], rel=1e-5)
+            assert a.sigma == pytest.approx(sigs_r[j], abs=1e-6)
 
 
 def test_fused_dispatch_count(tiny_lm):
-    """One episode batch = rollout + validation + ring write + update
-    chunk: <= 4 jit executions on the fused path (the ISSUE 3
-    acceptance bound), measured by wrapping the compiled entry points
-    themselves — with canaries proving the per-step host path is gone."""
-    from benchmarks.search_setup import assert_fused_dispatch_count
-    search = _mk(tiny_lm, FusedCompressionSearch, "pq", updates=2)
+    """One batch through the per-batch tail = rollout + validation +
+    ring write + update chunk: <= 4 jit executions, measured by wrapping
+    the compiled entry points themselves — with canaries proving the
+    per-step host path never runs."""
+    from _dispatch_probes import assert_fused_dispatch_count
+    search = _mk(tiny_lm, "pq", updates=2)
     search.run(episodes=8)               # compile + cross warmup
     counts = assert_fused_dispatch_count(search, first_episode=8,
                                          batch_size=4)
@@ -262,8 +234,9 @@ def test_fused_dispatch_count(tiny_lm):
 # ---------------------------------------------------- fused populations
 
 def test_population_fused_rollouts_match_solo(tiny_lm):
-    """fuse_rollouts=True: one vmapped rollout across hardware targets
-    reproduces each member run alone (same seeds -> same PRNG)."""
+    """fuse_rollouts=True: one vmapped epoch across hardware targets,
+    then one vmapped rollout for the trailing partial batch, reproduces
+    each member run alone (same seeds -> same PRNG)."""
     v5p = HardwareTarget(name="tpu-v5p", peak_bf16=459e12,
                          peak_int8=918e12, hbm_bw=2765e9, ici_bw=90e9)
     cm, batch = tiny_lm
@@ -274,17 +247,20 @@ def test_population_fused_rollouts_match_solo(tiny_lm):
                         batch_size=16, buffer_size=256))
 
     def member(hw, sens=None):
-        return FusedCompressionSearch(cm, batch, scfg, ctx, hw=hw,
-                                      sens=sens, batch_size=3)
+        return CompressionSearch(cm, batch, scfg, ctx, hw=hw, sens=sens,
+                                 batch_size=3)
 
     m0 = member(V5E)
     members = [member(V5E, sens=m0.sens), member(v5p, sens=m0.sens)]
     pop = PopulationSearch(members, fuse_rollouts=True)
     assert pop._rollouts_fusable()
-    results = pop.run(episodes=6)
+    results = pop.run(episodes=7)            # 2 whole batches + 1
+    for m in members:
+        assert m.dispatch_log.count("epoch") == 2
+        assert m.dispatch_log.count("rollout") == 1
     solos = [member(V5E, sens=m0.sens), member(v5p, sens=m0.sens)]
     for m, res in zip(solos, results):
-        want = m.run(episodes=6)
+        want = m.run(episodes=7)
         for a, b in zip(res.history, want.history):
             assert a.reward == pytest.approx(b.reward, abs=1e-6)
             assert a.latency_s == pytest.approx(b.latency_s, rel=1e-6)
@@ -303,7 +279,7 @@ def test_population_mixed_methods_falls_back(tiny_lm):
             reward=RewardConfig(target_ratio=0.5),
             ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                             batch_size=16, buffer_size=256, action_dim=3))
-        return FusedCompressionSearch(cm, batch, scfg, ctx, batch_size=2)
+        return CompressionSearch(cm, batch, scfg, ctx, batch_size=2)
 
     pop = PopulationSearch([member("p"), member("q"), member("pq")],
                            fuse_rollouts=True)
@@ -334,7 +310,7 @@ def test_static_cache_evicts_oldest(tiny_lm, monkeypatch):
     cm, _ = tiny_lm
     monkeypatch.setattr(state_mod, "_STATIC_CACHE_MAX", 2)
     monkeypatch.setattr(state_mod, "_static_cache", {})
-    search = _mk(tiny_lm, BatchedCompressionSearch, "pq")
+    search = _mk(tiny_lm, "pq")
     from repro.core.state import _static_features
     vals = [_static_features(search.specs, t, search.sens, search.ref_lat)
             for t in search.steps[:3]]

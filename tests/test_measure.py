@@ -153,9 +153,9 @@ def test_oracle_cache_keyed_on_calib(cm):
 
 
 def test_calibrated_fused_dispatch_bound():
-    """ISSUE 6 acceptance: oracle_mode="calibrated" keeps the fused
-    rollout engine at the analytic engine's <=4-dispatch bound."""
-    from benchmarks.search_setup import calibrated_fused_row
+    """oracle_mode="calibrated" keeps the per-batch tail at the analytic
+    oracle's <=4-dispatch bound."""
+    from _dispatch_probes import calibrated_fused_row
     row = calibrated_fused_row(batch_size=4, updates=4)
     assert row["dispatches_per_batch"] <= 4
 

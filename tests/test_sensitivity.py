@@ -102,7 +102,7 @@ def test_fused_chunking_invariant(tiny_lm, chunk):
 def test_sensitivity_dispatch_count(tiny_lm):
     """One analysis = ONE fused jit execution, zero per-probe
     dispatches (the sensitivity analogue of the epoch dispatch bound)."""
-    from benchmarks.search_setup import assert_sensitivity_dispatch_count
+    from _dispatch_probes import assert_sensitivity_dispatch_count
     cm, batch = tiny_lm
     counts = assert_sensitivity_dispatch_count(cm, batch)
     assert counts == {"fused": 1, "seq_probes": 0}

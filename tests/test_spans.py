@@ -12,7 +12,7 @@ from repro.core import spans
 from repro.core.ddpg import DDPGConfig
 from repro.core.latency import LatencyContext
 from repro.core.reward import RewardConfig
-from repro.core.search import (FusedCompressionSearch, PopulationSearch,
+from repro.core.search import (CompressionSearch, PopulationSearch,
                                SearchConfig)
 
 EPOCH_PHASES = ("search.epoch", "search.epoch.args",
@@ -81,8 +81,8 @@ def _engine(tiny_lm, seed=0, sens=None):
         methods="pq", episodes=8, reward=RewardConfig(target_ratio=0.5),
         ddpg=DDPGConfig(warmup_episodes=2, updates_per_episode=2,
                         batch_size=16, buffer_size=256), seed=seed)
-    return FusedCompressionSearch(cm, batch, scfg, ctx, sens=sens,
-                                  batch_size=2, epoch_batches=2)
+    return CompressionSearch(cm, batch, scfg, ctx, sens=sens,
+                             batch_size=2, epoch_batches=2)
 
 
 def _epoch_spans(got, first):

@@ -256,43 +256,3 @@ def test_agent_init_uses_paper_final_scale():
     for net in (st.actor, st.critic):
         assert np.abs(np.asarray(net[-1]["w"])).max() <= 3e-3
         assert np.abs(np.asarray(net[0]["w"])).max() > 3e-3
-
-
-# ----------------------- regression-gate inversion -------------------------
-
-def test_regression_gate_lower_is_better_inversion():
-    """ms_per_update gates with the latency sense: UP is a regression,
-    down never is. serve_tok_per_s keeps the throughput sense."""
-    from benchmarks.regression_gate import check
-    key = {"table": "update_floor", "engine": "megabatch", "members": 4,
-           "batch_size": 128, "updates_per_episode": 8}
-    base = [{**key, "ms_per_update": 10.0}]
-    # 50% slower -> fails at tol 0.2
-    checked, fails = check([{**key, "ms_per_update": 15.0}], base, 0.2)
-    assert checked == 1 and len(fails) == 1
-    # 50% faster -> passes (would have FAILED under the throughput rule)
-    checked, fails = check([{**key, "ms_per_update": 5.0}], base, 0.2)
-    assert checked == 1 and fails == []
-    # within tolerance -> passes
-    checked, fails = check([{**key, "ms_per_update": 11.0}], base, 0.2)
-    assert checked == 1 and fails == []
-
-    skey = {"table": "serve", "engine": "serve_int8", "batch_size": 4}
-    sbase = [{**skey, "serve_tok_per_s": 1000.0}]
-    checked, fails = check([{**skey, "serve_tok_per_s": 700.0}], sbase, 0.2)
-    assert checked == 1 and len(fails) == 1
-    checked, fails = check([{**skey, "serve_tok_per_s": 1500.0}], sbase,
-                           0.2)
-    assert checked == 1 and fails == []
-
-
-def test_regression_gate_metric_filter():
-    from benchmarks.regression_gate import check
-    key = {"table": "update_floor", "engine": "vmap", "members": 1,
-           "batch_size": 128, "updates_per_episode": 8}
-    base = [{**key, "ms_per_update": 10.0, "eps_per_s": 100.0}]
-    cur = [{**key, "ms_per_update": 50.0, "eps_per_s": 100.0}]
-    checked, fails = check(cur, base, 0.2, metric="eps_per_s")
-    assert checked == 1 and fails == []         # the bad metric is ignored
-    checked, fails = check(cur, base, 0.2, metric="ms_per_update")
-    assert checked == 1 and len(fails) == 1
